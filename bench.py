@@ -156,17 +156,8 @@ def _timed_primed(dispatch, reps: int, primers: int = 1):
 def _setup_jax():
     import jax
 
-    # CPU tier rides the persistent compilation cache (the TPU plugin
-    # doesn't reload from it — the aot.py serialized-executable path
-    # covers that tier); shared wiring with the warm doctor's probe
     from drand_tpu import aot
-    if aot.enable_persistent_cache(min_compile_time_s=1.0) is None:
-        # non-CPU backend: still point the cache dir at the shared
-        # location so any CPU-compiled helper programs persist
-        jax.config.update("jax_compilation_cache_dir",
-                          aot.persistent_cache_dir())
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          1.0)
+    aot.enable_persistent_cache(min_compile_time_s=1.0)
     return jax
 
 
@@ -194,9 +185,8 @@ def _chain_fixture(shape_name: str, batch: int):
     # and suite as unchained, different signed messages
     suite = f"{shape_name[:2]}{suite}" if shape.chained else suite
     fname = f"bench_sigs_{shape_name}_{batch}_{suite}_{pk_h}.npy"
-    # AOT-dir first (committed by the warm run: /tmp does not survive
-    # environment resets and signing 16k fixtures costs ~11 min on this
-    # 1-core host), /tmp second.
+    # AOT-dir first (committed: signing 16k fixtures costs minutes of
+    # host time), /tmp second.
     from drand_tpu import aot
     repo_cache = os.path.join(aot.aot_dir(), "fixtures", fname)
     tmp_cache = f"/tmp/drand_tpu_{fname}"
@@ -223,21 +213,25 @@ def _chain_fixture(shape_name: str, batch: int):
 
 
 def _warn_if_cold(verifier, n):
-    """A missing AOT executable means a ~1.7h cold XLA compile on this
-    host (aot/*.aotx are disk-resident only — see README).  Fail loud and
-    early instead of silently compiling for an hour."""
+    """CPU tier: say so early when no serialized executable matches this
+    kernel revision and the run starts with a whole compile.  On the TPU
+    the program is built by `jit` (JAX's persistent cache only); what a
+    build costs there is what `chip_smoke.py` prints."""
     from drand_tpu import aot
+    from drand_tpu.ops.pallas_field import use_pallas
     from drand_tpu.verify import _bucket
+    if use_pallas():
+        return
     path = aot.cache_path(verifier._aot_name(_bucket(n)))
     if not os.path.exists(path):
         if aot.warming():
             print(f"bench: warming {os.path.basename(path)} (compile + "
-                  "serialize; ~1h on this host)", file=sys.stderr)
+                  "serialize)", file=sys.stderr)
         else:
-            print(f"bench: COLD START — no AOT executable for this kernel "
-                  f"revision ({os.path.basename(path)}); compiling now "
-                  f"takes ~1h on this host. Run scripts/warm_artifacts.sh "
-                  f"to persist executables, or expect this run to be slow.",
+            print(f"bench: COLD START — no serialized executable for this "
+                  f"kernel revision ({os.path.basename(path)}); compiling "
+                  f"now. Run scripts/warm_artifacts.sh to persist "
+                  f"executables, or expect this run to be slow.",
                   file=sys.stderr)
 
 

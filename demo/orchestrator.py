@@ -8,6 +8,11 @@ at the first broken invariant.  Usable as a library (integration tests) or
 a script:
 
     python -m demo.orchestrator --nodes 3 --threshold 2 --period 3
+
+A CPU recipe: it starts one daemon process per node and each imports JAX,
+while an accelerator belongs to one process at a time.  `_child_env` pins
+every child to `JAX_PLATFORMS=cpu`, whatever the parent's environment
+says.  The chip path is `python chip_smoke.py` (one process, both nodes).
 """
 
 from __future__ import annotations
@@ -47,6 +52,15 @@ def _cli_knows(repo: str, flag: str) -> bool:
         return False
 
 
+def _child_env(repo: str, **extra) -> dict:
+    """Environment of every process the demo starts: CPU backend (see the
+    module doc string) and the repo's one compile-cache directory."""
+    from drand_tpu import aot
+    return dict(os.environ, PYTHONPATH=repo, JAX_PLATFORMS="cpu",
+                JAX_COMPILATION_CACHE_DIR=aot.persistent_cache_dir(),
+                **extra)
+
+
 class Node:
     def __init__(self, index: int, base: str, control: int, private: int,
                  public: int | None, repo: str = REPO,
@@ -66,11 +80,8 @@ class Node:
         self.certs_dir = certs_dir
 
     def cli(self, *args, timeout=120, check=True) -> str:
-        env = dict(os.environ,
-                   PYTHONPATH=self.repo,
-                   JAX_PLATFORMS="cpu",
-                   JAX_COMPILATION_CACHE_DIR="/tmp/drand_tpu_jax_cache",
-                   DRAND_SHARE_SECRET="demo-orchestrator-secret")
+        env = _child_env(self.repo,
+                         DRAND_SHARE_SECRET="demo-orchestrator-secret")
         cmd = [sys.executable, "-m", "drand_tpu.cli", *args]
         r = subprocess.run(cmd, capture_output=True, text=True,
                            timeout=timeout, env=env, cwd=self.repo)
@@ -80,8 +91,7 @@ class Node:
         return r.stdout
 
     def start(self):
-        env = dict(os.environ, PYTHONPATH=self.repo, JAX_PLATFORMS="cpu",
-                   JAX_COMPILATION_CACHE_DIR="/tmp/drand_tpu_jax_cache")
+        env = _child_env(self.repo)
         args = [sys.executable, "-m", "drand_tpu.cli", "start",
                 "--folder", self.folder, "--control", str(self.control),
                 "--private-listen", self.private_addr]
@@ -186,9 +196,8 @@ class Orchestrator:
         procs = []
 
         def _env(nd):
-            return dict(os.environ, PYTHONPATH=nd.repo, JAX_PLATFORMS="cpu",
-                        JAX_COMPILATION_CACHE_DIR="/tmp/drand_tpu_jax_cache",
-                        DRAND_SHARE_SECRET="demo-orchestrator-secret")
+            return _child_env(
+                nd.repo, DRAND_SHARE_SECRET="demo-orchestrator-secret")
 
         def _share_flags(nd):
             # non-TLS nets must say so (share's leader_tls defaults on,

@@ -79,11 +79,8 @@ def device_crypto_enabled() -> bool:
         return False
     if os.environ.get("DRAND_TPU_DEVICE_CRYPTO"):
         return True
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    import jax
+    return jax.default_backend() == "tpu"
 
 
 def make_backend(pub_poly, threshold: int, n: int):

@@ -66,16 +66,11 @@ class ChainVerifier:
         the batch shards over a 1-D round-axis mesh (ShardedVerifier), so
         catch-up sync and check-chain scale with chips (SURVEY.md §5.8)."""
         if self._lazy_verifier is None:
+            import jax
             v = Verifier(self._pk_point, self.scheme.shape)
-            try:
-                import jax
-                if len(jax.devices()) > 1:
-                    from drand_tpu.parallel import ShardedVerifier
-                    v = ShardedVerifier(v)
-            except Exception:
-                dlog.get("chain").exception(
-                    "multi-device sharding unavailable; verification "
-                    "falls back to a single device")
+            if len(jax.devices()) > 1:
+                from drand_tpu.parallel import ShardedVerifier
+                v = ShardedVerifier(v)
             self._lazy_verifier = v
         return self._lazy_verifier
 

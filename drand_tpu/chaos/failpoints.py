@@ -79,7 +79,7 @@ SITES: dict[str, str] = {
                         "(relay/gossip.py); ctx: src, dst",
     "warm.stage_exec":  "one warm-pipeline stage attempt before its "
                         "subprocess spawns (warm/runner.py); error = a "
-                        "tunnel-drop-shaped transient the RetryPolicy "
+                        "dropped-connection-shaped transient the RetryPolicy "
                         "must recover; ctx: pipeline, stage, attempt",
     "probe.sample":     "one consistency-probe signature sample "
                         "(observatory/consistency.py); drop = probe "

@@ -1248,23 +1248,12 @@ _COMMANDS = {
 
 
 def _ensure_jax_backend() -> None:
-    """Fall back to the CPU backend when the configured platform is
-    unavailable (e.g. JAX_PLATFORMS points at a TPU plugin that isn't on
-    this operator machine).  The daemon's live protocol path runs on host
-    crypto; the device kernels only accelerate batch verification, and
-    XLA:CPU serves those fine."""
-    try:
-        import jax
-        jax.devices()
-    except Exception:
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
-        try:
-            jax.config.update("jax_platforms", "cpu")
-            jax.devices()
-        except Exception as exc:  # pragma: no cover
-            print(f"warning: no usable JAX backend ({exc}); "
-                  "batch verification disabled", file=sys.stderr)
+    """Bring the JAX backend up before any command uses it, and fail
+    there if it cannot be.  An operator without an accelerator starts
+    the daemon with `JAX_PLATFORMS=cpu`; a daemon started for a chip it
+    cannot reach must not carry on silently on the CPU."""
+    import jax
+    jax.devices()
 
 
 # commands that touch the JAX device path (daemon verification, client

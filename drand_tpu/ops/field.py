@@ -84,18 +84,18 @@ def tail_segments(bits: str):
 
 
 def compact_graphs() -> bool:
-    """Compile-lean mode (`DRAND_TPU_COMPACT=1`): every ladder traces as
-    ONE dense masked per-bit scan instead of the static segment unroll.
-    The graph shrinks ~10x (the full verify drops from ~550k to tens of
-    thousands of HLO ops) at the cost of executing masked-away add steps
-    — the right trade wherever compile/load time is the budget (the
-    driver's CPU dryrun and single-chip compile check), and the wrong one
-    on the TPU throughput path, which keeps the static segmentation.
+    """Compile-lean mode: every ladder traces as ONE dense masked per-bit
+    scan instead of the static segment unroll.  The graph shrinks ~10x
+    (the full verify drops from ~550k to tens of thousands of HLO ops, and
+    from 655 Pallas call sites to 152) at the cost of executing
+    masked-away add steps — the right trade wherever compile/load time is
+    the budget.
 
-    Read at TRACE time.  Scope it with `compact_scope()` rather than
-    mutating the environment: a leaked global flag would silently trace
-    every later graph in the process compact (drand_tpu.aot keys entries
-    by this flag, but throughput would still quietly drop ~10x)."""
+    Read at TRACE time: the innermost `compact_scope()` decides, and
+    outside any scope `DRAND_TPU_COMPACT=1` does."""
+    scoped = _COMPACT.get()
+    if scoped is not None:
+        return scoped
     return bool(os.environ.get("DRAND_TPU_COMPACT"))
 
 
@@ -125,34 +125,28 @@ def miller_path_tag() -> str:
     return f"miller{int(miller_merged())}{int(line_merge_enabled())}"
 
 
-import contextlib  # noqa: E402  (kept beside its sole user)
+import contextlib  # noqa: E402  (kept beside their sole user)
+import contextvars  # noqa: E402
 
-
-_COMPACT_LOCK = __import__("threading").RLock()   # nesting is legal
+_COMPACT = contextvars.ContextVar("drand_tpu_compact", default=None)
 
 
 @contextlib.contextmanager
-def compact_scope():
-    """Trace the enclosed graph(s) in compact mode, then restore.
+def compact_scope(compact: bool = True):
+    """Trace the enclosed graph(s) in compact mode (or, with False, in
+    the static-unroll mode), then restore.
 
-    The flag is read at TRACE time from a process-global, so the scope is
-    serialized under a lock (two threads interleaving enter/exit would
-    leak compact mode into a throughput trace — a silent ~10x slowdown
-    for every later same-shape caller; the lock makes concurrent misuse
-    block instead of corrupt).  Intended users are the driver entry
-    points (__graft_entry__) and tests; the AOT cache keys executables by
-    this flag so a compact executable is never served to a throughput
-    caller (aot.cache_path)."""
-    with _COMPACT_LOCK:
-        old = os.environ.get("DRAND_TPU_COMPACT")
-        os.environ["DRAND_TPU_COMPACT"] = "1"
-        try:
-            yield
-        finally:
-            if old is None:
-                os.environ.pop("DRAND_TPU_COMPACT", None)
-            else:
-                os.environ["DRAND_TPU_COMPACT"] = old
+    The mode is an argument of whoever traces, carried by a context
+    variable: it is local to the tracing thread, so concurrent traces in
+    different modes cannot leak into each other.  Callers:
+    `Verifier._run_fn(compact=...)`, the driver entry points
+    (__graft_entry__) and tests.  The serialized-executable cache keys
+    entries by this mode (aot.cache_path)."""
+    token = _COMPACT.set(compact)
+    try:
+        yield
+    finally:
+        _COMPACT.reset(token)
 
 
 def _repunit_plan(lengths, seeds):

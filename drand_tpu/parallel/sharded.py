@@ -49,18 +49,16 @@ class ShardedVerifier:
     def _sharded_kernel(self, m: int):
         """The verify body compiled with explicit mesh in/out shardings.
 
-        Verifier._kernel's executables (AOT-loaded or compiled fresh) are
-        lowered from sharding-less single-device ShapeDtypeStructs: a
-        `Compiled` does not re-specialize, so calling one with
-        NamedSharding multi-device inputs either fails or (through the
-        AOT path's committed-input wrapper) silently device_puts the
-        shards back to one device, de-sharding the throughput path.  The
-        multi-device path therefore compiles its own kernels, keyed by
-        batch size (mesh/axis are fixed per ShardedVerifier), and
-        persists them through the same serialized-executable cache as the
-        single-device path so a node restart loads instead of recompiling
-        (the mesh shape is part of the cache name; aot's env tag already
-        pins platform + device count)."""
+        Verifier._kernel's executables are lowered from sharding-less
+        single-device ShapeDtypeStructs: a `Compiled` does not
+        re-specialize, so calling one with NamedSharding multi-device
+        inputs either fails or silently de-shards the throughput path.
+        The multi-device path therefore compiles its own kernels, keyed
+        by batch size (mesh/axis are fixed per ShardedVerifier).  On the
+        CPU tier they persist through the same serialized-executable
+        cache as the single-device path (the mesh shape is part of the
+        cache name; aot's env tag already pins platform + device count);
+        on the TPU JAX's persistent cache is the only one."""
         cache = getattr(self, "_skernels", None)
         if cache is None:
             cache = self._skernels = {}
@@ -69,18 +67,31 @@ class ShardedVerifier:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             from drand_tpu import aot
+            from drand_tpu.ops.pallas_field import use_pallas
 
+            cpu_tier = not use_pallas()
             name = (f"sharded-{self.axis}{self.n_dev}-"
                     f"{self.verifier._aot_name(m)}")
-            fn = aot.load(name)
+            fn = aot.load(name) if cpu_tier else None
             if fn is None:
                 shard_in = NamedSharding(self.mesh, P(self.axis, None))
                 out_sh = NamedSharding(self.mesh, P(self.axis))
                 repl = NamedSharding(self.mesh, P())
                 pk_sh = jax.tree_util.tree_map(lambda _: repl,
                                                self.verifier._pk)
+                # shard_map, not sharding propagation: every device runs
+                # the whole body on its slice of the round axis (rounds
+                # are independent), and the TPU's compiler refuses to
+                # partition a Pallas kernel by itself ("Mosaic kernels
+                # cannot be automatically partitioned")
+                body = jax.shard_map(
+                    self._run_fn(), mesh=self.mesh,
+                    in_specs=(P(self.axis, None), P(self.axis, None),
+                              jax.tree_util.tree_map(lambda _: P(),
+                                                     self.verifier._pk)),
+                    out_specs=P(self.axis), check_vma=False)
                 fn = jax.jit(
-                    self._run_fn(),
+                    body,
                     in_shardings=(shard_in, shard_in, pk_sh),
                     out_shardings=out_sh,
                 ).lower(
@@ -89,13 +100,14 @@ class ShardedVerifier:
                     jax.ShapeDtypeStruct((m, self.verifier.shape.sig_len),
                                          "uint8"),
                     self.verifier._pk_struct()).compile()
-                try:
-                    aot.save(name, fn)
-                except Exception as e:
-                    import sys
-                    print(f"drand_tpu.aot: sharded kernel save failed "
-                          f"({type(e).__name__}: {e}); continuing without "
-                          "persistence", file=sys.stderr)
+                if cpu_tier:
+                    try:
+                        aot.save(name, fn)
+                    except Exception as e:
+                        import sys
+                        print(f"drand_tpu.aot: sharded kernel save failed "
+                              f"({type(e).__name__}: {e}); continuing "
+                              "without persistence", file=sys.stderr)
             cache[m] = fn
         return cache[m]
 

@@ -119,7 +119,7 @@ def check_backend(probe=None) -> CheckResult:
             "backend", False,
             f"backend init did not answer within {BACKEND_TIMEOUT_S:.0f}s "
             f"(JAX_PLATFORMS={requested or 'unset'}) — unreachable device "
-            "or hung tunnel")
+            "or hung backend")
     except Exception as exc:
         return CheckResult("backend", False, f"backend probe failed: {exc}")
     init_s = float(info.get("init_s", wall))

@@ -8,7 +8,7 @@
 #   parity, g1, single, multichain
 # — but orchestrated: environment preflight (doctor) before anything
 # runs, per-stage timeouts and auto-retry on transient failures
-# (tunnel drops, environment resets), checkpointed state in
+# (dropped connections, environment resets), checkpointed state in
 # warm_logs/state.json, heartbeat progress lines, and per-stage
 # spans/metrics.
 #

@@ -5,7 +5,7 @@ Drives the tiny CPU-only `smoke3` spec end-to-end through the real CLI:
 
   1. `warm run smoke3` launched with WARM_SMOKE_HANG_S so stage s2
      hangs in its subprocess, then the WHOLE orchestrator is killed
-     with SIGKILL mid-stage — the tunnel-drop/environment-reset shape
+     with SIGKILL mid-stage — the dropped-connection/environment-reset shape
      that used to cost a human relaunch;
   2. `warm status` must show s1 done / s2 torn mid-flight from the
      byte-stable state.json checkpoint;

@@ -91,12 +91,15 @@ def sim_kernels(tile=8, row=(1, 8)):
     with a tiny tile (mirrors the interp fixture's shape overrides)."""
     from drand_tpu.ops import pallas_field as PFm
     orig_call, orig_tile, orig_row = PFm.pl.pallas_call, PFm.TILE, PFm._ROW
+    orig_jit = PFm._jit
     PFm.pl.pallas_call = sim_pallas_call
+    PFm._jit = lambda fn: fn        # the simulator runs eagerly
     PFm.TILE, PFm._ROW = tile, row
     PFm._CACHE.clear()
     try:
         yield
     finally:
         PFm.pl.pallas_call = orig_call
+        PFm._jit = orig_jit
         PFm.TILE, PFm._ROW = orig_tile, orig_row
         PFm._CACHE.clear()

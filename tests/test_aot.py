@@ -168,16 +168,28 @@ def test_cache_metrics_hit_miss_compile(tmp_path, monkeypatch):
         == err0 + 1
 
 
-def test_enable_persistent_cache_cpu_tier(tmp_path):
-    """On the CPU backend the persistent compilation cache is enabled
-    and pointed at the shared dir (the warm dryrun stage's env rides
-    the same path via {jax_cache} substitution)."""
-    d = aot.enable_persistent_cache(str(tmp_path / "cache"))
-    assert d == str(tmp_path / "cache")
-    assert jax.config.jax_compilation_cache_dir == d
-    # restore the suite-wide cache dir (tests/conftest.py)
-    jax.config.update("jax_compilation_cache_dir",
-                      "/tmp/drand_tpu_jax_cache")
+def test_enable_persistent_cache_follows_env(tmp_path, monkeypatch):
+    """One function decides the directory: JAX_COMPILATION_CACHE_DIR
+    where set, else the fixed git-ignored `.jax_cache` in the checkout
+    (never /tmp, a pid or a time); and it is enabled on this backend."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "cache"))
+        d = aot.enable_persistent_cache()
+        assert d == str(tmp_path / "cache") == aot.persistent_cache_dir()
+        assert jax.config.jax_compilation_cache_dir == d
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert aot.persistent_cache_dir() == os.path.join(repo, ".jax_cache")
+        assert aot.enable_persistent_cache() == \
+            jax.config.jax_compilation_cache_dir == \
+            os.path.join(repo, ".jax_cache")
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+    finally:
+        # restore the suite-wide cache dir (tests/conftest.py)
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 _PROBE = """

@@ -1,0 +1,162 @@
+"""CPU rehearsal of `chip_smoke.py`'s control flow.
+
+The smoke itself proves the TPU path and refuses every other platform, so
+here (a) the script, run as the driver runs it but on the CPU, must exit
+non-zero with `"ok": false` and a reason that names the platform, and (b)
+its catch-up, corrupted-signature and host-agreement checks must hold at
+64 rounds when the platform checks are stubbed IN THE TEST and the device
+program is replaced by a host-backed one that gives real verdicts (the
+XLA:CPU compile of the whole verify graph takes many minutes and is not
+what this file is about).  The program has no option for any of this.
+"""
+
+import asyncio
+import hashlib
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+
+_real_devices = jax.devices
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+
+def _json_lines(text: str) -> list[dict]:
+    return [json.loads(l) for l in text.splitlines() if l.startswith("{")]
+
+
+def test_on_the_cpu_the_script_fails_and_names_the_platform():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO,
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode != 0
+    last = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False
+    assert "'cpu'" in last["reason"] and "tpu" in last["reason"]
+    assert "device" not in last          # no result is printed
+
+
+class _NoKernels:
+    """The lowered stage of a program that holds no Pallas kernel."""
+
+    def as_text(self):
+        return ""
+
+
+def _host_backed_build(honest: bool):
+    """A stand-in for `Verifier.build` whose program checks every
+    (message, signature) on the host tier; `honest=False` makes it say
+    yes to everything, which is the fault the smoke must catch."""
+    from drand_tpu import native
+    from drand_tpu.crypto import sign as S
+    from drand_tpu.crypto.bls12381 import curve as GC
+
+    def build(self, n):
+        pk = GC.g1_to_bytes(self._pk_golden)
+
+        def one(msg, sig):
+            digest = hashlib.sha256(bytes(msg)).digest()
+            if native.available():
+                return native.verify_g2(pk, digest, bytes(sig),
+                                        self.shape.dst)
+            return S.bls_verify(self._pk_golden, digest, bytes(sig))
+
+        def fn(msgs, sigs, _pk):
+            msgs, sigs = np.asarray(msgs), np.asarray(sigs)
+            if not honest:
+                return np.ones(len(msgs), dtype=bool)
+            verdicts = {}         # padding repeats rows: check each once
+            for m, s in zip(msgs, sigs):
+                key = (bytes(m), bytes(s))
+                if key not in verdicts:
+                    verdicts[key] = one(m, s)
+            return np.array([verdicts[(bytes(m), bytes(s))]
+                             for m, s in zip(msgs, sigs)], dtype=bool)
+
+        self._kernels[n] = fn
+        return {"program": self._aot_name(n), "bucket": n,
+                "tracing": "host-stub", "trace_s": 0.0, "lower_s": 0.0,
+                "compile_s": 0.0, "lowered": _NoKernels()}
+
+    return build
+
+
+@pytest.fixture()
+def smoke_on_cpu(monkeypatch):
+    import chip_smoke
+    import drand_tpu.verify as V
+    monkeypatch.setattr(chip_smoke, "check_device", lambda dev: None)
+    monkeypatch.setattr(chip_smoke, "check_program", lambda rec: None)
+    monkeypatch.setattr(V, "_BUCKETS", V._BUCKETS)   # smoke() narrows it
+    # the suite runs on 8 virtual devices; the one-chip smoke is one device
+    monkeypatch.setattr(jax, "devices", lambda: _real_devices()[:1])
+    return chip_smoke
+
+
+def test_catch_up_and_corruption_checks_hold_at_64_rounds(
+        smoke_on_cpu, monkeypatch, capsys):
+    import drand_tpu.verify as V
+    monkeypatch.setattr(V.Verifier, "build", _host_backed_build(True))
+    device = asyncio.run(smoke_on_cpu.smoke(backlog=64))
+    assert device["platform"] == "cpu"        # main() is what refuses it
+    lines = _json_lines(capsys.readouterr().out)
+    runs = {l["catch_up"]: l for l in lines if "catch_up" in l}
+    assert runs["clean"]["sync_ok"] and \
+        runs["clean"]["committed_rounds"] == 64
+    assert not runs["corrupted"]["sync_ok"]
+    assert runs["corrupted"]["committed_rounds"] < 40     # 64 * 5 // 8
+    for hv in (l["host_vs_device"] for l in lines if "host_vs_device" in l):
+        assert hv["host_true"] == hv["device_true"] == hv["rounds"] - 1
+        assert not hv["host_on_corrupted"] and not hv["device_on_corrupted"]
+    loads = [l["program"]["load"] for l in lines if "program" in l]
+    assert loads == ["first build", "second build, fresh Verifier"]
+
+
+def test_a_consumer_that_commits_past_a_corrupted_round_fails_the_smoke(
+        smoke_on_cpu, monkeypatch, capsys):
+    import drand_tpu.verify as V
+    monkeypatch.setattr(V.Verifier, "build", _host_backed_build(False))
+    with pytest.raises(smoke_on_cpu.SmokeFailure, match="corrupted round"):
+        asyncio.run(smoke_on_cpu.smoke(backlog=64))
+
+
+def test_four_chips_control_flow_on_four_virtual_devices(
+        smoke_on_cpu, monkeypatch, capsys):
+    """`--four-chips` on four of the suite's virtual CPU devices, with the
+    verify body replaced by a traceable stand-in that rejects exactly the
+    round the smoke corrupts (round batch/2 + 1): the sharded path is
+    taken, every device holds a shard, verdicts agree."""
+    import jax.numpy as jnp
+
+    import drand_tpu.verify as V
+    batch = 16384
+    bad = batch // 2 + 1
+
+    def fake_run_fn(self, compact=None):
+        def run(msgs, sigs, pk):
+            low = msgs[..., 6].astype(jnp.int32) * 256 + msgs[..., 7]
+            return low != bad
+        return run
+
+    monkeypatch.setattr(V.Verifier, "_run_fn", fake_run_fn)
+    monkeypatch.setattr(jax, "devices", lambda: _real_devices()[:4])
+    # the CPU tier would serialize the stand-in programs into aot/
+    monkeypatch.setattr("drand_tpu.aot.load", lambda name, extra="": None)
+    monkeypatch.setattr("drand_tpu.aot.save", lambda *a, **k: "")
+    monkeypatch.setattr(V, "_BUCKETS", (8, 64, 512, 4096, 16384))
+    device = smoke_on_cpu.four_chips(batch)
+    assert device["count"] == 4
+    out = [l["four_chips"] for l in _json_lines(capsys.readouterr().out)
+           if "four_chips" in l][0]
+    assert len(out["devices_holding_a_shard"]) == 4
+    assert out["rows_per_shard"] == [batch // 4]
+    assert out["sharded_true"] == out["one_device_true"] == batch - 1

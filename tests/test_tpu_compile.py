@@ -1,0 +1,110 @@
+"""The Pallas kernels of the verify path compile for a TPU v5e.
+
+Interpret-mode KATs (test_sim_kats.py) say the kernels compute the right
+thing; they cannot say that the chip's compiler accepts them.  PR 22 found
+the two merged Miller kernels refused (`RESOURCE_EXHAUSTED: Ran out of
+memory in memory space vmem ... Scoped allocation with size 19.78M and
+limit 16.00M`, and 23.29M for the add step) after every KAT had passed
+since PR 9.  The TPU's compiler is installed here and compiles for a chip
+that is described and not attached, so these tests compile each kernel for
+a described `v5e:2x2`, one tile (1,024 elements) per call, which is the
+shape the 512 bucket gives; a kernel's VMEM need is per grid step and does
+not grow with the tile count.  Nothing runs: a compile that passes is not
+a chip run.
+
+Left out for time (each was compiled once by hand for PR 22, both at one
+tile and at the 16 tiles of the 16,384 bucket; seconds in CHANGES.md):
+`mont_reduce`, `flat_mul` (dense and sparse), `fp2_sqr5_mul`/`sqr4_mul`
+and the `sqr_chain_mul` family, `g2_add_line`, `g2_point_dbl`/
+`g2_point_add`, `line_merge`, `flat_conj`/`flat_frob`, and the wider
+`fp2_products`/`fp2_sqrs` stackings.  The two Miller kernels stay in
+although each takes minutes: they are the ones the compiler refused.
+
+As the on-chip-measurement guide sets out: the topology is described
+inside a module-scoped fixture that skips where it cannot be, nothing
+touches the TPU library while a module is imported, the persistent cache
+is off around the compiles, and they run in this process.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from drand_tpu.crypto.bls12381.constants import P
+from drand_tpu.ops import pallas_field as PFm
+
+NT = 1                                   # tiles per call
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """SingleDeviceSharding on the first chip of a described v5e:2x2,
+    with JAX's persistent cache off while the module's tests run (a
+    compile for a described chip is written to it but cannot be read
+    back without the chip)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _tf(tiles):
+    return PFm.TileForm(tiles, (NT * PFm.TILE,), NT * PFm.TILE)
+
+
+def _coords(tiles, n):
+    """n plain [..., 32] coordinate arrays out of one packed operand."""
+    return PFm.pallas_field(P).unpack_coords(_tf(tiles), n)
+
+
+def _g2_dbl_line(pf, t):
+    c = _coords(t, 8)
+    T2, line = pf.g2_dbl_line(((c[0], c[1]), (c[2], c[3]), (c[4], c[5])),
+                              c[6], c[7])
+    return T2, line
+
+
+# name -> (function of the PallasField and tile operands, limb rows of
+# each operand)
+KERNELS = {
+    "mont_mul": (lambda pf, a, b: pf.mont_mul(_tf(a), _tf(b)).tiles,
+                 (32, 32)),
+    "mont_sqr": (lambda pf, a: pf.mont_sqr(_tf(a)).tiles, (32,)),
+    "fp2_products": (
+        lambda pf, a, b: pf.fp2_products([(_tf(a), _tf(b))])[0].tiles,
+        (64, 64)),
+    "fp2_sqrs": (lambda pf, a: pf.fp2_sqrs([_tf(a)])[0].tiles, (64,)),
+    "flat_sqr": (lambda pf, a: pf.flat_sqr(_tf(a)).tiles, (384,)),
+    "cyclo_sqr": (lambda pf, a: pf.cyclo_sqr(_tf(a)).tiles, (384,)),
+    "g2_dbl_line": (_g2_dbl_line, (256,)),
+    "miller_dbl_iter": (
+        lambda pf, f, t, p, m: [o.tiles for o in pf.miller_dbl_iter(
+            _tf(f), _tf(t), _tf(p), _tf(m))],
+        (384, 384, 128, 2)),
+    "miller_add_iter": (
+        lambda pf, f, t, q, p, m: [o.tiles for o in pf.miller_add_iter(
+            _tf(f), _tf(t), _tf(q), _tf(p), _tf(m))],
+        (384, 384, 256, 128, 2)),
+}
+
+
+@pytest.mark.parametrize("name", list(KERNELS))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, limbs = KERNELS[name]
+    pf = PFm.pallas_field(P)
+    args = [jax.ShapeDtypeStruct((NT, l, *PFm._ROW), jnp.int32,
+                                 sharding=one_chip) for l in limbs]
+    lowered = jax.jit(lambda *a: fn(pf, *a)).lower(*args)
+    assert "tpu_custom_call" in lowered.as_text(), \
+        f"{name} lowered without a Pallas kernel"
+    lowered.compile()      # raises what the chip's compiler would raise

@@ -183,16 +183,19 @@ def _dump_rows(db_path: str):
         con.close()
 
 
-async def _one_epoch(addr: str, verifier, rounds: int, wire_chunk: int,
-                     consumer_codec: str | None):
+async def catch_up(addr: str, verifier, rounds: int,
+                   consumer_codec: str | None = None):
     """One fresh-store catch-up of `rounds` rounds through the real
-    client; returns (elapsed_s, stats, consumer_db_path)."""
+    client stack (GrpcBeaconNetwork.sync_chain -> SyncManager ->
+    verifier -> store commit); returns (ok, elapsed_s, stats,
+    consumer_db_path, last_committed_round).  Shared with chip_smoke.py,
+    which also drives the pass that must FAIL (a corrupted served
+    signature), so nothing is asserted here."""
     from drand_tpu.beacon.sync_manager import SyncManager, SyncRequest
     from drand_tpu.chain.beacon import Beacon
     from drand_tpu.chain.store import new_chain_store
     from drand_tpu.net.client import GrpcBeaconNetwork, PeerClients
 
-    os.environ[WIRE_ENV] = str(wire_chunk)
     if consumer_codec:
         os.environ[CODEC_ENV] = consumer_codec
     folder = tempfile.mkdtemp(prefix="bench-sync-")
@@ -208,13 +211,26 @@ async def _one_epoch(addr: str, verifier, rounds: int, wire_chunk: int,
     sm = SyncManager(store, _Group(), verifier, net, [peer], _Clock(),
                      insecure_store=store.insecure)
     t0 = time.perf_counter()
-    ok = await sm._try_node(peer, SyncRequest(1, rounds))
-    elapsed = time.perf_counter() - t0
+    try:
+        ok = await sm._try_node(peer, SyncRequest(1, rounds))
+        elapsed = time.perf_counter() - t0
+        last = store.last().round
+    finally:
+        store.close()
+        await peers.close()
+    return ok, elapsed, dict(sm.stats), db_path, last
+
+
+async def _one_epoch(addr: str, verifier, rounds: int, wire_chunk: int,
+                     consumer_codec: str | None):
+    """One timed catch-up that must succeed; returns (elapsed_s, stats,
+    consumer_db_path)."""
+    os.environ[WIRE_ENV] = str(wire_chunk)
+    ok, elapsed, stats, db_path, last = await catch_up(
+        addr, verifier, rounds, consumer_codec)
     assert ok, "sync must succeed"
-    assert store.last().round == rounds, store.last().round
-    store.close()
-    await peers.close()
-    return elapsed, dict(sm.stats), db_path
+    assert last == rounds, last
+    return elapsed, stats, db_path
 
 
 async def _run_pass(addr: str, verifier, rounds: int, epochs: int,
@@ -389,6 +405,22 @@ async def _main_object(args, sigs, verifier) -> dict:
     return report
 
 
+def real_fixture(backlog: int):
+    """(sigs[backlog, 96], ChainVerifier) of the real scheme
+    `pedersen-bls-unchained` under the fixture key: rounds 1..16384 from
+    the committed bench fixture, the rest from its committed
+    `_extend_chain_native` extension (signed afresh only when absent)."""
+    import bench  # noqa: E402  (repo root on path)
+    from drand_tpu.chain.scheme import scheme_by_id
+    from drand_tpu.chain.verify import ChainVerifier
+    from drand_tpu.crypto.bls12381 import curve as GC
+    sk, pk, shape, sigs = bench._chain_fixture("unchained", 16384)
+    pk_tag = hashlib.sha256(GC.g1_to_bytes(pk)).hexdigest()[:8]
+    sigs = _extend_chain_native(sk, shape, sigs, backlog, pk_tag)
+    return sigs, ChainVerifier(scheme_by_id(_Group.scheme_id),
+                               GC.g1_to_bytes(pk))
+
+
 async def _main(args) -> dict:
     from drand_tpu.chain.beacon import Beacon
 
@@ -397,15 +429,8 @@ async def _main(args) -> dict:
                                   _StubVerifier())
     if args.mode == "real":
         import bench  # noqa: E402  (repo root on path)
-        from drand_tpu.chain.scheme import scheme_by_id
-        from drand_tpu.chain.verify import ChainVerifier
-        from drand_tpu.crypto.bls12381 import curve as GC
         bench._setup_jax()
-        sk, pk, shape, sigs = bench._chain_fixture("unchained", 16384)
-        pk_tag = hashlib.sha256(GC.g1_to_bytes(pk)).hexdigest()[:8]
-        sigs = _extend_chain_native(sk, shape, sigs, BACKLOG, pk_tag)
-        verifier = ChainVerifier(scheme_by_id(_Group.scheme_id),
-                                 GC.g1_to_bytes(pk))
+        sigs, verifier = real_fixture(BACKLOG)
         import jax
         device = str(jax.devices()[0].platform)
     else:
