@@ -23,6 +23,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from drand_tpu import log as dlog
+from drand_tpu import tracing
 from drand_tpu.chain.beacon import Beacon
 from drand_tpu.chain.segment import PackedBeacons, pack_rows
 from drand_tpu.chain.store import BeaconNotFound, StoreError
@@ -132,9 +133,31 @@ class _CatchupPipeline:
         (later segments are discarded, not settled);
       - a commit/dispatch error is re-raised to the caller after the
         stages drain.
+
+    Spans (one trace a catch-up, under `SyncManager._try_node`'s
+    `sync.catchup`): every flushed segment is a `sync.segment` from its
+    flush to the end of its commit, with children `sync.queue_wait`
+    (attribute `stage`: enqueue to dequeue in that stage's queue, and the
+    queue's depth at the enqueue), `sync.backpressure` (only where a
+    full queue made the producer wait), `sync.pack`, the verifier's
+    `verify.segment` and `verify.dispatch`, `sync.settle` (the same two
+    clock reads as `stats["verify_s"]`) over `verify.resolve`,
+    `store.materialize` and the store's `store.commit`.  Never a span a
+    round.
     """
 
     _CLOSE = object()
+
+    @dataclass
+    class _Work:
+        """One flushed segment on its way through the stages."""
+        items: list
+        anchor_sig: bytes
+        span: tracing.Span           # sync.segment; ends when it leaves
+        queued_at: float = 0.0       # perf_counter at the last enqueue
+        depth: int = 0               # that queue's depth at that enqueue
+        seg: object = None           # coalesced by the pack stage
+        resolver: object = None
 
     def __init__(self, manager, up_to: int):
         self.m = manager
@@ -155,10 +178,36 @@ class _CatchupPipeline:
     def broken(self) -> bool:
         return self.failure or self.error is not None
 
-    async def submit(self, items: list, anchor_sig: bytes) -> None:
-        """Hand a flushed segment to the pack stage.  Backpressure: a
-        full queue blocks the fetch loop, bounding in-flight memory."""
-        await self._q_verify.put((items, anchor_sig))
+    async def _enqueue(self, queue: asyncio.Queue, work, stage: str) -> None:
+        """Backpressure: a full queue blocks the producer (the fetch
+        loop, the pack stage), bounding in-flight memory."""
+        work.depth = queue.qsize()
+        began = work.queued_at = time.perf_counter()
+        if queue.full():
+            await queue.put(work)
+            # no other task runs between the put and this line
+            work.queued_at = time.perf_counter()
+            tracing.record_span("sync.backpressure", began, work.queued_at,
+                                parent=work.span, stage=stage)
+        else:
+            queue.put_nowait(work)
+
+    def _dequeued(self, work, stage: str) -> float:
+        """Note how long `work` sat in `stage`'s queue; returns the clock
+        reading, which is also where the stage's own time starts."""
+        now = time.perf_counter()
+        tracing.record_span("sync.queue_wait", work.queued_at, now,
+                            parent=work.span, stage=stage, depth=work.depth)
+        return now
+
+    async def submit(self, items: list, anchor_sig: bytes,
+                     rounds: int) -> None:
+        """Hand a flushed segment of `rounds` rounds to the pack stage."""
+        sp = tracing.begin_span(  # lint: disable=span-balance
+            "sync.segment", first_round=_item_span(items[0])[0],
+            rounds=rounds)        # ended where the segment leaves a stage
+        await self._enqueue(self._q_verify,
+                            self._Work(items, anchor_sig, sp), "verify")
 
     async def close(self) -> None:
         """Drain both stages to completion (commits every segment still
@@ -196,60 +245,80 @@ class _CatchupPipeline:
                 prev = it.tail_sig
         return out
 
-    def _dispatch(self, items: list, anchor_sig: bytes):
-        seg = self._coalesce(items, anchor_sig)
+    def _dispatch(self, work) -> None:
+        """Worker thread, under the segment's span."""
+        t0 = time.perf_counter()
+        seg = self._coalesce(work.items, work.anchor_sig)
+        tracing.record_span("sync.pack", t0, time.perf_counter(),
+                            items=len(work.items))
         if isinstance(seg, list):
             resolver = self.m.verifier.verify_chain_segment_async(
-                seg, anchor_sig)
+                seg, work.anchor_sig)
         else:
             resolver = self.m.verifier.verify_packed_segment_async(
-                seg, anchor_sig)
-        return seg, resolver
+                seg, work.anchor_sig)
+        work.seg, work.resolver = seg, resolver
 
     async def _pack_loop(self) -> None:
         while True:
-            item = await self._q_verify.get()
-            if item is self._CLOSE:
+            work = await self._q_verify.get()
+            if work is self._CLOSE:
                 await self._q_commit.put(self._CLOSE)
                 return
+            t0 = self._dequeued(work, "verify")
             if self.broken:
-                continue                     # drain-and-discard
-            items, anchor_sig = item
-            t0 = time.perf_counter()
+                work.span.end("discarded")   # drain-and-discard
+                continue
             try:
-                seg, resolver = await asyncio.to_thread(
-                    self._dispatch, items, anchor_sig)
+                with tracing.under(work.span):
+                    await asyncio.to_thread(self._dispatch, work)
             except BaseException as exc:  # noqa: BLE001 — stage must drain
                 self.error = exc
+                work.span.end("error")
                 continue
             dt = time.perf_counter() - t0
             self.m.stats["pack_s"] += dt
             _observe_stage("pack", dt)
-            await self._q_commit.put((seg, anchor_sig, resolver))
+            await self._enqueue(self._q_commit, work, "commit")
 
     # -- settle/commit stage ------------------------------------------------
 
     def _commit(self, seg, anchor_sig: bytes) -> int:
-        beacons = seg if isinstance(seg, list) \
-            else seg.beacons(anchor_sig=anchor_sig)
+        """Worker thread, under the segment's span: the store's own
+        `store.commit` span becomes the segment's child."""
+        if isinstance(seg, list):
+            beacons = seg
+        else:
+            t0 = time.perf_counter()
+            beacons = seg.beacons(anchor_sig=anchor_sig)
+            tracing.record_span("store.materialize", t0,
+                                time.perf_counter(), rounds=len(beacons))
         self.m.store.put_many(beacons)
         return len(beacons)
 
     async def _settle_loop(self) -> None:
         while True:
-            item = await self._q_commit.get()
-            if item is self._CLOSE:
+            work = await self._q_commit.get()
+            if work is self._CLOSE:
                 return
+            t0 = self._dequeued(work, "commit")
             if self.broken:
+                work.span.end("discarded")
                 continue
-            seg, anchor_sig, resolver = item
-            t0 = time.perf_counter()
+            seg, anchor_sig = work.seg, work.anchor_sig
+            settle = tracing.begin_span("sync.settle", parent=work.span,
+                                        at=t0)
             try:
-                ok = np.asarray(await asyncio.to_thread(resolver))
+                with tracing.under(settle):
+                    ok = np.asarray(await asyncio.to_thread(work.resolver))
             except BaseException as exc:  # noqa: BLE001
                 self.error = exc
+                settle.end("error")
+                work.span.end("error")
                 continue
-            dt = time.perf_counter() - t0
+            t1 = time.perf_counter()
+            settle.end(at=t1)
+            dt = t1 - t0
             self.m.stats["verify_s"] += dt
             _observe_stage("verify", dt)
             if not bool(np.all(ok)):
@@ -260,14 +329,19 @@ class _CatchupPipeline:
                            for i in np.nonzero(~ok)[0][:5]]
                 log.warning("segment verify failed at rounds %s", bad)
                 self.failure = True
+                work.span.end("verify_failed")
                 continue
             t0 = time.perf_counter()
             try:
-                n = await asyncio.to_thread(self._commit, seg, anchor_sig)
+                with tracing.under(work.span):
+                    n = await asyncio.to_thread(self._commit, seg,
+                                                anchor_sig)
             except BaseException as exc:  # noqa: BLE001
                 self.error = exc
+                work.span.end("error")
                 continue
             dt = time.perf_counter() - t0
+            work.span.end()
             self.m.stats["commit_s"] += dt
             self.m.stats["segments"] += 1
             self.m.stats["rounds"] += n
@@ -410,6 +484,21 @@ class SyncManager:
         return [winner] + [p for p in peers if p is not winner]
 
     async def _try_node(self, peer, req: SyncRequest) -> bool:
+        """One catch-up from one peer, as one trace: the root span
+        `sync.catchup` over `_fetch_stage`.  The wire wait stays a
+        counter on it (`fetch_s`, `messages`): a catch-up makes a wait
+        for every message, which is no span each."""
+        before = dict(self.stats)
+        with tracing.span(
+                "sync.catchup", from_round=req.from_round, up_to=req.up_to,
+                peer=getattr(peer, "address", "") or str(peer)) as root:
+            try:
+                return await self._fetch_stage(peer, req, root)
+            finally:
+                root.set(**{k: self.stats[k] - before[k]
+                            for k in ("rounds", "segments", "fetch_s")})
+
+    async def _fetch_stage(self, peer, req: SyncRequest, root) -> bool:
         """Consume one peer's stream through the off-loop catch-up
         pipeline (tryNode, sync_manager.go:326-438 — rebuilt, ISSUE 13).
 
@@ -448,6 +537,7 @@ class SyncManager:
         pipe.start()
 
         fetch_acc = 0.0            # wire-wait seconds since the last flush
+        messages = 0               # stream items taken off the wire
 
         async def flush() -> None:
             """Hand the buffered run to the pipeline; advance the anchor."""
@@ -468,7 +558,7 @@ class SyncManager:
                                   round=last_r, batch=n)
             sig = anchor_sig
             anchor_round, anchor_sig = last_r, _item_tail_sig(seg[-1])
-            await pipe.submit(seg, sig)
+            await pipe.submit(seg, sig, n)
 
         gen = self.net.sync_chain(peer, from_round)
         stream = gen.__aiter__()
@@ -514,6 +604,7 @@ class SyncManager:
                     pending = None
                     break
                 pending = None
+                messages += 1
                 stall_at = self.clock.now() + STALL_FACTOR * self.group.period
                 first_r, last_r, n = _item_span(item)
                 expected = (_item_span(buffer[-1])[1] + 1 if buffer
@@ -552,6 +643,7 @@ class SyncManager:
             # data anchor and are safe to commit, and the pre-pipelining
             # loop would have committed them before reading further.
             # close() drains the pack and settle stages to completion.
+            root.set(messages=messages)
             if pending is not None:
                 pending.cancel()
             try:

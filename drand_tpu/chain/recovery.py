@@ -119,7 +119,26 @@ async def scan_store(store, verifier=None, *, beacon_id: str = "",
     stream through the batched device verifier in `segment_rounds`
     segments.  All sqlite reads and every potentially-blocking verifier
     dispatch happen in worker threads; the event loop stays live.
+
+    One trace a scan: the root `store.scan`; under it a `scan.read` for
+    every `raw_rows` batch and a `scan.decode` for that batch's per-row
+    loop (rows, corrupt and unlinked counted as attributes, never a span
+    a row); a `scan.flush` for every verified segment, holding
+    `scan.pack` (`pack_rows`), the verifier's own `verify.segment` and
+    `verify.dispatch`, and `scan.verify_wait` over its `verify.resolve`.
     """
+    from drand_tpu import tracing
+    with tracing.span("store.scan", beacon_id=beacon_id,
+                      verify=verifier is not None) as root:
+        report = await _scan_store(store, verifier, beacon_id,
+                                   segment_rounds, read_batch, on_progress)
+        root.set(scanned=report.scanned, flagged=len(report.damaged_rounds))
+    return report
+
+
+async def _scan_store(store, verifier, beacon_id: str, segment_rounds: int,
+                      read_batch: int, on_progress) -> IntegrityReport:
+    from drand_tpu import tracing
     t0 = time.perf_counter()
     report = IntegrityReport(beacon_id=beacon_id,
                              path=getattr(store, "path", ""),
@@ -128,67 +147,85 @@ async def scan_store(store, verifier=None, *, beacon_id: str = "",
     prev_good: tuple[int, bytes] | None = None   # (round, sig) last good row
     pending: list[tuple[int, bytes, bytes]] = []  # BLS backlog (r, sig, prev)
 
+    def verify_packed(item) -> np.ndarray:
+        """Worker thread: dispatch one packed segment and wait for it."""
+        # anchor = the row's own STORED prev: linkage against the
+        # actual predecessor sig was already judged structurally,
+        # so here the batch checks pure signature validity over
+        # exactly the bytes on disk
+        resolver = verifier.verify_packed_segment_async(item,
+                                                        item.first_prev)
+        with tracing.span("scan.verify_wait"):
+            return np.asarray(resolver())
+
     async def flush_bls() -> None:
         if verifier is None or not pending:
             return
-        singles: list = []
-        for item in pack_rows(pending, max_chunk=segment_rounds):
-            if isinstance(item, PackedBeacons):
-                # anchor = the row's own STORED prev: linkage against the
-                # actual predecessor sig was already judged structurally,
-                # so here the batch checks pure signature validity over
-                # exactly the bytes on disk
-                ok = await asyncio.to_thread(
-                    lambda it=item: np.asarray(
-                        verifier.verify_packed_segment_async(
-                            it, it.first_prev)()))
-                for i in np.nonzero(~ok)[0]:
-                    report.bad_sigs.append(int(item.start_round + int(i)))
-            else:
-                singles.append(item)
-        if singles:
-            ok = np.asarray(await asyncio.to_thread(
-                verifier.verify_beacons, singles))
-            for b, good in zip(singles, ok):
-                if not bool(good):
-                    report.bad_sigs.append(b.round)
+        with tracing.span("scan.flush", rows=len(pending)):
+            t0 = time.perf_counter()
+            items = list(pack_rows(pending, max_chunk=segment_rounds))
+            tracing.record_span("scan.pack", t0, time.perf_counter(),
+                                items=len(items))
+            singles: list = []
+            for item in items:
+                if isinstance(item, PackedBeacons):
+                    ok = await asyncio.to_thread(verify_packed, item)
+                    for i in np.nonzero(~ok)[0]:
+                        report.bad_sigs.append(
+                            int(item.start_round + int(i)))
+                else:
+                    singles.append(item)
+            if singles:
+                ok = np.asarray(await asyncio.to_thread(
+                    verifier.verify_beacons, singles))
+                for b, good in zip(singles, ok):
+                    if not bool(good):
+                        report.bad_sigs.append(b.round)
         pending.clear()
 
     next_round = GENESIS_ROUND
     while True:
+        t0 = time.perf_counter()
         rows = await asyncio.to_thread(store.raw_rows, next_round, read_batch)
+        tracing.record_span("scan.read", t0, time.perf_counter(),
+                            from_round=next_round, rows=len(rows))
         if not rows:
             break
-        for r, blob in rows:
-            report.scanned += 1
-            if report.first_round < 0:
-                report.first_round = r
-            report.tip_round = r
-            if expected is not None and r > expected:
-                report.missing.append((expected, r - 1))
-            expected = r + 1
-            try:
-                decoded_round, sig, prev = row_codec.decode_fields(blob)
-                if decoded_round != r:
-                    raise row_codec.CodecError(
-                        f"row decodes to round {decoded_round}")
-            except row_codec.CodecError:
-                report.corrupt.append(r)
-                prev_good = None
-                continue
-            if prev and prev_good is not None and prev_good[0] == r - 1 \
-                    and prev != prev_good[1]:
-                # the stored prev contradicts the actual predecessor sig:
-                # damage localized to THIS row (its sig may still be the
-                # true chain sig, so it stays a linkage anchor for r+1)
-                report.unlinked.append(r)
+        flagged = len(report.corrupt), len(report.unlinked)
+        with tracing.span("scan.decode", rows=len(rows)) as decode:
+            for r, blob in rows:
+                report.scanned += 1
+                if report.first_round < 0:
+                    report.first_round = r
+                report.tip_round = r
+                if expected is not None and r > expected:
+                    report.missing.append((expected, r - 1))
+                expected = r + 1
+                try:
+                    decoded_round, sig, prev = row_codec.decode_fields(blob)
+                    if decoded_round != r:
+                        raise row_codec.CodecError(
+                            f"row decodes to round {decoded_round}")
+                except row_codec.CodecError:
+                    report.corrupt.append(r)
+                    prev_good = None
+                    continue
+                if prev and prev_good is not None \
+                        and prev_good[0] == r - 1 and prev != prev_good[1]:
+                    # the stored prev contradicts the actual predecessor
+                    # sig: damage localized to THIS row (its sig may still
+                    # be the true chain sig, so it stays a linkage anchor
+                    # for r+1)
+                    report.unlinked.append(r)
+                    prev_good = (r, sig)
+                    continue
                 prev_good = (r, sig)
-                continue
-            prev_good = (r, sig)
-            if r != GENESIS_ROUND:       # genesis is an anchor, not a sig
-                pending.append((r, sig, prev))
-            if len(pending) >= segment_rounds:
-                await flush_bls()
+                if r != GENESIS_ROUND:   # genesis is an anchor, not a sig
+                    pending.append((r, sig, prev))
+                if len(pending) >= segment_rounds:
+                    await flush_bls()
+            decode.set(corrupt=len(report.corrupt) - flagged[0],
+                       unlinked=len(report.unlinked) - flagged[1])
         if on_progress is not None:
             on_progress(report.tip_round)
         next_round = rows[-1][0] + 1
@@ -242,15 +279,14 @@ def repair_store(store, report: IntegrityReport,
 async def startup_recovery(store, verifier, *, beacon_id: str = "",
                            segment_rounds: int = SCAN_SEGMENT_ROUNDS,
                            ) -> tuple[IntegrityReport, dict | None]:
-    """Boot-time scan + (if damaged) repair, with spans and the
-    `drand_store_integrity` gauge.  Returns (report, repair summary or
-    None).  The CALLER owns what follows a repair: rebuilding the
+    """Boot-time scan + (if damaged) repair, with the scan's and the
+    repair's spans and the `drand_store_integrity` gauge.  Returns
+    (report, repair summary or None).  The CALLER owns what follows a repair: rebuilding the
     engine over the rolled-back store and queueing the re-sync of
     `(verified_tip + 1 .. old tip)` from peers."""
     from drand_tpu import tracing
-    with tracing.span("store.scan", beacon_id=beacon_id):
-        report = await scan_store(store, verifier, beacon_id=beacon_id,
-                                  segment_rounds=segment_rounds)
+    report = await scan_store(store, verifier, beacon_id=beacon_id,
+                              segment_rounds=segment_rounds)
     try:
         from drand_tpu import metrics as M
         M.STORE_INTEGRITY.labels(beacon_id or "default").set(

@@ -189,14 +189,13 @@ class ChainVerifier:
         if not self.scheme.decouple_prev_sig:
             prev = np.stack([np.frombuffer(b.previous_sig, dtype=np.uint8)
                              for b in beacons])
-        # the span covers dispatch THROUGH resolve — exactly the window
-        # the device is busy — so its TraceAnnotation brackets the XLA
-        # ops in a /debug/jax-profile capture of the same window
+        # the span covers dispatch THROUGH resolve: it is closed on the
+        # resolving thread, so it writes nothing into a profiler capture
+        # (`tracing.clock_mark` ties its `start_mono` to one instead)
         from drand_tpu import tracing
         sp = tracing.begin_span(
             "verify.batch", beacon_id=self.beacon_id,
-            round_=int(beacons[-1].round), batch=len(beacons),
-            device=True)
+            round_=int(beacons[-1].round), batch=len(beacons))
         try:
             pending = self._verifier.verify_batch_async(rounds, sigs, prev)
         except Exception:

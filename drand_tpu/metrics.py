@@ -327,8 +327,8 @@ OBJECTSYNC_LAG = Gauge(
 # `_ratio` (unitless 0..1), same contract as the SLO attainment gauge.
 DISPATCH_SECONDS = Histogram(
     "drand_dispatch_seconds",
-    "Device-wall seconds of one batched dispatch, by seam and padded "
-    "bucket size",
+    "Host wall seconds around one batched dispatch (dispatch plus "
+    "blocking resolve; not device time), by seam and padded bucket size",
     ["seam", "bucket"], registry=REGISTRY,
     buckets=(.0005, .001, .0025, .005, .01, .025, .05, .1, .25, .5,
              1.0, 2.5, 5.0, 15.0))

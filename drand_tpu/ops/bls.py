@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from drand_tpu.crypto.bls12381.constants import P
+from drand_tpu.ops import H2C, SIG_DECODE
 from drand_tpu.ops import curve as DC
 from drand_tpu.ops import h2c as DH
 from drand_tpu.ops import pairing as DP
@@ -150,12 +151,14 @@ def verify_g2_sigs(msgs: jnp.ndarray, sig_bytes: jnp.ndarray, pk_aff, dst: bytes
     (reference: `key.Scheme.VerifyRecovered` at `chain/verify.go:44`).
     """
     shape = msgs.shape[:-1]
-    (sx, sy), s_inf, s_valid = g2_decompress(sig_bytes)
-    sig_jac = (sx, sy, T.fp2_broadcast(T.FP2_ONE, shape))
-    in_sub = DC.g2_in_subgroup(sig_jac)
+    with jax.named_scope(SIG_DECODE):
+        (sx, sy), s_inf, s_valid = g2_decompress(sig_bytes)
+        sig_jac = (sx, sy, T.fp2_broadcast(T.FP2_ONE, shape))
+        in_sub = DC.g2_in_subgroup(sig_jac)
 
-    h_jac = DH.hash_to_g2(msgs, dst)
-    (hx, hy), h_inf = DC.point_to_affine(h_jac, DC.Fp2Ops)
+    with jax.named_scope(H2C):
+        h_jac = DH.hash_to_g2(msgs, dst)
+        (hx, hy), h_inf = DC.point_to_affine(h_jac, DC.Fp2Ops)
 
     if neg_gen_aff is None:
         from drand_tpu.crypto.bls12381 import curve as GC
@@ -173,12 +176,14 @@ def verify_g1_sigs(msgs: jnp.ndarray, sig_bytes: jnp.ndarray, pk_g2_aff, dst: by
     scheme, BASELINE.md config 4).  Checks e(-sigma, g2) * e(H(m), pk) == 1.
     """
     shape = msgs.shape[:-1]
-    (sx, sy), s_inf, s_valid = g1_decompress(sig_bytes)
-    sig_jac = (sx, sy, jnp.broadcast_to(T.FP_ONE, shape + (N_LIMBS,)).astype(jnp.int32))
-    in_sub = DC.g1_in_subgroup(sig_jac)
+    with jax.named_scope(SIG_DECODE):
+        (sx, sy), s_inf, s_valid = g1_decompress(sig_bytes)
+        sig_jac = (sx, sy, jnp.broadcast_to(T.FP_ONE, shape + (N_LIMBS,)).astype(jnp.int32))
+        in_sub = DC.g1_in_subgroup(sig_jac)
 
-    h_jac = DH.hash_to_g1(msgs, dst)
-    (hx, hy), h_inf = DC.point_to_affine(h_jac, DC.FpOps)
+    with jax.named_scope(H2C):
+        h_jac = DH.hash_to_g1(msgs, dst)
+        (hx, hy), h_inf = DC.point_to_affine(h_jac, DC.FpOps)
 
     from drand_tpu.crypto.bls12381 import curve as GC
     g2_aff = _const_g2_affine(GC.G2_GEN)

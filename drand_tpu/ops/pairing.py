@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from drand_tpu.crypto.bls12381.constants import X as _BLS_X
+from drand_tpu.ops import FINAL_EXP, MILLER
 from drand_tpu.ops import flat12 as F
 from drand_tpu.ops import towers as T
 from drand_tpu.ops.field import FP
@@ -365,6 +366,8 @@ def pairing_check_pairs(pairs, active=None):
     the layout boundary once at flat_is_one — entry packs + exit mask
     instead of per-call relayout (flat_inv's tower evaluation is the one
     counted interior exception, once per check)."""
-    f = miller_loop_pairs(pairs, active,
-                          _keep_tiled=FP._pallas() is not None)
-    return F.flat_is_one(final_exp(f))
+    with jax.named_scope(MILLER):
+        f = miller_loop_pairs(pairs, active,
+                              _keep_tiled=FP._pallas() is not None)
+    with jax.named_scope(FINAL_EXP):
+        return F.flat_is_one(final_exp(f))

@@ -743,15 +743,21 @@ class PallasField:
 
         `kernel` is a bound method or a `functools.partial` of one over
         static (hashable) arguments; `call` is determined by the kernel
-        and the operand shapes, so those two key the wrapper."""
+        and the operand shapes, so those two key the wrapper.  The
+        method's name, less its `_kernel`, names the Pallas call: a
+        device trace then says `.../miller/.../mont_mul/pallas_call`
+        where the caller's `jax.named_scope` (ops.STAGES) gives the
+        stage; the stage is no part of the key."""
         if isinstance(kernel, functools.partial):
             key = (kernel.func.__name__, kernel.args)
         else:
             key = (kernel.__name__, ())
+        name = key[0].strip("_").removesuffix("_kernel")
         key += (tuple((a.shape, a.dtype.name) for a in args),)
         fn = self._launchers.get(key)
         if fn is None:
-            fn = self._launchers[key] = _jit(pl.pallas_call(kernel, **call))
+            fn = self._launchers[key] = _jit(
+                pl.pallas_call(kernel, name=name, **call))
         return fn(*args)
 
     def mont_mul(self, a, b):

@@ -22,11 +22,15 @@ Usage:
   - programmatic: `with profiling.trace("/tmp/trace"): run_kernels()`
   - one-shot:     `profiling.capture("/tmp/trace", seconds=2.0)`
   - daemon:       GET /debug/jax-profile?seconds=2  on the metrics port
-  - perf work:    `python -m drand_tpu.profiling out_dir -- cmd ...`
-                  runs `cmd` in a subprocess with a JAX trace captured
-                  around its whole lifetime (see __main__.py);
-                  tools/profile_verify.py remains the verify-specific
-                  harness.
+  - perf work:    `python benchmark/run.py --workload <cell> --trace 1`
+                  captures one whole operation of a benchmark cell and
+                  reduces it (device busy and idle, time per stage).
+
+A capture starts and ends with `tracing.clock_mark()`: one annotation
+that carries the reading of `time.perf_counter_ns()`, the clock of every
+span's `start_mono`, so `/debug/spans` can be laid beside the device's
+operations.  The verify program's operations carry their stage
+(`drand_tpu.ops.STAGES`) and their Pallas kernel's name in `op_name`.
 
 Traces are TensorBoard-compatible (`xplane.pb` under the out dir).
 """
@@ -46,11 +50,15 @@ from drand_tpu.profiling.journey import JOURNEY  # noqa: F401
 def trace(out_dir: str):
     """Capture a JAX profiler trace around a block."""
     import jax
+
+    from drand_tpu import tracing
     os.makedirs(out_dir, exist_ok=True)
     jax.profiler.start_trace(out_dir)
     try:
+        tracing.clock_mark()
         yield out_dir
     finally:
+        tracing.clock_mark()
         jax.profiler.stop_trace()
 
 
@@ -61,16 +69,9 @@ def capture(out_dir: str, seconds: float = 2.0) -> str:
     return out_dir
 
 
-def annotate(name: str):
-    """Named span visible in the trace timeline (TraceAnnotation)."""
-    import jax
-    return jax.profiler.TraceAnnotation(name)
-
-
 def manifest(out_dir: str) -> dict:
     """Describe a captured trace directory: the files the profiler wrote
-    (relative paths + sizes), for the `/debug/jax-profile` response and
-    the `-m` runner's summary."""
+    (relative paths + sizes), for the `/debug/jax-profile` response."""
     files = []
     total = 0
     for root, _dirs, names in os.walk(out_dir):

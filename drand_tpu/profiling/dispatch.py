@@ -6,8 +6,9 @@ parallel/sharded per-device rounding) — a chronically under-filled
 bucket wastes device time that no aggregate counter surfaces.  This
 module keeps a bounded ring of per-dispatch records capturing the
 requested n, the chosen bucket, the fill ratio, the padding-rounds
-wasted, queue-wait vs device-wall time, and the amortized per-round
-cost — the flight-recorder view behind `/debug/dispatch`, the Watchdog
+wasted, queue-wait vs the host's wall time around the call (dispatch
+plus blocking resolve: a host clock, NOT device time, which only a
+profiler trace gives), and the amortized per-round cost — the flight-recorder view behind `/debug/dispatch`, the Watchdog
 "device" snapshot key, and the `drand_dispatch_*` metrics.
 
 Seams:
@@ -38,7 +39,7 @@ class DispatchRecord:
     seam: str
     n: int                      # rounds/partials actually requested
     bucket: int                 # padded dispatch size the kernel saw
-    device_s: float             # wall seconds inside the backend call
+    host_wall_s: float          # host wall seconds inside the backend call
     queue_wait_s: float = 0.0   # enqueue -> dispatch (coalescing seams)
     wall: float = 0.0           # wall-clock stamp (operator correlation)
     attrs: dict = field(default_factory=dict)
@@ -53,16 +54,16 @@ class DispatchRecord:
 
     @property
     def us_per_round(self) -> float:
-        """Amortized device microseconds per REQUESTED round — padding
-        makes this worse than device_s/bucket, which is the point."""
-        return (self.device_s / self.n * 1e6) if self.n > 0 else 0.0
+        """Amortized host-wall microseconds per REQUESTED round — padding
+        makes this worse than host_wall_s/bucket, which is the point."""
+        return (self.host_wall_s / self.n * 1e6) if self.n > 0 else 0.0
 
     def to_dict(self) -> dict:
         return {
             "seam": self.seam, "n": self.n, "bucket": self.bucket,
             "fill_ratio": round(self.fill_ratio, 4),
             "padding_rounds": self.padding_rounds,
-            "device_s": round(self.device_s, 9),
+            "host_wall_s": round(self.host_wall_s, 9),
             "queue_wait_s": round(self.queue_wait_s, 9),
             "us_per_round": round(self.us_per_round, 3),
             "wall": round(self.wall, 6),
@@ -83,26 +84,26 @@ class DispatchRecorder:
         # the totals are what the watchdog and perf deltas read)
         self._totals: dict[str, dict] = {}
 
-    def record(self, seam: str, n: int, bucket: int, device_s: float,
+    def record(self, seam: str, n: int, bucket: int, host_wall_s: float,
                queue_wait_s: float = 0.0, **attrs) -> DispatchRecord:
         rec = DispatchRecord(seam=seam, n=int(n), bucket=int(bucket),
-                             device_s=float(device_s),
+                             host_wall_s=float(host_wall_s),
                              queue_wait_s=float(queue_wait_s),
                              wall=_wall_stamp(), attrs=attrs)
         with self._lock:
             self._ring.append(rec)
             tot = self._totals.setdefault(seam, {
                 "dispatches": 0, "rounds": 0, "padding_rounds": 0,
-                "device_s": 0.0, "queue_wait_s": 0.0})
+                "host_wall_s": 0.0, "queue_wait_s": 0.0})
             tot["dispatches"] += 1
             tot["rounds"] += rec.n
             tot["padding_rounds"] += rec.padding_rounds
-            tot["device_s"] += rec.device_s
+            tot["host_wall_s"] += rec.host_wall_s
             tot["queue_wait_s"] += rec.queue_wait_s
         try:
             from drand_tpu import metrics as M
             M.DISPATCH_SECONDS.labels(seam, str(rec.bucket)) \
-                .observe(rec.device_s)
+                .observe(rec.host_wall_s)
             M.DISPATCH_FILL_RATIO.labels(seam).set(rec.fill_ratio)
             if rec.padding_rounds:
                 M.DISPATCH_PADDING.labels(seam).inc(rec.padding_rounds)
@@ -132,9 +133,9 @@ class DispatchRecorder:
             tot["avg_fill_ratio"] = round(
                 tot["rounds"] / dispatched, 4) if dispatched else 0.0
             tot["amortized_us_per_round"] = round(
-                tot["device_s"] / tot["rounds"] * 1e6, 3) \
+                tot["host_wall_s"] / tot["rounds"] * 1e6, 3) \
                 if tot["rounds"] else 0.0
-            tot["device_s"] = round(tot["device_s"], 6)
+            tot["host_wall_s"] = round(tot["host_wall_s"], 6)
             tot["queue_wait_s"] = round(tot["queue_wait_s"], 6)
         return totals
 
@@ -164,12 +165,12 @@ def _wall_stamp() -> float:
 DISPATCH = DispatchRecorder()
 
 
-def record_dispatch(seam: str, n: int, bucket: int, device_s: float,
+def record_dispatch(seam: str, n: int, bucket: int, host_wall_s: float,
                     queue_wait_s: float = 0.0, **attrs) -> None:
     """Module-level convenience used by the instrumented seams; never
     raises (the flight recorder is an observer, not a participant)."""
     try:
-        DISPATCH.record(seam, n, bucket, device_s,
+        DISPATCH.record(seam, n, bucket, host_wall_s,
                         queue_wait_s=queue_wait_s, **attrs)
     except Exception:
         pass
@@ -192,13 +193,13 @@ class timed_dispatch:
         self.queue_wait_s = queue_wait_s
         self.attrs = attrs
         self._t0 = 0.0
-        self.device_s = 0.0
+        self.host_wall_s = 0.0
 
     def __enter__(self) -> "timed_dispatch":
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        self.device_s = time.perf_counter() - self._t0
-        record_dispatch(self.seam, self.n, self.bucket, self.device_s,
+        self.host_wall_s = time.perf_counter() - self._t0
+        record_dispatch(self.seam, self.n, self.bucket, self.host_wall_s,
                         queue_wait_s=self.queue_wait_s, **self.attrs)

@@ -23,10 +23,18 @@ timeline.  This module is the TPU-native replacement (SURVEY §5.1):
     the caller's span as parent.
   - `SpanRecorder`: bounded in-process ring buffer behind the
     `/debug/spans` routes on the metrics port (drand_tpu/metrics.py).
-  - device bridge: `device=True` opens a `jax.profiler.TraceAnnotation`
-    for the span's lifetime, so host spans wrapping device work appear
-    by the same name in the TensorBoard xplane trace captured via
-    `/debug/jax-profile`.
+  - one clock with the device trace: a span publishes its monotonic
+    start (`start_mono`, on `time.perf_counter`), and `clock_mark()`
+    writes that clock's reading into a profiler capture as one
+    `TraceAnnotation`, so `/debug/spans` lines up with the xplane of
+    `/debug/jax-profile` without a profiler event per span.
+    `span(..., device=True)` still shows ONE lexically scoped stage by
+    name in the capture; it is opened and closed on the entering thread
+    (a TraceMe is per-thread), which is why `begin_span` has no such
+    option.
+  - `gc.full`: a `gc.callbacks` hook records every generation-2
+    collection (after a program build one stops all Python threads for
+    seconds) as a span of its own trace.
 
 Every ended span also feeds the `drand_stage_duration_seconds{stage,
 beacon_id}` Prometheus histogram (drand_tpu/metrics.py), which is how
@@ -41,6 +49,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import hashlib
 import os
 import threading
@@ -98,31 +107,30 @@ class Span:
     attrs: dict = field(default_factory=dict)
     status: str = "ok"
     start_wall: float = 0.0
+    start_mono: float = 0.0             # time.perf_counter at start()
     duration_s: float | None = None     # set by end()
-    _start_mono: float = 0.0
-    _annotation: object = None
     _ended: bool = False
 
-    def start(self) -> "Span":
-        self.start_wall = _wall()
-        self._start_mono = time.perf_counter()
+    def start(self, at: float | None = None) -> "Span":
+        """`at` is a `time.perf_counter` reading the caller already made
+        (a stage whose stat and span share their clock reads)."""
+        now = time.perf_counter()
+        self.start_mono = now if at is None else at
+        self.start_wall = _wall() - (now - self.start_mono)
         return self
 
-    def end(self, status: str | None = None) -> "Span":
-        """Close the span: fix the duration, record it, feed the stage
-        histogram, close the device annotation.  Idempotent."""
+    def end(self, status: str | None = None,
+            at: float | None = None) -> "Span":
+        """Close the span: fix the duration (to `at`, a `perf_counter`
+        reading, where the caller has one), record it, feed the stage
+        histogram.  Idempotent."""
         if self._ended:
             return self
         self._ended = True
-        self.duration_s = time.perf_counter() - self._start_mono
+        self.duration_s = (time.perf_counter() if at is None else at) \
+            - self.start_mono
         if status is not None:
             self.status = status
-        if self._annotation is not None:
-            try:
-                self._annotation.__exit__(None, None, None)
-            except Exception:
-                pass
-            self._annotation = None
         RECORDER.record(self)
         try:
             from drand_tpu import metrics as M
@@ -139,18 +147,6 @@ class Span:
             log.debug("journey feed failed", exc_info=True)
         return self
 
-    def annotate_device(self) -> None:
-        """Open a jax.profiler.TraceAnnotation for this span's lifetime
-        so it shows up by name in the XLA timeline (profiling.annotate).
-        Never fails the caller — tracing must not break verification."""
-        try:
-            from drand_tpu import profiling
-            ann = profiling.annotate(self.name)
-            ann.__enter__()
-            self._annotation = ann
-        except Exception:
-            self._annotation = None
-
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
         return self
@@ -161,6 +157,7 @@ class Span:
             "parent_id": self.parent_id, "name": self.name,
             "beacon_id": self.beacon_id, "round": self.round,
             "start": round(self.start_wall, 6),
+            "start_mono": round(self.start_mono, 9),
             "duration_s": (round(self.duration_s, 9)
                            if self.duration_s is not None else None),
             "status": self.status, "attrs": dict(self.attrs),
@@ -186,6 +183,7 @@ class SpanRecorder:
         self._lock = threading.Lock()
 
     def record(self, span: Span) -> None:
+        _drain_full_collections()
         with self._lock:
             self._spans.append(span)
 
@@ -194,6 +192,7 @@ class SpanRecorder:
             return len(self._spans)
 
     def spans(self) -> list[Span]:
+        _drain_full_collections()
         with self._lock:
             return list(self._spans)
 
@@ -244,16 +243,19 @@ def current() -> Span | None:
 
 def begin_span(name: str, *, beacon_id: str = "", round_: int | None = None,
                trace_id: str | None = None, parent_id: str | None = None,
-               device: bool = False, **attrs) -> Span:
+               parent: Span | None = None, at: float | None = None,
+               **attrs) -> Span:
     """Start a span WITHOUT making it the context's current span — the
     split start/end form for stages whose close happens in a different
     scope (e.g. a batched verify's dispatch vs its resolver).  Callers
     MUST balance with `.end()` (lint: span-balance).
 
-    Trace identity resolves in order: explicit trace_id > the current
-    context span (parent link) > the deterministic per-round trace >
-    a fresh random trace."""
-    parent = _current.get()
+    Trace identity resolves in order: explicit trace_id > `parent`, or
+    else the current context span (parent link) > the deterministic
+    per-round trace > a fresh random trace.  `at` is the span's start
+    where the caller has already read `time.perf_counter`."""
+    if parent is None:
+        parent = _current.get()
     if trace_id is None:
         if parent is not None:
             trace_id = parent.trace_id
@@ -267,12 +269,32 @@ def begin_span(name: str, *, beacon_id: str = "", round_: int | None = None,
         beacon_id = parent.beacon_id
     if parent is not None and round_ is None:
         round_ = parent.round
-    sp = Span(name=name, trace_id=trace_id, span_id=new_span_id(),
-              parent_id=parent_id, beacon_id=beacon_id, round=round_,
-              attrs=dict(attrs)).start()
-    if device:
-        sp.annotate_device()
-    return sp
+    return Span(name=name, trace_id=trace_id, span_id=new_span_id(),
+                parent_id=parent_id, beacon_id=beacon_id, round=round_,
+                attrs=dict(attrs)).start(at)
+
+
+def record_span(name: str, start_mono: float, end_mono: float, *,
+                parent: Span | None = None, **attrs) -> Span:
+    """A finished span from two `time.perf_counter` readings the caller
+    made anyway (a queue wait, a stage that already feeds a stat): the
+    span and the stat cannot disagree, and the stage pays no further
+    clock read."""
+    return begin_span(name, parent=parent, at=start_mono,
+                      **attrs).end(at=end_mono)
+
+
+@contextlib.contextmanager
+def under(sp: Span | None):
+    """Make an open span the context's current one for a block without
+    ending it there: a pipeline stage works under the span of the
+    segment it was handed, and `asyncio.to_thread` carries that into
+    the worker."""
+    token = _current.set(sp)
+    try:
+        yield sp
+    finally:
+        _current.reset(token)
 
 
 @contextlib.contextmanager
@@ -280,10 +302,12 @@ def span(name: str, *, beacon_id: str = "", round_: int | None = None,
          trace_id: str | None = None, parent_id: str | None = None,
          device: bool = False, **attrs):
     """Context-managed span, installed as the task's current span so
-    children (including RPCs via `inject`) parent to it."""
+    children (including RPCs via `inject`) parent to it.  `device=True`
+    also shows the stage by name in a profiler capture: one
+    `TraceAnnotation`, entered and left here, on the entering thread."""
     sp = begin_span(name, beacon_id=beacon_id, round_=round_,
-                    trace_id=trace_id, parent_id=parent_id, device=device,
-                    **attrs)
+                    trace_id=trace_id, parent_id=parent_id, **attrs)
+    annotation = _annotation(name) if device else None
     token = _current.set(sp)
     try:
         yield sp
@@ -301,7 +325,75 @@ def span(name: str, *, beacon_id: str = "", round_: int | None = None,
             # unusable there; the contextvar died with the origin
             # context, so there is nothing to restore.
             pass
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         sp.end()
+
+
+# -- the profiler's clock -------------------------------------------------
+
+CLOCK_MARK = "drand:clock_mark perf_counter_ns="
+
+
+def _annotation(name: str):
+    """An entered `jax.profiler.TraceAnnotation`, or None where the
+    profiler cannot be had: tracing must not break the traced."""
+    try:
+        import jax
+        annotation = jax.profiler.TraceAnnotation(name)
+        annotation.__enter__()
+        return annotation
+    except Exception:
+        return None
+
+
+def clock_mark() -> int:
+    """Write the spans' clock into a running profiler capture: one
+    annotation named `CLOCK_MARK` + the reading of
+    `time.perf_counter_ns()` taken as it opens.  Its place on the
+    capture's own axis gives the offset between the two clocks, so every
+    span's `start_mono` can be laid beside the device's operations; two
+    marks (`profiling.trace` writes one at each end) also show the
+    drift.  Returns the reading."""
+    now = time.perf_counter_ns()
+    annotation = _annotation(f"{CLOCK_MARK}{now}")
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
+    return now
+
+
+# -- full garbage collections ---------------------------------------------
+#
+# The hook runs inside the collector, possibly while this very thread
+# holds the recorder's or a metric's lock: it only notes the two clock
+# reads, and the span is made at the next record or read of the ring.
+
+_full_collections: list[tuple[float, float, int]] = []
+_full_began = [0.0]
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _full_began[0] = time.perf_counter()
+    else:
+        _full_collections.append((_full_began[0], time.perf_counter(),
+                                  info["collected"]))
+
+
+def _drain_full_collections() -> None:
+    while _full_collections:
+        try:
+            began, ended, collected = _full_collections.pop(0)
+        except IndexError:          # another thread drained it
+            return
+        Span(name="gc.full", trace_id=new_trace_id(),
+             span_id=new_span_id(), attrs={"collected": collected}
+             ).start(began).end(at=ended)
+
+
+gc.callbacks.append(_on_gc)
 
 
 # -- RPC propagation (protobuf Metadata fields 4/5) -----------------------

@@ -18,15 +18,18 @@ Signature randomness = sha256(sig) (`chain/beacon.go:51-54`).
 from __future__ import annotations
 
 import functools
+import time
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from drand_tpu import tracing
 from drand_tpu.crypto.bls12381.constants import DST_G1, DST_G2
 from drand_tpu.ops import bls as BLS
 from drand_tpu.ops.sha256 import sha256
+from drand_tpu.profiling import record_dispatch
 
 # Batch buckets: requests are padded up to the nearest size so only a few
 # XLA programs are ever compiled per scheme.  Overridable for tests/small
@@ -178,18 +181,24 @@ class Verifier:
         trace, to lower and to compile (host clock; a persistent-cache
         hit shows as a short compile), and the `lowered` stage, in whose
         text the Pallas kernels can be counted."""
-        import time as _time
-
         from drand_tpu.ops.field import compact_graphs
         from drand_tpu.ops.pallas_field import use_pallas
-        t0 = _time.perf_counter()
-        traced = jax.jit(self._run_fn()).trace(*self._arg_structs(n))
-        t1 = _time.perf_counter()
-        lowered = traced.lower()
-        t2 = _time.perf_counter()
-        self._kernels[n] = lowered.compile()
-        t3 = _time.perf_counter()
-        return {"program": self._aot_name(n), "bucket": n,
+        name = self._aot_name(n)
+        # a span of its own and one a phase, from the same clock reads as
+        # the record: a bucket built lazily under an open `sync.segment`
+        # or `scan.flush` shows there as what stalled it
+        with tracing.span("verifier.build", bucket=n, program=name) as sp:
+            t0 = sp.start_mono
+            traced = jax.jit(self._run_fn()).trace(*self._arg_structs(n))
+            t1 = time.perf_counter()
+            tracing.record_span("build.trace", t0, t1)
+            lowered = traced.lower()
+            t2 = time.perf_counter()
+            tracing.record_span("build.lower", t1, t2)
+            self._kernels[n] = lowered.compile()
+            t3 = time.perf_counter()
+            tracing.record_span("build.compile", t2, t3)
+        return {"program": name, "bucket": n,
                 "tracing": "compact" if use_pallas() or compact_graphs()
                 else "static",
                 "trace_s": t1 - t0, "lower_s": t2 - t1,
@@ -210,32 +219,41 @@ class Verifier:
         n = rounds.shape[0]
         if n == 0:
             return lambda: np.zeros(0, dtype=bool)
-        msgs = self.messages(rounds, prev_sigs)
-        m = _bucket(n)
-        if m != n:
-            pad = m - n
-            msgs = np.concatenate([msgs, np.repeat(msgs[-1:], pad, axis=0)])
-            sigs = np.concatenate([sigs, np.repeat(sigs[-1:], pad, axis=0)])
-        import time as _time
-        t0 = _time.perf_counter()
-        ok = self._kernel(m)(jnp.asarray(msgs, dtype=jnp.uint8),
-                             jnp.asarray(sigs, dtype=jnp.uint8),
-                             self._pk)
-        dispatch_s = _time.perf_counter() - t0
+        # `verify.dispatch`: message build and padding (`prepare_s`), then
+        # host-to-device and the enqueue (`enqueue_s`); a bucket built
+        # lazily is its child `verifier.build`.  `pad_rows` over `bucket`
+        # is the share of the device's work that is padding.
+        with tracing.span("verify.dispatch", n=n) as sp:
+            msgs = self.messages(rounds, prev_sigs)
+            m = _bucket(n)
+            if m != n:
+                pad = m - n
+                msgs = np.concatenate(
+                    [msgs, np.repeat(msgs[-1:], pad, axis=0)])
+                sigs = np.concatenate(
+                    [sigs, np.repeat(sigs[-1:], pad, axis=0)])
+            t0 = time.perf_counter()
+            kernel = self._kernel(m)
+            t1 = time.perf_counter()
+            ok = kernel(jnp.asarray(msgs, dtype=jnp.uint8),
+                        jnp.asarray(sigs, dtype=jnp.uint8), self._pk)
+            dispatch_s = time.perf_counter() - t1
+            sp.set(bucket=m, pad_rows=m - n, prepare_s=t0 - sp.start_mono,
+                   enqueue_s=dispatch_s)
         done = [False]    # split dispatch/resolve: record exactly once
 
         def resolve():
-            t1 = _time.perf_counter()
+            t1 = time.perf_counter()
             out = np.asarray(ok)[:n]
             if not done[0]:
                 done[0] = True
-                from drand_tpu.profiling import record_dispatch
-                # device wall = async dispatch + the blocking resolve
+                t2 = time.perf_counter()
+                tracing.record_span("verify.resolve", t1, t2, n=n, bucket=m)
+                # host wall = async dispatch + the blocking resolve
                 # (queue-wait is the gap the CALLER leaves before
                 # resolving — that overlap is the pipelining win, not
                 # waste, so it is not charged here)
-                record_dispatch("verify", n, m,
-                                dispatch_s + (_time.perf_counter() - t1))
+                record_dispatch("verify", n, m, dispatch_s + (t2 - t1))
             return out
         return resolve
 

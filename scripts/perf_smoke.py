@@ -39,9 +39,9 @@ def _synthetic_dispatch() -> dict:
     """Known dispatch mix -> exact seam summary (no singleton: the
     smoke must not pollute the process-global flight recorder)."""
     ring = dispatch.DispatchRecorder(maxlen=16)
-    ring.record("verify", n=10, bucket=16, device_s=0.004)
-    ring.record("verify", n=16, bucket=16, device_s=0.004)
-    ring.record("partials", n=6, bucket=8, device_s=0.002)
+    ring.record("verify", n=10, bucket=16, host_wall_s=0.004)
+    ring.record("verify", n=16, bucket=16, host_wall_s=0.004)
+    ring.record("partials", n=6, bucket=8, host_wall_s=0.002)
     summary = ring.seam_summary()
     v = summary["verify"]
     assert v["dispatches"] == 2 and v["rounds"] == 26, summary

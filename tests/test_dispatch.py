@@ -8,24 +8,24 @@ from drand_tpu.profiling.dispatch import (DISPATCH, DispatchRecord,
 
 
 def test_record_math():
-    rec = DispatchRecord(seam="verify", n=10, bucket=16, device_s=0.004)
+    rec = DispatchRecord(seam="verify", n=10, bucket=16, host_wall_s=0.004)
     assert rec.fill_ratio == 10 / 16
     assert rec.padding_rounds == 6
     assert rec.us_per_round == 0.004 / 10 * 1e6
     d = rec.to_dict()
     assert d["fill_ratio"] == 0.625 and d["padding_rounds"] == 6
     # exact-bucket dispatch wastes nothing
-    full = DispatchRecord(seam="verify", n=16, bucket=16, device_s=0.004)
+    full = DispatchRecord(seam="verify", n=16, bucket=16, host_wall_s=0.004)
     assert full.fill_ratio == 1.0 and full.padding_rounds == 0
     # degenerate shapes must not divide by zero
-    empty = DispatchRecord(seam="verify", n=0, bucket=0, device_s=0.0)
+    empty = DispatchRecord(seam="verify", n=0, bucket=0, host_wall_s=0.0)
     assert empty.fill_ratio == 0.0 and empty.us_per_round == 0.0
 
 
 def test_ring_bounds_and_totals_survive_eviction():
     ring = DispatchRecorder(maxlen=4)
     for i in range(10):
-        ring.record("verify", n=1, bucket=2, device_s=0.001)
+        ring.record("verify", n=1, bucket=2, host_wall_s=0.001)
     assert len(ring) == 4                      # ring forgot 6
     tot = ring.seam_summary()["verify"]
     assert tot["dispatches"] == 10             # totals did not
@@ -36,9 +36,9 @@ def test_ring_bounds_and_totals_survive_eviction():
 
 def test_seam_summary_amortized_cost():
     ring = DispatchRecorder()
-    ring.record("verify", n=10, bucket=16, device_s=0.004)
-    ring.record("verify", n=16, bucket=16, device_s=0.004)
-    ring.record("aggregate", n=3, bucket=3, device_s=0.001,
+    ring.record("verify", n=10, bucket=16, host_wall_s=0.004)
+    ring.record("verify", n=16, bucket=16, host_wall_s=0.004)
+    ring.record("aggregate", n=3, bucket=3, host_wall_s=0.001,
                 queue_wait_s=0.5, backend="host")
     s = ring.seam_summary()
     assert s["verify"]["avg_fill_ratio"] == round(26 / 32, 4)
@@ -56,7 +56,7 @@ def test_record_feeds_prometheus():
     from drand_tpu import metrics as M
     before = M.DISPATCH_PADDING.labels("verify")._value.get()
     ring = DispatchRecorder()
-    ring.record("verify", n=10, bucket=16, device_s=0.004)
+    ring.record("verify", n=10, bucket=16, host_wall_s=0.004)
     assert M.DISPATCH_PADDING.labels("verify")._value.get() == before + 6
     assert M.DISPATCH_FILL_RATIO.labels("verify")._value.get() == 0.625
     hist = M.DISPATCH_SECONDS.labels("verify", "16")
@@ -87,7 +87,7 @@ def test_timed_dispatch_context_manager():
     try:
         with timed_dispatch("partials", n=6, bucket=8, path="tabled") as td:
             pass
-        assert td.device_s >= 0.0
+        assert td.host_wall_s >= 0.0
         recs = ring.records(seam="partials")
         assert len(recs) == 1
         assert recs[0].n == 6 and recs[0].bucket == 8

@@ -312,3 +312,61 @@ def test_util_fsck_repairs_and_reports_json(tmp_path, capsys):
     assert ei.value.code == 0          # clean after repair
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["ok"] and out["tip_round"] == 3
+
+
+# -- the scan's span tree (ISSUE 25) -----------------------------------------
+
+def test_a_scan_is_one_trace_whose_self_times_add_up(tmp_path):
+    from benchmark.readers.program_spans import self_seconds
+    from drand_tpu import tracing
+    s, _ = _chain_db(tmp_path, 40)
+    tracing.RECORDER.clear()
+    rep = _scan(s, _FakeVerifier(23), segment_rounds=16, read_batch=16)
+    s.close()
+    assert rep.bad_sigs == [23] and rep.scanned == 40
+    spans = [sp for sp in tracing.RECORDER.spans() if sp.name != "gc.full"]
+    by_id = {sp.span_id: sp for sp in spans}
+    (root,) = [sp for sp in spans if sp.parent_id is None]
+    assert root.name == "store.scan"
+    assert root.attrs["scanned"] == 40 and root.attrs["flagged"] == 1
+    assert {sp.trace_id for sp in spans} == {root.trace_id}
+    names = [sp.name for sp in spans]
+    # 40 rows in batches of 16: three reads with rows and the empty one
+    # that ends the scan; a flush for every 16 good rows and the tail
+    assert names.count("scan.read") == 4 and names.count("scan.decode") == 3
+    assert names.count("scan.flush") == 3
+    assert names.count("scan.pack") == names.count("scan.verify_wait") == 3
+    assert set(names) == {"store.scan", "scan.read", "scan.decode",
+                          "scan.flush", "scan.pack", "scan.verify_wait"}
+    assert len(spans) <= 6 * 4 + 1          # a handful a batch, not a row
+    assert sum(sp.attrs["rows"] for sp in spans
+               if sp.name == "scan.decode") == 40
+    assert sum(sp.attrs["rows"] for sp in spans
+               if sp.name == "scan.flush") == 40
+    for sp in spans:
+        if sp.parent_id is not None:
+            p = by_id[sp.parent_id]
+            assert p.start_mono - 1e-6 <= sp.start_mono, (sp.name, p.name)
+            assert sp.start_mono + sp.duration_s \
+                <= p.start_mono + p.duration_s + 1e-6, (sp.name, p.name)
+    # a flush inside the row loop is the decode's child, the last one the
+    # root's: either way no two siblings overlap, so the self times of
+    # the tree add up to the scan
+    flush_parents = {by_id[sp.parent_id].name for sp in spans
+                     if sp.name == "scan.flush"}
+    assert flush_parents == {"scan.decode", "store.scan"}
+    own = self_seconds([(sp.span_id, sp.parent_id, sp.name, sp.start_mono,
+                         sp.start_mono + sp.duration_s) for sp in spans])
+    assert all(v >= -1e-9 for v in own.values())
+    assert sum(own.values()) == pytest.approx(root.duration_s, rel=0.02)
+    assert rep.elapsed_s <= root.duration_s
+
+
+def test_the_structural_scan_has_no_verify_spans(tmp_path):
+    from drand_tpu import tracing
+    s, _ = _chain_db(tmp_path, 5)
+    tracing.RECORDER.clear()
+    assert _scan(s).ok
+    s.close()
+    names = {sp.name for sp in tracing.RECORDER.spans()}
+    assert names - {"gc.full"} == {"store.scan", "scan.read", "scan.decode"}

@@ -404,3 +404,109 @@ def test_check_past_beacons_pipelined_finds_faulty(chain, monkeypatch):
     faulty = mgr.check_past_beacons()
     # a bad stored signature also breaks the NEXT round's linkage
     assert set(faulty) == {4, 5, 9, 10}
+
+
+# -- the catch-up's span tree (ISSUE 25) -------------------------------------
+
+class _YesVerifier:
+    """Says yes without a device: the spans are the pipeline's own."""
+
+    def verify_packed_segment_async(self, packed, anchor_prev_sig):
+        n = len(packed)
+        return lambda: np.ones(n, dtype=bool)
+
+
+def test_a_catch_up_is_one_trace_of_bounded_spans(chain, monkeypatch):
+    from drand_tpu import tracing
+    from drand_tpu.chain.store import CallbackStore
+    beacons, _ = chain
+    monkeypatch.setattr(SM, "SYNC_CHUNK", 4)
+    monkeypatch.setattr(SM, "SYNC_CHUNK_GROWTH", 1)
+    items = _pack(beacons, 2)                  # five wire messages
+    store = CallbackStore(_seeded_store())     # opens `store.commit`
+    mgr = SM.SyncManager(store=store, group=FakeGroup(),
+                         verifier=_YesVerifier(), network=ChunkNet(items),
+                         nodes=[object()], clock=FixedClock())
+    tracing.RECORDER.clear()
+    try:
+        assert asyncio.run(mgr._try_node(object(),
+                                         SM.SyncRequest(1, up_to=N)))
+    finally:
+        store._pool.shutdown(wait=False)
+    spans = [s for s in tracing.RECORDER.spans() if s.name != "gc.full"]
+    by_id = {s.span_id: s for s in spans}
+    (root,) = [s for s in spans if s.parent_id is None]
+    assert root.name == "sync.catchup"
+    assert {s.trace_id for s in spans} == {root.trace_id}
+    assert {s.name for s in spans} == {
+        "sync.catchup", "sync.segment", "sync.queue_wait", "sync.pack",
+        "sync.settle", "store.materialize", "store.commit"}
+    # every child lies inside its parent's interval
+    for s in spans:
+        if s.parent_id is not None:
+            p = by_id[s.parent_id]
+            assert p.start_mono - 1e-6 <= s.start_mono, (s.name, p.name)
+            assert s.start_mono + s.duration_s \
+                <= p.start_mono + p.duration_s + 1e-6, (s.name, p.name)
+    # three segments (4 + 4 + 2 rounds), each with a bounded set of
+    # children whatever its size: never a span a round
+    segments = [s for s in spans if s.name == "sync.segment"]
+    assert [s.attrs["rounds"] for s in segments] == [4, 4, 2]
+    assert [s.attrs["first_round"] for s in segments] == [1, 5, 9]
+    assert all(s.parent_id == root.span_id and s.status == "ok"
+               for s in segments)
+    for seg in segments:
+        kids = [s for s in spans if s.parent_id == seg.span_id]
+        assert sorted(k.name for k in kids) == [
+            "store.commit", "store.materialize", "sync.pack",
+            "sync.queue_wait", "sync.queue_wait", "sync.settle"]
+        waits = {k.attrs["stage"]: k for k in kids
+                 if k.name == "sync.queue_wait"}
+        assert set(waits) == {"verify", "commit"}
+        assert all(0 <= w.attrs["depth"] <= SM.PIPELINE_DEPTH
+                   for w in waits.values())
+    assert len(spans) == 1 + 3 * 7
+    # the stats and the spans that share their clock reads agree exactly
+    settle = sum(s.duration_s for s in spans if s.name == "sync.settle")
+    assert settle == pytest.approx(mgr.stats["verify_s"], abs=1e-9)
+    assert root.attrs["fetch_s"] == pytest.approx(mgr.stats["fetch_s"])
+    assert root.attrs["rounds"] == mgr.stats["rounds"] == N
+    assert root.attrs["segments"] == mgr.stats["segments"] == 3
+    assert root.attrs["messages"] == len(items)
+    # what a stage's stat holds beyond its spans is the hop into the
+    # worker thread, so the spans can only be the shorter
+    commit = sum(s.duration_s for s in spans
+                 if s.name in ("store.materialize", "store.commit"))
+    assert 0 < commit <= mgr.stats["commit_s"]
+
+
+def test_a_failed_segment_ends_its_span_and_its_successors(chain,
+                                                            monkeypatch):
+    from drand_tpu import tracing
+
+    class _NoAtRound7(_YesVerifier):
+        def verify_packed_segment_async(self, packed, anchor_prev_sig):
+            ok = packed.rounds() != 7
+            return lambda: ok
+
+    beacons, _ = chain
+    monkeypatch.setattr(SM, "SYNC_CHUNK", 2)
+    monkeypatch.setattr(SM, "SYNC_CHUNK_GROWTH", 1)
+    store = _seeded_store()
+    mgr = SM.SyncManager(store=store, group=FakeGroup(),
+                         verifier=_NoAtRound7(),
+                         network=ChunkNet(_pack(beacons, 2)),
+                         nodes=[object()], clock=FixedClock())
+    tracing.RECORDER.clear()
+    assert not asyncio.run(mgr._try_node(object(),
+                                         SM.SyncRequest(1, up_to=N)))
+    assert set(store.by_round) == {0, 1, 2, 3, 4, 5, 6}
+    segments = [s for s in tracing.RECORDER.spans()
+                if s.name == "sync.segment"]
+    status = {s.attrs["first_round"]: s.status for s in segments}
+    assert status[1] == status[3] == status[5] == "ok"
+    assert status[7] == "verify_failed"
+    # whatever was flushed after it left the pipeline unverified or
+    # uncommitted, and says so
+    assert all(v == "discarded" for k, v in status.items() if k > 7)
+    assert all(s.duration_s is not None for s in segments)

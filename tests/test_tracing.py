@@ -226,3 +226,134 @@ def test_rpc_trace_context_crosses_nodes():
             await sc.stop()
 
     asyncio.run(main())
+
+
+# -- one clock with the device trace (ISSUE 25) ----------------------------
+
+
+def test_a_span_publishes_its_monotonic_start():
+    import time
+    before = time.perf_counter()
+    with tracing.span("stage") as sp:
+        pass
+    after = time.perf_counter()
+    assert before <= sp.start_mono <= after
+    d = sp.to_dict()
+    assert d["start_mono"] == pytest.approx(sp.start_mono, abs=1e-6)
+    assert sp.start_mono + d["duration_s"] <= after + 1e-6
+    # the wall stamp stays what it was: an operator's clock, injectable
+    tracing.set_wall_clock(lambda: 1000.0)
+    with tracing.span("stamped") as sp2:
+        pass
+    assert sp2.to_dict()["start"] == pytest.approx(1000.0, abs=1e-3)
+
+
+def test_a_span_from_two_clock_reads_the_caller_made():
+    with tracing.span("outer") as outer:
+        sp = tracing.record_span("waited", 10.0, 12.5, stage="verify")
+    assert (sp.start_mono, sp.duration_s) == (10.0, 2.5)
+    assert sp.parent_id == outer.span_id and sp.trace_id == outer.trace_id
+    assert sp.attrs == {"stage": "verify"}
+    assert sp in tracing.RECORDER.spans()
+    # an explicit parent wins over the context's
+    other = tracing.begin_span("other")
+    with tracing.span("ctx"):
+        child = tracing.record_span("c", 1.0, 2.0, parent=other)
+    other.end()
+    assert child.parent_id == other.span_id
+
+
+def test_clock_mark_carries_perf_counter_into_a_capture(tmp_path):
+    """The mark is one annotation whose name holds the clock's reading;
+    `profiling.trace` writes one at each end of a capture."""
+    import time
+
+    from jax.profiler import ProfileData
+
+    from drand_tpu import profiling
+    before = time.perf_counter_ns()
+    with profiling.trace(str(tmp_path)):
+        inside = tracing.clock_mark()
+    after = time.perf_counter_ns()
+    assert before <= inside <= after
+    files = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    assert files
+    marks = []
+    for plane in ProfileData.from_file(str(files[0])).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(tracing.CLOCK_MARK):
+                    marks.append((ev.start_ns,
+                                  int(ev.name[len(tracing.CLOCK_MARK):])))
+    assert len(marks) == 3                  # start, mine, end
+    marks.sort()
+    assert [m[1] for m in marks] == sorted(m[1] for m in marks)
+    assert before <= marks[0][1] and marks[-1][1] <= after
+    # both clocks tick alike: the offset is the same at every mark
+    offsets = [ns - at for at, ns in marks]
+    assert max(offsets) - min(offsets) < 50e6
+
+
+def test_a_span_closed_on_another_thread_keeps_parent_and_trace():
+    """The batched verify's shape: begun in a worker under the segment's
+    span, ended by whoever resolves it."""
+    import threading
+
+    async def main():
+        with tracing.span("sync.catchup") as root:
+            seg = tracing.begin_span("sync.segment", rounds=4)
+            holder = {}
+
+            def dispatch():
+                holder["sp"] = tracing.begin_span("verify.segment")
+
+            with tracing.under(seg):
+                await asyncio.to_thread(dispatch)
+            assert tracing.current() is root      # `under` restored it
+            t = threading.Thread(target=holder["sp"].end)
+            t.start()
+            t.join()
+            seg.end()
+        return root, seg, holder["sp"]
+
+    root, seg, sp = asyncio.run(main())
+    assert seg.parent_id == root.span_id
+    assert sp.parent_id == seg.span_id
+    assert sp.trace_id == seg.trace_id == root.trace_id
+    assert sp.duration_s is not None and sp.start_mono >= seg.start_mono
+    assert {s.name for s in tracing.RECORDER.trace(root.trace_id)} == {
+        "sync.catchup", "sync.segment", "verify.segment"}
+
+
+def test_a_lexical_span_can_show_by_name_in_a_capture(tmp_path):
+    """`span(device=True)`: one TraceAnnotation, entered and left in the
+    `with`, so on one thread; the split form has no such option."""
+    from jax.profiler import ProfileData
+
+    from drand_tpu import profiling
+    with profiling.trace(str(tmp_path)):
+        with tracing.span("partial.aggregate", device=True) as sp:
+            pass
+    assert sp.duration_s is not None
+    files = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    names = {ev.name for plane in ProfileData.from_file(str(files[0])).planes
+             for line in plane.lines for ev in line.events}
+    assert "partial.aggregate" in names
+    assert "device" not in tracing.begin_span.__kwdefaults__
+
+
+def test_full_collections_are_spans_and_young_ones_are_not():
+    import gc
+    gc.collect(0)
+    gc.collect(1)
+    assert [s for s in tracing.RECORDER.spans() if s.name == "gc.full"] == []
+    junk = [[i] for i in range(1000)]
+    for j in junk:
+        j.append(j)                     # cycles for the collector
+    del junk, j
+    gc.collect()
+    full = [s for s in tracing.RECORDER.spans() if s.name == "gc.full"]
+    assert len(full) == 1
+    assert full[0].duration_s > 0 and full[0].parent_id is None
+    assert full[0].attrs["collected"] >= 1000
+    assert full[0].start_mono > 0
