@@ -143,7 +143,7 @@ def _env_tag() -> str:
 
 
 def cache_path(name: str, extra: str = "") -> str:
-    # DRAND_TPU_COMPACT changes the traced program (dense-scan ladders vs
+    # DRAND_TPU_COMPACT changes the traced program (one scan a ladder vs
     # static segmentation — drand_tpu.ops.field.compact_graphs), so it is
     # part of the key: a compact executable must never be served to a
     # throughput caller or vice versa.  `extra` carries caller-specific

@@ -229,9 +229,9 @@ def point_mul_bits(pt, bits, ops):
 def point_mul_const(pt, k: int, ops):
     """Scalar mul by a static non-negative scalar.
 
-    Statically segmented double-and-add (field.tail_segments): zero runs
-    of the scalar scan a double-only body; set bits unroll their
-    point_add — sparse scalars like the BLS parameter |x| (subgroup
+    Double-and-add over the scalar's static bits
+    (field.segmented_ladder): every bit doubles, only the set bits run
+    their point_add — sparse scalars like the BLS parameter |x| (subgroup
     checks, cofactor clearing) skip the ~90% of additions a masked
     per-bit scan would compute and discard.  Safety of the
     no-doubling-fallback add: acc = m*pt with 2 <= m < order can never

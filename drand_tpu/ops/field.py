@@ -71,8 +71,8 @@ def tail_segments(bits: str):
     Shared by every static double-and-add ladder (Miller loop, final-exp
     x-chains, constant scalar multiplication): sparse constants like the
     BLS parameter |x| (5 set tail bits of 63) make a masked per-bit scan
-    execute its full add/multiply path mostly as waste; segmenting scans
-    the zero runs with a double-only body and unrolls the set-bit steps."""
+    execute its full add/multiply path mostly as waste; `segmented_ladder`
+    runs the add step on the set bits only, in either tracing mode."""
     segs, i, n = [], 0, len(bits)
     while i < n:
         j = i
@@ -84,12 +84,13 @@ def tail_segments(bits: str):
 
 
 def compact_graphs() -> bool:
-    """Compile-lean mode: every ladder traces as ONE dense masked per-bit
-    scan instead of the static segment unroll.  The graph shrinks ~10x
-    (the full verify drops from ~550k to tens of thousands of HLO ops, and
-    from 655 Pallas call sites to 152) at the cost of executing
-    masked-away add steps — the right trade wherever compile/load time is
-    the budget.
+    """Compile-lean mode: every ladder traces as ONE per-bit scan instead
+    of the static segment unroll.  The graph shrinks ~10x (the full verify
+    drops from ~550k to tens of thousands of HLO ops, and from 655 Pallas
+    call sites to 152).  The scan's body doubles on every bit and adds
+    under a `lax.cond` on the bit, so the work executed is the static
+    mode's; what the static mode still has over it are the levers shut by
+    `not compact_graphs()` (merged Miller kernels, addition chains).
 
     Read at TRACE time: the innermost `compact_scope()` decides, and
     outside any scope `DRAND_TPU_COMPACT=1` does."""
@@ -267,8 +268,11 @@ def addchain_plan(e: int, w: int = 5, run_min: int = 99):
 def segmented_ladder(segments, state, dbl_fn, add_fn):
     """Shared driver for static double-and-add ladders over
     `tail_segments` output: scans each zero run with the double-only body
-    and unrolls each set-bit step (double + add).  `state` is any pytree;
-    `dbl_fn(state) -> state`, `add_fn(state) -> state`."""
+    and unrolls each set-bit step (double + add); in compact mode, one
+    scan over all bits whose body doubles and then adds under a scalar
+    `lax.cond`.  Either way `add_fn` runs on the set bits only.  `state`
+    is any pytree; `dbl_fn(state) -> state`, `add_fn(state) -> state`
+    (same structure and dtypes: it is a `cond` branch)."""
     if compact_graphs():
         bits = []
         for run, has_one in segments:
@@ -277,12 +281,11 @@ def segmented_ladder(segments, state, dbl_fn, add_fn):
                 bits.append(1)
 
         def body(st, bit):
-            st_d = dbl_fn(st)
-            st_a = add_fn(st_d)
-            mask = bit.astype(bool)
-            st_n = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(mask, a, b), st_a, st_d)
-            return st_n, None
+            # `bit` is a scalar of the scanned constant, not a per-row
+            # mask: the conditional stays a real branch inside the
+            # `while`, so the add step runs on the set bits only
+            return jax.lax.cond(bit != 0, add_fn, lambda s: s,
+                                dbl_fn(st)), None
 
         state, _ = jax.lax.scan(body, state,
                                 jnp.asarray(bits, dtype=jnp.int32))

@@ -15,10 +15,12 @@ line steps):
   - The Fp12 accumulator lives in the FLAT representation (ops/flat12.py):
     squarings and line multiplications are single broadcasted Montgomery
     multiplies, not Karatsuba towers of separate ops.
-  - The loop over the 64-bit BLS parameter is statically segmented by the
-    parameter's bit pattern: `lax.scan` over each zero run (double-only
-    body) with the 5 set-bit addition steps unrolled between runs — the
-    graph stays a handful of small bodies, and no multiply is executed
+  - The loop over the 64-bit BLS parameter runs the addition step on the
+    parameter's 5 set tail bits only (field.segmented_ladder): statically
+    segmented, `lax.scan` over each zero run (double-only body) with the
+    addition steps unrolled between runs; in compact mode, which is what
+    the TPU serves, one scan over the 63 bits whose body puts the
+    addition step under a `lax.cond` on the bit.  No multiply is executed
     just to be masked away (a masked per-bit scan wastes the entire
     addition path on 58 of 63 iterations).
   - Lines are sparse flat elements: 3 Fp2 coefficients at w-powers
@@ -247,9 +249,9 @@ def miller_loop_pairs(pairs, active=None, _keep_tiled=False):
             newTs.append(Tk)
         return f, tuple(newTs)
 
-    # Static segmentation of the parameter bits (field.tail_segments):
-    # zero runs scan a double-only body; the 5 set bits unroll the
-    # addition step — nothing is computed just to be masked away.
+    # The parameter's bits are static (field.tail_segments): every bit
+    # runs the doubling half, only the 5 set bits the addition half —
+    # nothing is computed just to be masked away.
     f, _ = segmented_ladder(_X_SEGMENTS, (f, Ts),
                             lambda c: dbl_half(*c), add_half)
     f = F.flat_conj(f)                    # x < 0 (packed on Pallas)
@@ -312,10 +314,10 @@ def _miller_loop_pairs_merged(pf, pairs, active, shape, _keep_tiled=False):
 
 def _unitary_pow_x_abs(f):
     """f^|x| with cyclotomic squarings (valid: callers only pass
-    post-easy-part elements).  Same static segmentation as the Miller
-    loop: the zero runs scan a square-only body, the 5 set bits unroll
-    their multiply — the masked-scan version executed (and discarded) a
-    full Fp12 multiply on all 58 zero bits.  On the Pallas path the
+    post-easy-part elements).  The same ladder as the Miller loop
+    (field.segmented_ladder): 63 squarings and, on the 5 set bits only,
+    a multiply — a masked scan would execute (and discard) a full Fp12
+    multiply on all 58 zero bits.  On the Pallas path the
     chain is tile-resident, and a TileForm input stays packed (the
     whole final exponentiation now threads TileForm; `ft is f` exactly
     when no conversion happened)."""

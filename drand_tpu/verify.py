@@ -126,9 +126,9 @@ class Verifier:
         measured the static-unroll program at about three times the
         compact one's build time and six times its code size (655 Pallas
         call sites against 152; CHANGES.md), over twenty minutes a
-        bucket; until a chip A/B says the faster executable is worth its
-        build, the program a node can build at start-up is the one it
-        serves."""
+        bucket, and the compact ladder executes the same add steps (on
+        the set bits only, under a `lax.cond`): the program a node can
+        build at start-up is the one it serves."""
         import contextlib
 
         from drand_tpu.ops.field import compact_scope

@@ -19,12 +19,18 @@ and the `sqr_chain_mul` family, `g2_add_line`, `g2_point_dbl`/
 `g2_point_add`, `line_merge`, `flat_conj`/`flat_frob`, and the wider
 `fp2_products`/`fp2_sqrs` stackings.  The two Miller kernels stay in
 although each takes minutes: they are the ones the compiler refused.
+One composition is compiled too: a compact ladder (`cyclo_sqr` on every
+bit, dense `flat_mul` under a `lax.cond` on the set ones), because what
+the served program executes rests on the compiler keeping that
+conditional (ISSUE 26).
 
 As the on-chip-measurement guide sets out: the topology is described
 inside a module-scoped fixture that skips where it cannot be, nothing
 touches the TPU library while a module is imported, the persistent cache
 is off around the compiles, and they run in this process.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -108,3 +114,31 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in lowered.as_text(), \
         f"{name} lowered without a Pallas kernel"
     lowered.compile()      # raises what the chip's compiler would raise
+
+
+def test_compact_ladder_keeps_its_conditional_on_v5e(one_chip):
+    """What ISSUE 26 rests on: in compact mode a ladder is one `while`
+    whose add step (a Pallas call) sits under a `conditional` on the
+    scanned bit, and the chip's compiler keeps it one, neither turning it
+    into a select over both results nor refusing a Mosaic call there.
+    The ladder is f^|x| as `pairing._unitary_pow_x_abs` runs it."""
+    from drand_tpu.ops.field import compact_scope, segmented_ladder
+    from drand_tpu.ops.pairing import _X_SEGMENTS
+    pf = PFm.pallas_field(P)
+
+    def pow_x_abs(a):
+        ft = _tf(a)
+        with compact_scope():
+            out = segmented_ladder(
+                _X_SEGMENTS, ft, pf.cyclo_sqr,
+                lambda acc: pf.flat_mul(acc, ft, tuple(range(12))))
+        return out.tiles
+
+    arg = jax.ShapeDtypeStruct((NT, 384, *PFm._ROW), jnp.int32,
+                               sharding=one_chip)
+    text = jax.jit(pow_x_abs).lower(arg).compile().as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r" conditional\(", text)) == 1
+    # one call site a kernel: the body is not unrolled, the add step is
+    # in one branch only
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
