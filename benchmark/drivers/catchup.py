@@ -36,10 +36,10 @@ class Driver:
         size = self.ctx.config["bucket_rounds"]
         return [1] + list(range(ramp + 1, self.backlog + 1, size))
 
-    async def _serve(self, sigs: np.ndarray, label: str) -> str:
+    async def _serve(self, sigs: np.ndarray, prevs, label: str) -> str:
         from drand_tpu.chain.store import SqliteStore
         store = SqliteStore(os.path.join(self.ctx.workdir, f"{label}.db"))
-        H.fill_store(store, H.beacons_of(sigs))
+        H.fill_store(store, H.beacons_of(sigs, prevs))
         server, addr = await H.serve(store)
         self._stores.append(store)
         self._servers.append(server)
@@ -51,7 +51,7 @@ class Driver:
         store.close()
 
     async def setup(self) -> None:
-        self.addr = await self._serve(self.ctx.sigs, "serve")
+        self.addr = await self._serve(self.ctx.sigs, self.ctx.prevs, "serve")
 
     async def warmup(self) -> None:
         rec = await self._catch_up(
@@ -122,7 +122,7 @@ class Driver:
         from drand_tpu.chain.store import SqliteStore
         store = SqliteStore(db)
         try:
-            return H.stored_sigs(store, self.backlog,
+            return H.stored_rows(store, self.backlog,
                                  self.ctx.sigs.shape[1])
         finally:
             store.close()
@@ -130,15 +130,17 @@ class Driver:
 
     async def check_window(self, records: list[dict]) -> dict:
         """Every timed catch-up's store against the chain: all rounds, in
-        order, the served bytes; every wire message paired with a commit."""
+        order, the served bytes (of both fields, where the scheme is
+        chained); every wire message paired with a commit."""
         short = differing = 0
         for db in self._consumer_dbs:
-            rounds, got = self._committed(db)
+            rounds, sigs, prevs = self._committed(db)
             if len(rounds) != self.backlog or not (
                     rounds == np.arange(1, self.backlog + 1)).all():
                 short += 1
             else:
-                differing += int((got != self.ctx.sigs).any(axis=1).sum())
+                differing += H.rows_differing(sigs, prevs, self.ctx.sigs,
+                                              self.ctx.prevs)
         self._consumer_dbs = []
         unpaired = sum(r["chunks"] - len(r["chunk_commit_s"])
                        for r in records if r["ok"])
@@ -147,30 +149,45 @@ class Driver:
                 "window.committed_rows_differing": differing}
 
     async def check_faulted(self, draw: dict) -> dict:
-        """A catch-up on the chain with the faults planted must fail, and
-        commit no round at or after the first of them, and no byte that
-        the chain does not hold."""
-        first_bad = draw["faults"][0][0]
-        addr = await self._serve(H.plant(self.ctx.sigs, draw["faults"]),
-                                 "faulted")
+        """A catch-up from a node whose chain has the faults planted must
+        fail, and commit no round at or after the first damaged
+        signature, and no byte that the chain does not hold.
+
+        A damaged `previous_sig` (chained schemes) is the serving node's
+        own: the packed wire carries signatures, and a consumer links
+        each row to its own tail (`chain/segment.py`), so such a row
+        either fails the catch-up (served alone, as stored) or is
+        committed as the chain has it.  Where no signature is damaged at
+        all, the catch-up may therefore succeed; what it commits is held
+        to the chain all the same."""
+        ctx = self.ctx
+        fields = H.damaged_fields(draw["faults"], ctx.sigs.shape[1],
+                                  ctx.prevs is not None)
+        bad_sigs = [r for r, f in sorted(fields.items()) if "signature" in f]
+        first_bad = bad_sigs[0] if bad_sigs else self.backlog + 1
+        addr = await self._serve(
+            *H.plant(ctx.sigs, draw["faults"], ctx.prevs), "faulted")
         try:
             rec = await self._catch_up(addr, self.backlog)
         finally:
             await self._stop_serving()
-            os.remove(os.path.join(self.ctx.workdir, "faulted.db"))
-        rounds, got = self._committed(rec["db"])
+            os.remove(os.path.join(ctx.workdir, "faulted.db"))
+        rounds, sigs, prevs = self._committed(rec["db"])
         n = len(rounds)
         H.emit(faulted_pass={"first_bad_round": first_bad,
+                             "damaged": {str(r): sorted(f)
+                                         for r, f in fields.items()},
                              "sync_ok": rec["sync_ok"],
                              "committed_rounds": n, "wall_s": rec["wall_s"]})
         return {
-            "faulted.sync_ok": int(rec["sync_ok"]),
+            "faulted.sync_ok": int(rec["sync_ok"] and bool(bad_sigs)),
             "faulted.committed_at_or_after_first_bad":
                 int((rounds >= first_bad).sum()),
             "faulted.committed_out_of_order":
                 int((rounds != np.arange(1, n + 1)).sum()),
             "faulted.committed_rows_differing":
-                int((got != self.ctx.sigs[:n]).any(axis=1).sum())
+                H.rows_differing(sigs, prevs, ctx.sigs[:n],
+                                 ctx.prevs and ctx.prevs[:n])
                 if n <= self.backlog else n}
 
     async def close(self) -> None:

@@ -25,19 +25,24 @@ class Driver:
         return list(range(1, self.backlog + 1,
                           self.ctx.config["bucket_rounds"]))
 
-    def _filled(self, sigs, label: str):
+    def _filled(self, sigs, prevs, label: str, damaged: bool = False):
+        """A node's store holding these rows.  Damaged rows go in beneath
+        the store's decorators, as damage on a disk does (under a chained
+        scheme `SchemeStore` refuses a row that does not link)."""
         store = H.new_node_store(
             os.path.join(self.ctx.workdir, f"{label}.db"), self.ctx.group)
         self._stores.append(store)
-        H.fill_store(store, H.beacons_of(sigs))
+        H.fill_store(store.insecure if damaged and prevs is not None
+                     else store, H.beacons_of(sigs, prevs))
         return store
 
     async def setup(self) -> None:
-        self.store = self._filled(self.ctx.sigs, "node")
+        self.store = self._filled(self.ctx.sigs, self.ctx.prevs, "node")
 
     async def warmup(self) -> None:
         n = min(self.ctx.traffic["warmup_rounds"], self.backlog)
-        store = self._filled(self.ctx.sigs[:n], "warmup")
+        store = self._filled(self.ctx.sigs[:n], self.ctx.prevs
+                             and self.ctx.prevs[:n], "warmup")
         rec = await self._scan(store)
         self._drop(store)
         if not rec["ok"] or rec["report"]["tip_round"] != n:
@@ -75,37 +80,56 @@ class Driver:
     async def check_window(self, records: list[dict]) -> dict:
         """The scanned store still holds the chain, byte for byte (the
         scan reads; it may not write)."""
-        rounds, got = H.stored_sigs(self.store.insecure, self.backlog,
-                                    self.ctx.sigs.shape[1])
+        rounds, sigs, prevs = H.stored_rows(
+            self.store.insecure, self.backlog, self.ctx.sigs.shape[1])
         whole = len(rounds) == self.backlog
         return {"window.store_missing_rounds": int(not whole),
                 "window.stored_rows_differing":
-                    int((got != self.ctx.sigs).any(axis=1).sum())
+                    H.rows_differing(sigs, prevs, self.ctx.sigs,
+                                     self.ctx.prevs)
                     if whole else self.backlog - len(rounds)}
 
     async def check_faulted(self, draw: dict) -> dict:
-        """A scan of the chain with the faults planted reports exactly the
-        planted rounds as bad signatures, and nothing else."""
+        """A scan of the chain with the faults planted reports what the
+        plain reference finds there, and nothing else: under `bad_sigs`
+        only rounds whose signature is false over their own fields, under
+        `unlinked` only rounds whose stored `previous_sig` is not the
+        stored signature before them, and every damaged, false or
+        unlinked round under one of the two (the scan does not verify a
+        row it has filed as unlinked: `chain/recovery.py`).  Under an
+        unchained scheme nothing is unlinked, and this is: exactly the
+        planted rounds as bad signatures."""
+        ctx = self.ctx
         planted = {f[0] for f in draw["faults"]}
-        store = self._filled(H.plant(self.ctx.sigs, draw["faults"]),
-                             "faulted")
+        bad, bad_prevs = H.plant(ctx.sigs, draw["faults"], ctx.prevs)
+        invalid, unlinked = H.reference_findings(ctx.config, bad, bad_prevs,
+                                                 planted)
+        store = self._filled(bad, bad_prevs, "faulted", damaged=True)
         try:
             rec = await self._scan(store)
         finally:
             self._drop(store)
         rep = rec["report"]
-        found = set(rep["bad_sigs"])
+        found, found_unlinked = set(rep["bad_sigs"]), set(rep["unlinked"])
         H.emit(faulted_pass={"planted": sorted(planted),
+                             "invalid": sorted(invalid),
+                             "unlinked": sorted(unlinked),
                              "bad_sigs": rep["bad_sigs"][:16],
+                             "found_unlinked": rep["unlinked"][:16],
                              "verified_tip": rep["verified_tip"],
                              "wall_s": rec["wall_s"]})
-        return {
-            "faulted.bad_sigs_missed": len(planted - found),
-            "faulted.bad_sigs_spurious": len(found - planted),
+        out = {
+            "faulted.bad_sigs_missed":
+                len((planted | invalid | unlinked) - found - found_unlinked),
+            "faulted.bad_sigs_spurious": len(found - invalid)}
+        if ctx.prevs is not None:
+            out["faulted.unlinked_spurious"] = len(found_unlinked - unlinked)
+        out.update({
             "faulted.verified_tip_off_by":
                 abs(rep["verified_tip"] - (min(planted) - 1)),
             "faulted.other_findings": len(rep["corrupt"])
-                + len(rep["missing"]) + len(rep["unlinked"])}
+                + len(rep["missing"]) + len(found_unlinked - unlinked)})
+        return out
 
     async def close(self) -> None:
         for store in list(self._stores):

@@ -1,5 +1,5 @@
-"""Makes the benchmark's two fixture chains (run once, by hand; the
-outputs are committed beside this script).
+"""Makes the benchmark's fixture chains (run once, by hand; the outputs
+are committed beside this script).
 
     python benchmark/fixtures/make_fixtures.py
 
@@ -20,16 +20,31 @@ program's native tier accepts the first, the last and a sample of the
 extension.
 
 Prints each file's sha256, which the configuration's file records.
+
+    python benchmark/fixtures/make_fixtures.py --chained 1024
+
+signs a third chain and nothing else: `default-chained_<N>.npy`, rounds
+1..N of `pedersen-bls-chained` under the key of seed
+b"drand-tpu-bench-chained".  A round's message is
+sha256(previous_sig || uint64_be(round)), round 1's previous signature is
+the genesis seed sha256(b"drand-tpu-bench-chained-genesis"), so the
+rounds are signed one after another, by the program's native tier (its
+hash to G2 and one scalar multiplication).  Pinned: the first two
+signatures equal those of the benchmark's copy of the golden model,
+which also verifies a sample under the printed key.  Prints what the
+signing took, the sha256, the public key and the genesis seed.
 """
 
 from __future__ import annotations
 
+import argparse
 import concurrent.futures as cf
 import hashlib
 import multiprocessing as mp
 import os
 import struct
 import sys
+import time
 
 import numpy as np
 
@@ -37,6 +52,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 BACKLOG = 65536
 G1_SEED = b"drand-tpu-bench-g1sig"
+CHAINED_SEED = b"drand-tpu-bench-chained"
 
 
 def _digest(round_: int) -> bytes:
@@ -107,5 +123,52 @@ def main() -> None:
     print("public key (G2, 96 B):", pk96.hex())
 
 
+def chained(count: int) -> None:
+    """`default-chained_<count>.npy`, signed serially."""
+    sys.path.insert(0, ROOT)
+    from benchmark.reference import sign as S
+    from benchmark.reference.bls12381 import curve as C
+    from benchmark.reference.bls12381.constants import DST_G2
+    from drand_tpu import native
+    assert native.available(), "the native tier signs the chained chain"
+    sk, pk = S.keygen(CHAINED_SEED)
+    sk32 = sk.to_bytes(32, "big")
+    genesis = hashlib.sha256(CHAINED_SEED + b"-genesis").digest()
+
+    def message(prev: bytes, round_: int) -> bytes:
+        return hashlib.sha256(prev + struct.pack(">Q", round_)).digest()
+
+    sigs = np.zeros((count, 96), dtype=np.uint8)
+    prev = genesis
+    t0 = time.perf_counter()
+    for r in range(1, count + 1):
+        h = native.hash_to_g2(message(prev, r), DST_G2)
+        prev = native.g2_lincomb([h], [sk32])
+        sigs[r - 1] = np.frombuffer(prev, dtype=np.uint8)
+    took = time.perf_counter() - t0
+    assert bytes(sigs[0]) == S.bls_sign(sk, message(genesis, 1)), \
+        "round 1 differs from the golden model's signature"
+    assert bytes(sigs[1]) == S.bls_sign(sk, message(bytes(sigs[0]), 2)), \
+        "round 2 differs from the golden model's signature"
+    for r in sorted({1, 2, count} | set(range(3, count, max(count // 8, 1)))):
+        before = genesis if r == 1 else bytes(sigs[r - 2])
+        assert S.bls_verify(pk, message(before, r), bytes(sigs[r - 1])), \
+            f"the reference rejects round {r}"
+    out = os.path.join(HERE, f"default-chained_{count}.npy")
+    np.save(out, sigs)
+    print(f"signed {count} rounds serially in {took:.1f} s "
+          f"({1e3 * took / count:.2f} ms a round)")
+    print(os.path.basename(out), _sha256(out))
+    print("public key (G1, 48 B):", C.g1_to_bytes(pk).hex())
+    print("genesis seed:", genesis.hex())
+
+
 if __name__ == "__main__":
-    main()
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--chained", type=int, default=0, metavar="ROUNDS",
+                    help="sign only the chained chain, of this many rounds")
+    args = ap.parse_args()
+    if args.chained:
+        chained(args.chained)
+    else:
+        main()

@@ -4,7 +4,15 @@ thin wrappers that record them, the two-node stand (a copy of
 `_Clock` and `_StubVerifier`, which PR 22 ran on the chip: later PRs may
 change `tools/`, not the yardstick), the percentile rule, the pairing of
 chunk arrivals with commits, the seeded draw of sampled and faulted
-rounds, and the plain reference's verdicts.
+rounds, and the plain reference's verdicts and findings.
+
+A chain is an `[N, signature_bytes]` array of signatures, row i round
+i + 1.  Under a chained scheme (`"chained": true` in the configuration)
+a row also has a `previous_sig`, which is derived and never stored in a
+fixture: the signature of the row before, and for round 1 the chain's
+genesis seed (`genesis_seed_hex`).  `prevs` below is that column as a
+list of bytes, or None under an unchained scheme, where every function
+does what it did before it knew of one.
 
 Nothing here imports JAX.  `drand_tpu` is imported inside the functions
 that need it, after `run.py` has placed the configuration's environment.
@@ -49,6 +57,7 @@ class Ctx:
     config: dict            # configs/<name>.json
     traffic: dict           # traffic/<name>.json
     sigs: np.ndarray        # the chain: row i is round i + 1
+    prevs: "list[bytes] | None"   # its previous_sig column (chained only)
     group: "Group"
     spans: "Spans"
     verifier: object        # what the traffic is verified by, in spans
@@ -192,18 +201,42 @@ class Group:
 
     genesis_time = 0
 
-    def __init__(self, scheme_id: str, period: int):
+    def __init__(self, scheme_id: str, period: int,
+                 genesis_seed: bytes = b"genesis-seed-benchmark"):
         self.scheme_id = scheme_id
         self.period = period
+        self.genesis_seed = genesis_seed    # round 0's "signature"
 
 
-def beacons_of(sigs: np.ndarray, rounds=None) -> list:
-    """Beacons of the rows of `sigs`: rounds 1.. where none are given."""
+def group_of(config: dict) -> Group:
+    """The group of a configuration; a chained one states its genesis
+    seed, which is round 1's previous signature."""
+    if config["chained"]:
+        return Group(config["scheme_id"], config["period_s"],
+                     bytes.fromhex(config["genesis_seed_hex"]))
+    return Group(config["scheme_id"], config["period_s"])
+
+
+def previous_sigs(config: dict, sigs: np.ndarray):
+    """The chain's `previous_sig` column: for a chained configuration the
+    genesis seed and then each row before, else None."""
+    if not config["chained"]:
+        return None
+    return [bytes.fromhex(config["genesis_seed_hex"])] \
+        + [s.tobytes() for s in sigs[:-1]]
+
+
+def beacons_of(sigs: np.ndarray, prevs=None, rounds=None) -> list:
+    """Beacons of the rows of `sigs` (and of `prevs`, where the scheme is
+    chained): rounds 1.. where none are given."""
     from drand_tpu.chain.beacon import Beacon
     if rounds is None:
         rounds = range(1, len(sigs) + 1)
-    return [Beacon(round=int(r), signature=s.tobytes())
-            for r, s in zip(rounds, sigs)]
+    if prevs is None:
+        return [Beacon(round=int(r), signature=s.tobytes())
+                for r, s in zip(rounds, sigs)]
+    return [Beacon(round=int(r), signature=s.tobytes(), previous_sig=p)
+            for r, s, p in zip(rounds, sigs, prevs)]
 
 
 def fill_store(store, beacons) -> None:
@@ -218,7 +251,7 @@ def new_node_store(db_path: str, group: Group):
     from drand_tpu.chain.beacon import Beacon
     from drand_tpu.chain.store import new_chain_store
     store = new_chain_store(db_path, group)
-    store.put(Beacon(round=0, signature=b"genesis-seed-benchmark"))
+    store.put(Beacon(round=0, signature=group.genesis_seed))
     return store
 
 
@@ -248,14 +281,25 @@ async def serve(store):
     return server, f"127.0.0.1:{port}"
 
 
-def stored_sigs(store, count: int, sig_len: int):
-    """(rounds[N], sigs[N, sig_len]) of a store's rounds 1..count, the
-    genesis row left out."""
+def stored_rows(store, count: int, sig_len: int):
+    """(rounds[N], sigs[N, sig_len], prevs: N bytes) of a store's rounds
+    1..count, the genesis row left out."""
     rows = store.read_fields(1, count + 1)
     rounds = np.array([r for r, _, _ in rows], dtype=np.uint64)
     sigs = np.frombuffer(b"".join(s for _, s, _ in rows),
                          dtype=np.uint8).reshape(len(rows), sig_len)
-    return rounds, sigs
+    return rounds, sigs, [p for _, _, p in rows]
+
+
+def rows_differing(sigs, prevs, want_sigs, want_prevs) -> int:
+    """How many of the rows differ from the wanted ones in either field
+    (an unchained row's `previous_sig` is wanted empty)."""
+    differs = (sigs != want_sigs).any(axis=1)
+    if want_prevs is None:
+        want_prevs = [b""] * len(prevs)
+    differs |= np.array([p != w for p, w in zip(prevs, want_prevs)],
+                        dtype=bool)
+    return int(differs.sum())
 
 
 # -- verifiers that are not the program's -------------------------------------
@@ -286,15 +330,18 @@ class StubVerifier:
 
 class HostVerifier:
     """For the CPU rehearsal only: real verdicts with no device program,
-    each row through the program's host tier.  Unchained schemes only
-    (no linkage is checked)."""
+    each row through the program's host tier over the row's own
+    `previous_sig`.  Under a chained scheme a segment's linkage is held
+    as `ChainVerifier` holds it (`verify_chain_segment_async`,
+    `verify_packed_segment_async`; no second semantics): a list of
+    beacons links each `previous_sig` to the signature before it, the
+    first to the caller's anchor; a packed segment has no such column,
+    its rows are given the anchor and then each other's signatures, so a
+    server's `first_prev` is never trusted."""
 
     def __init__(self, chain_verifier):
         self._cv = chain_verifier
         self.scheme = chain_verifier.scheme
-        if not self.scheme.decouple_prev_sig:
-            raise BenchFailure("the rehearsal's host verifier knows "
-                               "unchained schemes only")
 
     def verify_beacon(self, beacon) -> bool:
         return self._cv.verify_beacon(beacon)
@@ -304,10 +351,20 @@ class HostVerifier:
                         dtype=bool)
 
     def verify_chain_segment_async(self, beacons, anchor_prev_sig):
-        return lambda: self.verify_beacons(beacons)
+        if self.scheme.decouple_prev_sig:
+            return lambda: self.verify_beacons(beacons)
+        want = [anchor_prev_sig] + [b.signature for b in beacons[:-1]]
+        linked = np.array([b.previous_sig == w
+                           for b, w in zip(beacons, want)], dtype=bool)
+        return lambda: self.verify_beacons(beacons) & linked
 
     def verify_packed_segment_async(self, packed, anchor_prev_sig):
-        return lambda: self.verify_beacons(packed.beacons())
+        if self.scheme.decouple_prev_sig:
+            return lambda: self.verify_beacons(packed.beacons())
+        sigs = [row.tobytes() for row in packed.sigs]
+        return self.verify_chain_segment_async(beacons_of(
+            packed.sigs, [anchor_prev_sig] + sigs[:-1], packed.rounds()),
+            anchor_prev_sig)
 
 
 # -- metric arithmetic --------------------------------------------------------
@@ -377,35 +434,91 @@ def draw_check(seed: int, backlog: int, starts: list[int], ramp: int,
             "sample": sorted(sample - rounds), "faults": planted}
 
 
-def plant(sigs: np.ndarray, faults) -> np.ndarray:
-    """A copy of the chain with one bit of each faulted round's signature
-    flipped (the byte index is taken modulo the signature's length)."""
+def fault_at(byte: int, sig_len: int, chained: bool) -> tuple[str, int]:
+    """The field a fault's byte index falls into, and where in it: taken
+    modulo the signature's length where the rows have no `previous_sig`;
+    where they have, modulo twice that, the upper half being the
+    `previous_sig`."""
+    at = byte % (2 * sig_len if chained else sig_len)
+    return ("previous_sig", at - sig_len) if at >= sig_len \
+        else ("signature", at)
+
+
+def plant(sigs: np.ndarray, faults, prevs=None):
+    """(sigs, prevs): a copy of the chain with one bit of each faulted
+    round flipped, in the field `fault_at` names (round 1's
+    `previous_sig` is the shorter genesis seed: modulo its length).  A
+    row's damage is its own: the row after a flipped signature keeps the
+    true one as its `previous_sig`."""
     bad = sigs.copy()
+    bad_prevs = None if prevs is None else list(prevs)
     for round_, byte, bit in faults:
-        bad[round_ - 1, byte % sigs.shape[1]] ^= np.uint8(1 << bit)
-    return bad
+        field, at = fault_at(byte, sigs.shape[1], prevs is not None)
+        if field == "signature":
+            bad[round_ - 1, at] ^= np.uint8(1 << bit)
+        else:
+            prev = bytearray(bad_prevs[round_ - 1])
+            prev[at % len(prev)] ^= 1 << bit
+            bad_prevs[round_ - 1] = bytes(prev)
+    return bad, bad_prevs
+
+
+def damaged_fields(faults, sig_len: int, chained: bool) -> dict:
+    """{round: the fields `plant` damages there}, from the faults alone."""
+    out: dict[int, set] = {}
+    for round_, byte, _bit in faults:
+        out.setdefault(round_, set()).add(
+            fault_at(byte, sig_len, chained)[0])
+    return out
 
 
 # -- the plain reference ------------------------------------------------------
 
-def reference_verdicts(config: dict, rounds, sigs: np.ndarray) -> np.ndarray:
+def reference_verdicts(config: dict, rounds, sigs: np.ndarray,
+                       prevs=None) -> np.ndarray:
     """Verdicts of the benchmark's own copy of the golden model
     (`benchmark/reference`: pure Python, imports nothing of the program)
-    on (round, signature) pairs of an unchained scheme under the
-    configuration's public key."""
+    on rows under the configuration's public key: the message is
+    sha256(uint64_be(round)), and under a chained scheme
+    sha256(previous_sig || uint64_be(round)) of the row's own
+    `previous_sig`."""
     from benchmark.reference import sign as S
     from benchmark.reference.bls12381 import curve as C
-    if config["chained"]:
-        raise BenchFailure("the reference knows unchained schemes only")
     pk_bytes = bytes.fromhex(config["public_key_hex"])
     on_g1 = config["signature_group"] == "G1"
     pk = C.g2_from_bytes(pk_bytes) if on_g1 else C.g1_from_bytes(pk_bytes)
     check = S.bls_verify_g1 if on_g1 else S.bls_verify
+    if not config["chained"]:
+        prevs = [b""] * len(sigs)
     out = []
-    for r, sig in zip(rounds, sigs):
-        msg = hashlib.sha256(struct.pack(">Q", int(r))).digest()
+    for r, sig, prev in zip(rounds, sigs, prevs):
+        msg = hashlib.sha256(prev + struct.pack(">Q", int(r))).digest()
         out.append(bool(check(pk, msg, bytes(sig))))
     return np.array(out, dtype=bool)
+
+
+def reference_findings(config: dict, sigs: np.ndarray, prevs,
+                       damaged) -> tuple[set, set]:
+    """Two independent facts of the rows of a (damaged) store holding
+    rounds 1..N, as sets of rounds: *invalid*, the signature is false
+    over the row's own `previous_sig` and round; *unlinked*, the stored
+    `previous_sig` is not the stored signature of the row before (the
+    genesis seed for round 1; never, under an unchained scheme).  Linkage
+    is read off every row.  A pairing in pure Python takes a sixth of a
+    second, so validity is judged on the `damaged` rounds alone: every
+    other row is the fixture's, true when it was made."""
+    damaged = sorted(damaged)
+    at = np.array(damaged, dtype=np.int64) - 1
+    ok = reference_verdicts(
+        config, damaged, sigs[at],
+        None if prevs is None else [prevs[i] for i in at])
+    invalid = {r for r, good in zip(damaged, ok) if not good}
+    unlinked = set()
+    if prevs is not None:
+        want = previous_sigs(config, sigs)
+        unlinked = {i + 1 for i, (p, w) in enumerate(zip(prevs, want))
+                    if p != w}
+    return invalid, unlinked
 
 
 def peaks_for(device_kind: str) -> dict:

@@ -16,11 +16,10 @@ The stages and `unscoped` then add up to the union of the intervals, which
 is `device.busy_s.*`.  Seconds are for every `per_rounds` rounds.  The
 traced run also logs, once, the dearest Pallas kernels by name and stage.
 
-`benchmark/run.py` deletes the trace before it asks any reader
-(`Run._reduce_trace`), so under it there is nothing to read and these
-metrics are left out of the line; `benchmark/stage_report.py` reads the
-trace first.  A program without the scopes (before PR 25) gives
-`unscoped` alone, which is no reading either: None.
+`benchmark/run.py` keeps the trace in the run's work directory until
+`Run.close` (since PR 28), so it is there when `result` asks.  A program
+without the scopes (before PR 25) gives `unscoped` alone, which is no
+reading: None.
 """
 
 from __future__ import annotations

@@ -13,8 +13,7 @@ operation, on the clock the harness reads (`time.perf_counter`, a span's
                                       rounds, of the trace's idle gaps of
                                       a millisecond or more over at least
                                       half of which no program span was
-                                      open.  The gaps are the five longest
-                                      (`trace_reduce` keeps no more)
+                                      open
 
 A program that publishes no `start_mono` (before PR 25) gives nothing to
 read: every metric here is then left out of the line.  The traced run
@@ -116,12 +115,12 @@ def reduced(run) -> dict | None:
     H.emit(program_spans={"count": len(rows), "rounds": rounds,
                           "by_name": by_name})
     trace = run.trace
-    if trace and trace.get("longest_gaps_at"):
+    if trace and trace.get("gaps_at"):
         # the gaps are given from the window's first mark, which is where
         # the harness read `window[0]`; spans begun before the window
         # (none today) would be missed here
         gaps = [(window[0] + g["at_s"], window[0] + g["at_s"] + g["for_s"])
-                for g in trace["longest_gaps_at"]
+                for g in trace["gaps_at"]
                 if g["for_s"] >= MIN_GAP_S]
         out["gaps"] = gaps
         H.emit(idle_gaps_under_program_spans=[

@@ -11,10 +11,12 @@ with new files and one entry of `BENCHMARK.json`.
 
 Every line of standard output is one JSON object.  All but the last are
 observations of this run; the last is the result the driver reads
-(`correct`, `attempted`, `failed`, `metrics`, `device`, and with
-`--trace 1` `breakdown`).  Off the TPU, with another number of chips than
-the cell asks for, or in a directory without the program, the run prints
-no result and exits with code 2.
+(`correct`, `attempted`, `failed`, `metrics`, `device`, with `--trace 1`
+`breakdown`, and last `checks`: every number the output check compared,
+beside its limit, which are also the last lines of standard error).  Off
+the TPU, with another number of chips than the cell asks for, or in a
+directory without the program, the run prints no result and exits with
+code 2.
 
 `--rehearse stub|host` is for the sandbox: JAX on the CPU, the first
 `rehearse_rounds` of the chain, and in the program's place a verifier
@@ -304,11 +306,12 @@ class Run:
         self.cache_before = _cache_entries(self.cache_dir)
         self.sigs = self._load_fixture()
         self.chain_verifier, verifier = self._make_verifier()
-        self.group = H.Group(self.config["scheme_id"],
-                             self.config["period_s"])
+        self.prevs = H.previous_sigs(self.config, self.sigs)
+        self.group = H.group_of(self.config)
         self.workdir = tempfile.mkdtemp(prefix="drand-bench-")
         self.ctx = H.Ctx(config=self.config, traffic=self.traffic,
-                         sigs=self.sigs, group=self.group, spans=self.spans,
+                         sigs=self.sigs, prevs=self.prevs,
+                         group=self.group, spans=self.spans,
                          verifier=H.SpanVerifier(verifier, self.spans),
                          workdir=self.workdir)
         module = importlib.import_module(
@@ -360,8 +363,8 @@ class Run:
             # device number, and goes on
             H.emit(trace_not_reduced=str(exc)[:400])
             return
-        finally:
-            shutil.rmtree(logdir, ignore_errors=True)
+        # the trace stays where it is for the readers that `result` asks
+        # (`readers/device_scopes.py`); `close` removes the work directory
         self.trace["rounds"] = rounds
         H.emit(trace={k: v for k, v in self.trace.items()
                       if k not in ("device_ops", "idle_gaps")})
@@ -407,18 +410,32 @@ class Run:
     def _verdict_checks(self, draw: dict) -> None:
         """The sampled and the faulted rounds, judged three times: by what
         the traffic is verified by (the device program), by the program's
-        host tier, and by the benchmark's plain reference."""
-        sample, faults = draw["sample"], draw["faults"]
-        bad = H.plant(self.sigs, faults)
-        rounds = np.array(sample + [f[0] for f in faults], dtype=np.uint64)
-        batch = np.concatenate([self.sigs[np.array(sample) - 1],
-                                bad[np.array([f[0] for f in faults]) - 1]])
+        host tier, and by the benchmark's plain reference.  Under a
+        chained scheme every row carries its `previous_sig`, and each
+        fault is judged in both fields: as planted, and the same bit in
+        the row's other field."""
+        sample, faults = draw["sample"], list(draw["faults"])
+        if self.prevs is not None:
+            faults += [(r, byte + self.sigs.shape[1], bit)
+                       for r, byte, bit in draw["faults"]]
+        at = np.array(sample) - 1
+        rounds, sigs = list(sample), [self.sigs[at]]
+        prevs = None if self.prevs is None else [self.prevs[i] for i in at]
+        for r, byte, bit in faults:     # each into a copy of its own row
+            bad, bad_prevs = H.plant(
+                self.sigs[r - 1:r], [(1, byte, bit)],
+                self.prevs and self.prevs[r - 1:r])
+            rounds.append(r)
+            sigs.append(bad)
+            if prevs is not None:
+                prevs += bad_prevs
+        batch = np.concatenate(sigs)
         want = np.array([True] * len(sample) + [False] * len(faults))
-        beacons = H.beacons_of(batch, rounds)
+        beacons = H.beacons_of(batch, prevs, rounds)
         served = np.asarray(self.ctx.verifier.verify_beacons(beacons))
         host = np.array([self.chain_verifier.verify_beacon(b)
                          for b in beacons])
-        ref = H.reference_verdicts(self.config, rounds, batch)
+        ref = H.reference_verdicts(self.config, rounds, batch, prevs)
         self._compare("verdicts.reference_differs_from_construction",
                       int((ref != want).sum()))
         self._compare("verdicts.served_differs_from_reference",
@@ -516,6 +533,9 @@ class Run:
             device["window_s"] = self.trace["window_s"]
             out["breakdown"] = {"device_ops": self.trace["device_ops"],
                                 "idle_gaps": self.trace["idle_gaps"]}
+        # last in the line: every number compared, beside its limit
+        out["checks"] = {c["name"]: [c["value"], c["limit"]]
+                         for c in self.checks}
         return out
 
     def observe(self) -> None:
@@ -585,9 +605,12 @@ def main(argv=None) -> int:
                reason=f"{type(exc).__name__}: {exc}"[:2000])
         return 1
     H.emit(**out)
-    if not out["correct"]:
-        print("benchmark: not correct: " + json.dumps(out["not_held"]),
-              file=sys.stderr)
+    # the last lines of standard error: each number compared, its limit
+    print("benchmark: " + ("correct" if out["correct"] else "NOT CORRECT"),
+          file=sys.stderr)
+    for name, (value, limit) in out["checks"].items():
+        print(f"benchmark: compared {name} {value} limit {limit}"
+              + ("" if value == limit else "  NOT HELD"), file=sys.stderr)
     return 0 if out["correct"] else 1
 
 

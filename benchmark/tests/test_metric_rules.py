@@ -53,7 +53,8 @@ def test_the_draw_is_the_seeds_alone_and_meets_every_kind_of_round():
 
 def test_plant_flips_one_bit_of_each_faulted_round():
     sigs = np.arange(5 * 48, dtype=np.uint8).reshape(5, 48)
-    bad = H.plant(sigs, [(2, 1000, 3), (5, 7, 0)])
+    bad, bad_prevs = H.plant(sigs, [(2, 1000, 3), (5, 7, 0)])
+    assert bad_prevs is None
     diff = np.bitwise_xor(bad, sigs)
     assert np.count_nonzero(diff) == 2
     assert diff[1, 1000 % 48] == 8 and diff[4, 7] == 1
@@ -69,7 +70,8 @@ def test_an_unknown_device_kind_is_an_error():
 def test_a_thin_window_goes_on_for_one_more_catch_up():
     from benchmark.drivers.catchup import Driver
     ctx = H.Ctx(config={}, traffic={}, sigs=np.zeros((4, 96), np.uint8),
-                group=None, spans=H.Spans(), verifier=None, workdir="")
+                prevs=None, group=None, spans=H.Spans(), verifier=None,
+                workdir="")
     one = {"ok": True, "rounds": 65536, "chunk_commit_s": [1.0] * 128}
     assert Driver(ctx).wants_more([one])            # 128 carry no p95
     assert not Driver(ctx).wants_more([one, one])   # 256 do

@@ -207,7 +207,7 @@ def _recorded_run(monkeypatch, with_trace=True):
                         bucket=16384, pad_rows=15872)
     tracing.record_span("verify.dispatch", 104.0, 104.5, n=16384,
                         bucket=16384, pad_rows=0)
-    trace = {"longest_gaps_at": [
+    trace = {"gaps_at": [
         {"at_s": 2.2, "for_s": 0.6, "open": "no_span"},
         {"at_s": 10.4, "for_s": 0.4, "open": "no_span"},
         {"at_s": 5.0, "for_s": 0.0004, "open": "no_span"}]}

@@ -89,6 +89,38 @@ def test_the_stub_verifier_comes_out_not_correct(cell):
                for c in compared)
 
 
+CHAINED = os.path.join(ROOT, "benchmark", "tests", "chained",
+                       "BENCHMARK.json")
+
+
+def _chained_cells():
+    with open(CHAINED) as f:
+        return [w["name"] for w in json.load(f)["workloads"]]
+
+
+@pytest.mark.parametrize("cell", _chained_cells())
+def test_a_chained_configuration_rehearses_from_data_alone(cell):
+    """`pedersen-bls-chained` on 1,024 rounds, a configuration file and
+    entries of a BENCHMARK.json of its own: correct on the host tier (the
+    seed plants damage in both fields), not correct on the stub."""
+    seed = str(2**31 + 105)
+    proc, lines = _run("--workload", cell, "--seed", seed, "--seconds", "1",
+                       "--trace", "0", "--rehearse", "host",
+                       "--bench-file", CHAINED)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert lines[-1]["correct"] is True and lines[-1]["failed"] == 0
+    draw = [ln for ln in lines if "check_draw" in ln][-1]["check_draw"]
+    fields = set().union(*H.damaged_fields(
+        [tuple(f) for f in draw["faults"]], 96, True).values())
+    assert fields == {"signature", "previous_sig"}
+    proc, lines = _run("--workload", cell, "--seed", seed, "--seconds", "1",
+                       "--trace", "0", "--rehearse", "stub",
+                       "--bench-file", CHAINED)
+    assert proc.returncode == 1 and lines[-1]["correct"] is False
+    assert any(c["name"].startswith("verdicts.")
+               for c in lines[-1]["not_held"])
+
+
 def test_the_crossings_run_from_data_alone(tmp_path):
     path, added = _crossed(tmp_path)
     assert len(added) == 2

@@ -185,8 +185,8 @@ def reduce_trace(logdir: str, window_pc, spans_pc) -> dict:
         device_events=len(all_events),
         device_ops=top_ops(all_events),
         idle_gaps=summarize_gaps(gaps, spans),
-        longest_gaps_at=[
+        gaps_at=[
             {"at_s": g[0] - window[0], "for_s": g[1] - g[0],
              "open": label_gap(g, spans)}
-            for g in sorted(gaps, key=lambda g: g[0] - g[1])[:5]])
+            for g in gaps if g[1] - g[0] >= SHORT_GAP_S])
     return out
