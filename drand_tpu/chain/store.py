@@ -476,13 +476,17 @@ class SchemeStore(StoreDecorator):
             last = self.inner.last()
         except BeaconNotFound:
             last = None
-        prev = last
-        for b in beacons:
-            if prev is not None and b.round == prev.round + 1 \
-                    and b.previous_sig != prev.signature:
-                raise StoreError(
-                    f"round {b.round} previous-sig does not link to chain")
-            prev = b
+        # `store.link_check`: the walk over every appended row, one span
+        # a segment, on this (the chained) branch only
+        from drand_tpu import tracing
+        with tracing.span("store.link_check", rows=len(beacons)):
+            prev = last
+            for b in beacons:
+                if prev is not None and b.round == prev.round + 1 \
+                        and b.previous_sig != prev.signature:
+                    raise StoreError(f"round {b.round} previous-sig does "
+                                     "not link to chain")
+                prev = b
         self.inner.put_many(beacons)
 
 
@@ -613,9 +617,13 @@ class CallbackStore(StoreDecorator):
         from drand_tpu import tracing
         from drand_tpu.chaos import failpoints as chaos
         beacons = list(beacons)
+        # counted before the span opens: its time stays what it was
+        payload = sum(len(b.signature) + len(b.previous_sig)
+                      for b in beacons)
         with tracing.span("store.commit", beacon_id=self.beacon_id,
                           round_=beacons[-1].round if beacons else None,
-                          batch=len(beacons)):
+                          batch=len(beacons), rows=len(beacons),
+                          payload_bytes=payload):
             if beacons:
                 chaos.failpoint_sync("store.commit", exc=StoreError,
                                      owner=self.owner,

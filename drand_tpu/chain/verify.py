@@ -67,7 +67,8 @@ class ChainVerifier:
         catch-up sync and check-chain scale with chips (SURVEY.md §5.8)."""
         if self._lazy_verifier is None:
             import jax
-            v = Verifier(self._pk_point, self.scheme.shape)
+            v = Verifier(self._pk_point, self.scheme.shape,
+                         single_host=self._verify_single)
             if len(jax.devices()) > 1:
                 from drand_tpu.parallel import ShardedVerifier
                 v = ShardedVerifier(v)
@@ -101,10 +102,15 @@ class ChainVerifier:
         from drand_tpu import tracing
         with tracing.span("verify.beacon", beacon_id=self.beacon_id,
                           round_=beacon.round):
-            return self._verify_beacon_inner(beacon)
+            return self._verify_single(beacon.round, beacon.signature,
+                                       beacon.previous_sig)[0]
 
-    def _verify_beacon_inner(self, beacon: Beacon) -> bool:
-        msg = self.digest_message(beacon.round, beacon.previous_sig)
+    def _verify_single(self, round_: int, signature: bytes,
+                       previous_sig: bytes) -> tuple[bool, str]:
+        """One round on the host: (verdict, the tier that gave it).  Also
+        the batched verifier's check of a shape-irregular row (round 1
+        over the 32-byte genesis seed)."""
+        msg = self.digest_message(round_, previous_sig)
         native_ok = False
         try:
             from drand_tpu import native
@@ -113,13 +119,10 @@ class ChainVerifier:
             _warn_native_unavailable(f"import failed: {type(e).__name__}: {e}")
         if native_ok:
             try:
-                if self.scheme.shape.sig_on_g1:
-                    return native.verify_g1(self.public_key_bytes, msg,
-                                            beacon.signature,
-                                            self.scheme.shape.dst)
-                return native.verify_g2(self.public_key_bytes, msg,
-                                        beacon.signature,
-                                        self.scheme.shape.dst)
+                check = native.verify_g1 if self.scheme.shape.sig_on_g1 \
+                    else native.verify_g2
+                return bool(check(self.public_key_bytes, msg, signature,
+                                  self.scheme.shape.dst)), "native"
             except Exception:
                 # a per-call failure is NOT tier unavailability: log it
                 # (with traceback) and fall back for this beacon only
@@ -130,12 +133,12 @@ class ChainVerifier:
             _warn_native_unavailable("native.available() returned False "
                                      "(g++ build failed or missing)")
         from drand_tpu.crypto import sign as S
+        check = S.bls_verify_g1 if self.scheme.shape.sig_on_g1 \
+            else S.bls_verify
         try:
-            if self.scheme.shape.sig_on_g1:
-                return S.bls_verify_g1(self._pk_point, msg, beacon.signature)
-            return S.bls_verify(self._pk_point, msg, beacon.signature)
+            return bool(check(self._pk_point, msg, signature)), "golden"
         except Exception:
-            return False
+            return False, "golden"
 
     def verify_beacons_async(self, beacons: list[Beacon]):
         """Dispatch a batch verify without blocking; returns a zero-arg

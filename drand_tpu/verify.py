@@ -27,6 +27,7 @@ import numpy as np
 
 from drand_tpu import tracing
 from drand_tpu.crypto.bls12381.constants import DST_G1, DST_G2
+from drand_tpu.ops import DIGEST
 from drand_tpu.ops import bls as BLS
 from drand_tpu.ops.sha256 import sha256
 from drand_tpu.profiling import record_dispatch
@@ -74,11 +75,15 @@ SHAPE_UNCHAINED_G1 = SchemeShape(chained=False, sig_on_g1=True, dst=DST_G1)
 class Verifier:
     """Batched beacon verifier for one chain (public key + scheme shape)."""
 
-    def __init__(self, public_key, shape: SchemeShape):
+    def __init__(self, public_key, shape: SchemeShape, single_host=None):
         """public_key: golden-model Jacobian point — G1 for G2-signature
-        schemes, G2 for the short-sig scheme."""
+        schemes, G2 for the short-sig scheme.  `single_host(round, sig,
+        prev_sig) -> (ok, tier)` is the owner's check of ONE round off the
+        device (`ChainVerifier` hands its live path's: native when built,
+        golden model else); without it the golden model checks."""
         self.shape = shape
         self._pk_golden = public_key
+        self._single_host = single_host
         if shape.sig_on_g1:
             self._pk = BLS._const_g2_affine(public_key)
         else:
@@ -140,7 +145,8 @@ class Verifier:
         def run(msgs_u8, sig_u8, pk):
             with (contextlib.nullcontext() if compact is None
                   else compact_scope(compact)):
-                digest = sha256(msgs_u8)
+                with jax.named_scope(DIGEST):
+                    digest = sha256(msgs_u8)
                 if shape.sig_on_g1:
                     return BLS.verify_g1_sigs(digest, sig_u8, pk, shape.dst)
                 return BLS.verify_g2_sigs(digest, sig_u8, pk, shape.dst)
@@ -222,7 +228,8 @@ class Verifier:
         # `verify.dispatch`: message build and padding (`prepare_s`), then
         # host-to-device and the enqueue (`enqueue_s`); a bucket built
         # lazily is its child `verifier.build`.  `pad_rows` over `bucket`
-        # is the share of the device's work that is padding.
+        # is the share of the device's work that is padding; `msg_bytes`
+        # is one row's message, `h2d_bytes` what the dispatch sends.
         with tracing.span("verify.dispatch", n=n) as sp:
             msgs = self.messages(rounds, prev_sigs)
             m = _bucket(n)
@@ -239,7 +246,8 @@ class Verifier:
                         jnp.asarray(sigs, dtype=jnp.uint8), self._pk)
             dispatch_s = time.perf_counter() - t1
             sp.set(bucket=m, pad_rows=m - n, prepare_s=t0 - sp.start_mono,
-                   enqueue_s=dispatch_s)
+                   enqueue_s=dispatch_s, msg_bytes=msgs.shape[1],
+                   h2d_bytes=msgs.nbytes + sigs.nbytes)
         done = [False]    # split dispatch/resolve: record exactly once
 
         def resolve():
@@ -273,12 +281,17 @@ class Verifier:
         anchor_prev_sig = np.asarray(anchor_prev_sig, dtype=np.uint8)
         if b and anchor_prev_sig.shape[0] != sigs.shape[1]:
             # irregular anchor (round 1 links to the 32-byte genesis
-            # seed): host-check the first element, batch the rest
-            first_ok = self._verify_single_host(
-                start_round, bytes(sigs[0]), bytes(anchor_prev_sig))
+            # seed): the rest is enqueued first, so the host checks the
+            # first element while the device works; its verdict is exact
+            # and ANDed into the segment's all the same
             rest = self.verify_chain_segment_async(
                 start_round + 1, sigs[1:], sigs[0]) if b > 1 else \
                 (lambda: np.zeros(0, dtype=bool))
+            with tracing.span("verify.genesis_link",
+                              round_=int(start_round)) as sp:
+                first_ok, tier = self._verify_single_host(
+                    start_round, bytes(sigs[0]), bytes(anchor_prev_sig))
+                sp.set(tier=tier, ok=first_ok)
             return lambda: np.concatenate(
                 [[first_ok], rest()]).astype(bool)
         rounds = np.arange(start_round, start_round + b, dtype=np.uint64)
@@ -299,8 +312,11 @@ class Verifier:
                                                anchor_prev_sig)()
 
     def _verify_single_host(self, round_: int, sig: bytes,
-                            prev_sig: bytes) -> bool:
-        """Golden-model scalar check (used for shape-irregular elements)."""
+                            prev_sig: bytes) -> tuple[bool, str]:
+        """Scalar check of one shape-irregular element off the device:
+        (verdict, the tier that gave it)."""
+        if self._single_host is not None:
+            return self._single_host(round_, sig, prev_sig)
         import hashlib
 
         from drand_tpu.crypto import sign as S
@@ -311,10 +327,10 @@ class Verifier:
         msg = h.digest()
         try:
             if self.shape.sig_on_g1:
-                return S.bls_verify_g1(self._pk_golden, msg, sig)
-            return S.bls_verify(self._pk_golden, msg, sig)
+                return S.bls_verify_g1(self._pk_golden, msg, sig), "golden"
+            return S.bls_verify(self._pk_golden, msg, sig), "golden"
         except Exception:
-            return False
+            return False, "golden"
 
 
 # jit once at module scope: re-wrapping `jax.jit(sha256)` per call made

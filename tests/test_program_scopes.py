@@ -27,8 +27,10 @@ _LOC = re.compile(r'= loc\("(jit\(run\)[^"]*)"')
 
 
 def test_one_vocabulary_for_both_schemes():
-    assert ops.STAGES == ("sig_decode", "h2c", "miller", "final_exp")
-    assert (ops.SIG_DECODE, ops.H2C, ops.MILLER, ops.FINAL_EXP) == ops.STAGES
+    assert ops.STAGES == ("digest", "sig_decode", "h2c", "miller",
+                          "final_exp")
+    assert (ops.DIGEST, ops.SIG_DECODE, ops.H2C, ops.MILLER,
+            ops.FINAL_EXP) == ops.STAGES
 
 
 @pytest.mark.parametrize("shape,pk", [
@@ -54,8 +56,10 @@ def test_every_stage_scopes_the_lowered_verify_program(shape, pk):
             # another stage
             assert parts[1] == stage, path
     assert all(by_stage.values()), by_stage
-    # outside the stages: the message digest and the verdict's last ANDs
-    assert len(unscoped) < 0.02 * len(paths), unscoped[:5]
+    # outside the stages: glue and the verdict's last ANDs (the digest
+    # has a stage of its own since ISSUE 29)
+    assert len(unscoped) < 0.005 * len(paths), unscoped[:5]
+    assert not any("sha256" in p or "digest" in p for p in unscoped)
 
 
 def _two_stage_program(pf, scoped: bool):
