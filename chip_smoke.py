@@ -237,10 +237,10 @@ async def smoke(backlog: int = BACKLOG) -> dict:
     device_verifier = chain_verifier._verifier
     # ONE program, the deep-backlog bucket: building a verify program
     # takes a large part of this script's time limit (PR 22: about six
-    # minutes on the chip's host, most of it Python tracing), so the
-    # 512-round first chunk of the catch-up is padded to the big program
-    # instead of getting the small one a node would also build.  The
-    # path is the same; only the padding differs.  Built ahead of the
+    # minutes on the chip's host, most of it Python tracing).  The
+    # catch-up asks the verifier what a dispatch is charged for and
+    # cuts its segments where this one program is full, so no
+    # 512-round first chunk is padded into it.  Built ahead of the
     # traffic so that its cost is read apart from the catch-up's.
     bucket = V._bucket(min(SYNC_CHUNK_MAX, backlog))
     emit(buckets=[bucket], note="one program; the buckets a node would "

@@ -140,6 +140,16 @@ class ChainVerifier:
         except Exception:
             return False, "golden"
 
+    def rows_charged(self, n: int) -> int:
+        """Rows of verification a batch of `n` costs on the tier that
+        will take it: `n` itself on the host tier (a small batch before
+        the device verifier exists), else the device verifier's program
+        (`Verifier.rows_charged`).  The catch-up cuts its segments by
+        it."""
+        if n <= _HOST_VERIFY_MAX and self._lazy_verifier is None:
+            return n
+        return self._verifier.rows_charged(n)
+
     def verify_beacons_async(self, beacons: list[Beacon]):
         """Dispatch a batch verify without blocking; returns a zero-arg
         callable that blocks and yields bool[B].

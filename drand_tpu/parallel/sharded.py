@@ -111,6 +111,12 @@ class ShardedVerifier:
             cache[m] = fn
         return cache[m]
 
+    def rows_charged(self, n: int) -> int:
+        """`Verifier.rows_charged` on this mesh: every device's equal
+        slice is padded into the verifier's program."""
+        from drand_tpu.verify import _bucket
+        return _bucket(-(-n // self.n_dev)) * self.n_dev
+
     def verify_batch_async(self, rounds, sigs, prev_sigs=None):
         """Dispatch a sharded batch verify without blocking; returns a
         zero-arg callable yielding bool[B] (same contract as
@@ -132,10 +138,8 @@ class ShardedVerifier:
         v = self.verifier
         msgs = v.messages(rounds, prev_sigs)
         # pad to devices * bucket granularity
-        per_dev = -(-n // self.n_dev)
-        from drand_tpu.verify import _bucket
-        per_dev = _bucket(per_dev)
-        m = per_dev * self.n_dev
+        m = self.rows_charged(n)
+        per_dev = m // self.n_dev
         if m != n:
             pad = m - n
             msgs = np.concatenate([msgs, np.repeat(msgs[-1:], pad, 0)])

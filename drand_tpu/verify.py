@@ -210,6 +210,13 @@ class Verifier:
                 "trace_s": t1 - t0, "lower_s": t2 - t1,
                 "compile_s": t3 - t2, "lowered": lowered}
 
+    def rows_charged(self, n: int) -> int:
+        """Rows of device work a dispatch of `n` rows costs: the program
+        it is padded into.  `verify_batch_async` pads with this same
+        answer; a caller that chooses its batch size (the catch-up's
+        segment cut) asks it first and fills the program."""
+        return _bucket(n)
+
     def verify_batch_async(self, rounds, sigs: np.ndarray,
                            prev_sigs: np.ndarray | None = None):
         """Dispatch a batched verify WITHOUT blocking on the result.
@@ -232,7 +239,7 @@ class Verifier:
         # is one row's message, `h2d_bytes` what the dispatch sends.
         with tracing.span("verify.dispatch", n=n) as sp:
             msgs = self.messages(rounds, prev_sigs)
-            m = _bucket(n)
+            m = self.rows_charged(n)
             if m != n:
                 pad = m - n
                 msgs = np.concatenate(

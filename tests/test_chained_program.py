@@ -198,7 +198,8 @@ class _Clock:
 
 def _catch_up(chained, served: np.ndarray, tmp_path, monkeypatch):
     """(ok, rounds, sigs, prevs committed) of one catch-up from round 1
-    in 40-round segments."""
+    to N with a 40-round target: the backlog is known, so the first
+    segment is cut where the 64-row program is full (ISSUE 30)."""
     config, _sigs, seed, cv, _text = chained
     monkeypatch.setattr(SM, "SYNC_CHUNK", SEGMENT)
     monkeypatch.setattr(SM, "SYNC_CHUNK_GROWTH", 1)
@@ -218,20 +219,25 @@ def _catch_up(chained, served: np.ndarray, tmp_path, monkeypatch):
     return ok, rounds, got, prevs
 
 
-@pytest.mark.parametrize("flip", [None, 0, 47],
+FULL = 64           # V._bucket(SEGMENT): where the catch-up cuts
+
+
+@pytest.mark.parametrize("flip", [None, 0, 70],
                          ids=["sound", "round_1", "second_segment"])
 def test_a_catch_up_commits_nothing_at_or_after_a_flipped_signature(
         chained, tmp_path, monkeypatch, flip):
-    """Two segments of 40 rounds, each one dispatch of the 64-row
-    program.  What is committed equals the true chain in both fields."""
+    """Two segments, 64 rounds (round 1 on the host, 63 rows on the
+    device) and the 16 left, each one dispatch of the 64-row program.
+    What is committed equals the true chain in both fields."""
     config, sigs = chained[:2]
+    assert V._bucket(SEGMENT) == FULL
     served = sigs if flip is None else _flipped(sigs, flip)
     tracing.RECORDER.clear()
     ok, rounds, got, prevs = _catch_up(chained, served, tmp_path,
                                        monkeypatch)
     # a failed segment commits nothing of itself: the committed rounds
     # end where the flipped row's segment begins
-    committed = N if flip is None else (flip // SEGMENT) * SEGMENT
+    committed = N if flip is None else (flip // FULL) * FULL
     assert ok is (flip is None)
     assert rounds.tolist() == list(range(1, committed + 1))
     assert H.rows_differing(got, prevs, sigs[:committed],
@@ -243,7 +249,13 @@ def test_a_catch_up_commits_nothing_at_or_after_a_flipped_signature(
     commits = [sp for sp in by_name.get("store.commit", ())
                if sp.attrs.get("rows")]
     checks = by_name.get("store.link_check", [])
-    assert len(commits) == len(checks) == -(-committed // SEGMENT)
+    assert len(commits) == len(checks) == -(-committed // FULL)
+    segments = [(sp.attrs["rounds"], sp.attrs["cut"])
+                for sp in by_name["sync.segment"]]
+    assert segments == [(FULL, "full"), (N - FULL, "backlog_end")]
+    # round 1 is the host's: 63 rows of the first segment on the device
+    assert [sp.attrs["n"] for sp in by_name["verify.dispatch"]] \
+        == [FULL - 1, N - FULL]
     assert sum(sp.attrs["rows"] for sp in checks) == committed
     # two fields a row; round 1's previous signature is the 32-byte seed
     assert sum(sp.attrs["payload_bytes"] for sp in commits) == \

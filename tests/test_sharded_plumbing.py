@@ -106,6 +106,20 @@ def test_sharded_verify_batch_plumbing():
     assert not ok[5] and ok.sum() == n - 1
 
 
+def test_rows_charged_is_what_a_dispatch_is_padded_to():
+    """The answer the catch-up cuts its segments by (ISSUE 30) is the
+    size the sharded dispatch compiles and pads to: every device's
+    slice in the verifier's own bucket."""
+    from drand_tpu.verify import _bucket
+    sv = ShardedVerifier(_StubVerifier())
+    n = 20
+    assert sv.rows_charged(n) == 8 * _bucket(3)
+    sv.verify_batch(np.arange(1, n + 1, dtype=np.uint64),
+                    np.zeros((n, 96), dtype=np.uint8))
+    assert list(sv._skernels) == [sv.rows_charged(n)]
+    assert sv.rows_charged(8 * _bucket(3) + 1) == 2 * sv.rows_charged(n)
+
+
 def test_sharded_kernel_inputs_actually_sharded():
     """The compiled sharded kernel receives mesh-sharded inputs (not
     arrays silently de-sharded back to one device)."""

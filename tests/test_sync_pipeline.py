@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 import drand_tpu.beacon.sync_manager as SM
+import drand_tpu.verify as V
 from drand_tpu import fixtures
 from drand_tpu.chain.beacon import Beacon
 from drand_tpu.chain.scheme import scheme_by_id
@@ -510,3 +511,141 @@ def test_a_failed_segment_ends_its_span_and_its_successors(chain,
     # uncommitted, and says so
     assert all(v == "discarded" for k, v in status.items() if k > 7)
     assert all(s.duration_s is not None for s in segments)
+
+
+# -- where a segment is cut (ISSUE 30) ----------------------------------------
+#
+# The device is charged by the program a dispatch is padded into, so a
+# catch-up that knows its backlog cuts a segment where that program is
+# full.  The fake below is a DEVICE verifier under a real ChainVerifier:
+# `Verifier.verify_batch_async` pads with `rows_charged` as on the chip,
+# and only the compiled program is replaced (a row is false iff its
+# signature's first byte is 0xFF).
+
+BACKLOG = 256
+
+
+class _FakeDevice(V.Verifier):
+    def __init__(self, shape):
+        self.shape = shape
+        self._pk = None
+        self._kernels = {}
+        self._single_host = lambda round_, sig, prev: (sig[0] != 0xFF,
+                                                       "fake")
+        self.dispatches = []       # (n, rows charged) of every dispatch
+
+    def _kernel(self, m):
+        def program(msgs, sigs, pk):
+            assert msgs.shape[0] == sigs.shape[0] == m
+            return np.asarray(sigs)[:, 0] != 0xFF
+        return program
+
+    def verify_batch_async(self, rounds, sigs, prev_sigs=None):
+        self.dispatches.append((len(rounds), self.rows_charged(len(rounds))))
+        return super().verify_batch_async(rounds, sigs, prev_sigs)
+
+
+class _Unasked:
+    """A verifier that does not answer the row question."""
+
+    def __init__(self, inner):
+        self.verify_packed_segment_async = inner.verify_packed_segment_async
+
+
+def _fake_chain(chained: bool, bad_round: int | None):
+    """BACKLOG rounds of made-up signatures as 2-round wire messages."""
+    sigs = np.random.default_rng(30).integers(
+        0, 128, size=(BACKLOG, 96), dtype=np.uint8)
+    if bad_round is not None:
+        sigs[bad_round - 1, 0] = 0xFF
+    return [SM.PackedBeacons(start_round=at + 1, sigs=sigs[at:at + 2],
+                             first_prev=SEED if at == 0 else
+                             sigs[at - 1].tobytes() if chained else b"",
+                             chained=chained)
+            for at in range(0, BACKLOG, 2)]
+
+
+CUT_CASES = {
+    # name: (buckets, scheme, up_to, asked, bad round) ->
+    #       (segments as (rounds, cut), device dispatches as (n, charged),
+    #        rounds committed)
+    "a_one_bucket_known_backlog_fills_the_program": (
+        ((64,), "pedersen-bls-unchained", BACKLOG, True, None),
+        ([(64, "full")] * 3 + [(64, "backlog_end")], [(64, 64)] * 4,
+         BACKLOG)),
+    "b_chained_from_round_1_sends_63_rows_first": (
+        ((64,), "pedersen-bls-chained", BACKLOG, True, None),
+        ([(64, "full")] * 3 + [(64, "backlog_end")],
+         [(63, 64)] + [(64, 64)] * 3, BACKLOG)),
+    "c_a_bucket_at_the_ramp_keeps_the_ramp": (
+        ((2, 64), "pedersen-bls-unchained", BACKLOG, True, None),
+        ([(2, "full")] + [(64, "full")] * 3 + [(62, "backlog_end")],
+         [(2, 2)] + [(64, 64)] * 3 + [(62, 64)], BACKLOG)),
+    "d_follow_mode_keeps_the_ramp": (
+        ((64,), "pedersen-bls-unchained", 0, True, None),
+        ([(2, "target")] + [(64, "target")] * 3 + [(62, "stream_end")],
+         [(2, 64)] + [(64, 64)] * 3 + [(62, 64)], BACKLOG)),
+    "e_a_backlog_inside_the_program_is_one_segment": (
+        ((64,), "pedersen-bls-unchained", 40, True, None),
+        ([(40, "backlog_end")], [(40, 64)], 40)),
+    "f_a_verifier_that_does_not_answer_keeps_the_ramp": (
+        ((64,), "pedersen-bls-unchained", BACKLOG, False, None),
+        ([(2, "target")] + [(64, "target")] * 3 + [(62, "backlog_end")],
+         [(2, 64)] + [(64, 64)] * 3 + [(62, 64)], BACKLOG)),
+    "g_a_false_row_in_the_second_full_segment": (
+        ((64,), "pedersen-bls-unchained", BACKLOG, True, 100),
+        (None, None, 64)),
+    "h_a_false_row_first_in_the_second_full_segment": (
+        ((64,), "pedersen-bls-unchained", BACKLOG, True, 65),
+        (None, None, 64)),
+    "i_a_false_row_last_in_the_first_full_segment": (
+        ((64,), "pedersen-bls-unchained", BACKLOG, True, 64),
+        (None, None, 0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CUT_CASES))
+def test_a_segment_is_cut_where_the_program_is_full(chain, monkeypatch,
+                                                    case):
+    from drand_tpu import tracing
+    (buckets, scheme_id, up_to, asked, bad_round), \
+        (want_segments, want_dispatches, want_committed) = CUT_CASES[case]
+    monkeypatch.setattr(V, "_BUCKETS", buckets)
+    monkeypatch.setattr(SM, "SYNC_CHUNK", 2)
+    monkeypatch.setattr(SM, "SYNC_CHUNK_MAX", 64)   # the throughput bucket
+    scheme = scheme_by_id(scheme_id)
+    cv = ChainVerifier(scheme, chain[1].public_key_bytes)
+    device = cv._lazy_verifier = _FakeDevice(scheme.shape)
+    chained = not scheme.decouple_prev_sig
+    store = _seeded_store()
+    mgr = SM.SyncManager(
+        store=store, group=FakeGroup(),
+        verifier=cv if asked else _Unasked(cv),
+        network=ChunkNet(_fake_chain(chained, bad_round)),
+        nodes=[object()], clock=FixedClock())
+    tracing.RECORDER.clear()
+    ok = asyncio.run(mgr._try_node(object(), SM.SyncRequest(1, up_to)))
+    assert ok is (bad_round is None)
+    # in order, and nothing at or after a failing segment's first round
+    assert sorted(store.by_round) == list(range(0, want_committed + 1))
+    segments = [(s.attrs["first_round"], s.attrs["rounds"], s.attrs["cut"],
+                 s.status)
+                for s in tracing.RECORDER.spans() if s.name == "sync.segment"]
+    if bad_round is not None:
+        sound = want_committed // 64           # whole segments before it
+        assert segments[:sound + 1] == \
+            [(1 + 64 * i, 64, "full", "ok") for i in range(sound)] \
+            + [(1 + want_committed, 64, "full", "verify_failed")]
+        assert all(s[3] == "discarded" for s in segments[sound + 1:])
+        return
+    assert [s[1:3] for s in segments] == want_segments
+    assert all(s[3] == "ok" for s in segments)
+    assert device.dispatches == want_dispatches
+    # the spans the benchmark reads `verify.pad_share` from say the same
+    assert [(s.attrs["n"], s.attrs["bucket"], s.attrs["pad_rows"])
+            for s in tracing.RECORDER.spans() if s.name == "verify.dispatch"] \
+        == [(n, m, m - n) for n, m in want_dispatches]
+    if chained:
+        links = [s for s in tracing.RECORDER.spans()
+                 if s.name == "verify.genesis_link"]
+        assert [s.round for s in links] == [1]
