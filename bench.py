@@ -91,22 +91,8 @@ def _emit(value, metric, unit="verifies/sec", **extra):
         "layout_conversions_traced": layout_conversion_counts(),
         **extra,
     }
-    # unified perf schema rides along (tools/perf/schema.py): the gate
-    # and the BENCH_HISTORY trajectory consume `records`, while the
-    # legacy top-level fields keep old consumers working
-    try:
-        from tools.perf import schema as perf_schema
-        from tools.perf.migrate import _direction_for
-        record["records"] = [perf_schema.make_record(
-            bench="kernel", metric=metric, value=record["value"],
-            unit=unit, direction=_direction_for(unit, metric),
-            timestamp=perf_schema.stamp(), config=CONFIG,
-            device=record["device"], writer="bench.py",
-            extras={k: v for k, v in record.items() if k != "records"})]
-    except Exception as exc:
-        print(f"bench: unified record emit failed: {exc}", file=sys.stderr)
     # the printed line IS the on-disk record (test_bench_protocol pins
-    # the parity), unified records included
+    # the parity)
     print(json.dumps(record))
     if _JSON_OUT and _JSON_OUT != "-":
         with open(_JSON_OUT, "w") as f:

@@ -11,8 +11,8 @@ read or written.
 The rest of this module serializes whole executables
 (`jax.experimental.serialize_executable`) to `aot/*.aotx` and loads them
 back without tracing, lowering or compiling.  The CPU tier keeps it: the
-driver's dryrun entry point (`__graft_entry__.py`), the sharded CPU mesh
-(`parallel/sharded.py`) and the warm stages.
+driver's dryrun entry point (`__graft_entry__.py`) and the sharded CPU
+mesh (`parallel/sharded.py`).
 
 Keying: entries are valid only for the exact program, so the cache key
 hashes (a) a caller-supplied name + static config, (b) the source of every
@@ -45,9 +45,9 @@ def persistent_cache_dir() -> str:
     """THE directory of JAX's persistent compilation cache: wherever
     `JAX_COMPILATION_CACHE_DIR` places it, else `.jax_cache` in the
     checkout (git-ignored).  The path is part of the cache's key, so it
-    never depends on a pid, a time or a temporary name.  jax-free: the
-    warm orchestrator substitutes it into stage env without importing
-    jax."""
+    never depends on a pid, a time or a temporary name.  jax-free: a
+    parent that starts JAX children (`demo/orchestrator.py`) hands it to
+    them without bringing a backend up itself."""
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         ".jax_cache")
@@ -152,9 +152,8 @@ def cache_path(name: str, extra: str = "") -> str:
     # superseded-entry pruning matches on the name stem, so key material
     # in the name would defeat it.
     # The Miller kernel-path flags (merged-iteration kernel, sparse line
-    # merge) also change the traced program without changing source —
-    # warm_r9 A/Bs them, so executables for different paths must never
-    # collide in the cache.
+    # merge) also change the traced program without changing source, so
+    # executables for different paths must never collide in the cache.
     from drand_tpu.ops.field import compact_graphs, miller_path_tag
     tag = hashlib.sha256(
         f"{name}|{_env_tag()}|{code_hash()}|compact={int(compact_graphs())}"
@@ -166,23 +165,10 @@ def _safe_name(name: str) -> str:
     return "".join(c if c.isalnum() or c in "-_." else "_" for c in name)
 
 
-def entries_for(name: str) -> list[str]:
-    """Existing cache-entry filenames for the logical `name`, any
-    env/code tag.  Deliberately jax-free (stem scan, no `_env_tag()`):
-    the warm orchestrator's done-detection runs in a process that must
-    never pay — or hang on — a backend init.  Pair with `code_hash()`
-    to decide whether an entry matches the current kernels."""
-    d = aot_dir()
-    if not os.path.isdir(d):
-        return []
-    safe = _safe_name(name)
-    return sorted(fn for fn in os.listdir(d)
-                  if fn.endswith(".aotx") and fn.rsplit("-", 1)[0] == safe)
-
-
 def warming() -> bool:
-    """True when the process is a warm run (tools/aot_warm.py or
-    `DRAND_TPU_AOT_WARM=1`): cache misses compile AND persist."""
+    """True when the process is a warm run (`DRAND_TPU_AOT_WARM=1`, as
+    `scripts/warm_artifacts.sh` sets it): cache misses compile AND
+    persist."""
     return bool(os.environ.get("DRAND_TPU_AOT_WARM"))
 
 

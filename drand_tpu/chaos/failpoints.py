@@ -77,10 +77,6 @@ SITES: dict[str, str] = {
                         "stream stays up; ctx: src, dst, round",
     "relay.exchange":   "outbound gossip peer-exchange RPC "
                         "(relay/gossip.py); ctx: src, dst",
-    "warm.stage_exec":  "one warm-pipeline stage attempt before its "
-                        "subprocess spawns (warm/runner.py); error = a "
-                        "dropped-connection-shaped transient the RetryPolicy "
-                        "must recover; ctx: pipeline, stage, attempt",
     "probe.sample":     "one consistency-probe signature sample "
                         "(observatory/consistency.py); drop = probe "
                         "suppressed, error = the sampled peer serves a "

@@ -1004,6 +1004,24 @@ async def _drive_fork_detect(net: ScenarioNet, seed: int,
     return target
 
 
+async def _await_counted(net: ScenarioNet, nodes, signers, round_: int,
+                         timeout: float) -> None:
+    """Wait, with a deadline, until the ledger of every node in `nodes`
+    has every signer in `signers` on its books for `round_`.  A round
+    lands in a store at the threshold, while the remaining partials are
+    still in flight; the next recovery seals the round's margin without
+    them.  So a drive that asserts on margins lets the stragglers land
+    before it moves the clock on.  Past the deadline it goes on, and the
+    drive's own assertions say what is missing."""
+    loop = asyncio.get_running_loop()
+    deadline = loop.time() + timeout
+    while loop.time() < deadline:
+        if all(net.process(i).handler.ledger.is_counted(s, round_)
+               for i in nodes for s in signers):
+            return
+        await asyncio.sleep(0.02)
+
+
 async def _drive_signer_loss(net: ScenarioNet, seed: int,
                              rng: random.Random) -> int:
     """Fleet-observatory acceptance (ISSUE 19): a seeded signer dies and
@@ -1013,13 +1031,16 @@ async def _drive_signer_loss(net: ScenarioNet, seed: int,
     victim rejoins.  An ordinary outage must raise no fork reports."""
     healthy_margin = net.n - net.thr
     base = max(net.last_rounds())
+    group = net.process(0).group
+    all_signers = [n.index for n in group.nodes]
     # a few healthy rounds first: every ledger must show the full margin
-    await net.advance_to_round(base + 3)
+    for r in range(base + 1, base + 4):
+        await net.advance_to_round(r)
+        await _await_counted(net, range(net.n), all_signers, r, timeout=10.0)
     victim = rng.randrange(1, net.n)          # keep the DKG leader alive
     vic_addr = net.daemons[victim].private_addr()
     surv_idx = [i for i in range(net.n) if i != victim]
     survivors = [net.daemons[i] for i in surv_idx]
-    group = net.process(surv_idx[0]).group
     vic_signer = next(n.index for n in group.nodes
                       if n.address == vic_addr)
     for i in surv_idx:
@@ -1059,6 +1080,8 @@ async def _drive_signer_loss(net: ScenarioNet, seed: int,
     while True:
         target += 1
         await net.advance_to_round(target, timeout=120.0)
+        # short: the victim may still be syncing and owe this round
+        await _await_counted(net, surv_idx, all_signers, target, timeout=2.0)
         if all(net.process(i).handler.ledger.last_final_margin ==
                healthy_margin for i in surv_idx):
             break

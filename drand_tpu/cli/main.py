@@ -165,26 +165,6 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--json", action="store_true", dest="json_out",
                     help="util fsck: machine-readable report on stdout")
 
-    sp = sub.add_parser("perf", help="perf trajectory utilities: gate "
-                        "unified bench artifacts against the committed "
-                        "baselines, show the gated history")
-    sp.add_argument("action", choices=["gate", "history"])
-    sp.add_argument("artifacts", nargs="*",
-                    help="perf gate: unified bench artifact JSON paths")
-    sp.add_argument("--baseline", default=None,
-                    help="baselines file (default: committed "
-                    "tools/perf/baselines.json)")
-    sp.add_argument("--history", default=None,
-                    help="history JSONL path (default: "
-                    "BENCH_HISTORY.jsonl)")
-    sp.add_argument("--no-history", action="store_true",
-                    help="perf gate: do not append to the history")
-    sp.add_argument("--metric", default=None,
-                    help="perf history: filter to one <bench>/<metric> "
-                    "key")
-    sp.add_argument("--limit", type=int, default=20,
-                    help="perf history: newest entries to show")
-
     sp = sub.add_parser("relay", help="run an HTTP relay over upstreams")
     sp.add_argument("--url", action="append", required=True,
                     help="upstream HTTP API endpoints")
@@ -247,35 +227,6 @@ def build_parser() -> argparse.ArgumentParser:
                     "fault window (loop-blocking callbacks, unlocked / "
                     "cross-task mutations); also via "
                     "DRAND_TPU_ASYNC_SANITIZE=1")
-
-    sp = sub.add_parser("warm", help="warm/measure pipeline orchestrator "
-                        "(drand_tpu/warm): resumable, retrying, "
-                        "checkpointed AOT warm chains with environment "
-                        "preflight")
-    sp.add_argument("action",
-                    choices=["run", "resume", "status", "doctor", "list"])
-    sp.add_argument("pipeline", nargs="?", default="",
-                    help="pipeline name (warm list shows them)")
-    sp.add_argument("--workdir", default="",
-                    help="override the spec's working directory "
-                    "(artifacts + state.json checkpoint)")
-    sp.add_argument("--no-doctor", action="store_true",
-                    help="skip the environment preflight before "
-                    "run/resume (eyes open)")
-    sp.add_argument("--fast-doctor", action="store_true",
-                    help="preflight without the two-subprocess "
-                    "compile-cache probe")
-    sp.add_argument("--seed", type=int, default=0,
-                    help="retry-backoff hash seed (replay a chain's "
-                    "retry schedule byte-for-byte)")
-    sp.add_argument("--heartbeat", type=float, default=30.0,
-                    help="seconds between stage progress lines")
-    sp.add_argument("--metrics", type=int, default=-1, dest="warm_metrics",
-                    help="serve /metrics + /debug/spans on this port "
-                    "while the chain runs (0 = ephemeral port; default "
-                    "off)")
-    sp.add_argument("--json", action="store_true", dest="warm_json",
-                    help="machine-readable output (status/doctor)")
 
     sp = sub.add_parser("relay-s3", help="relay rounds into an object "
                         "store (cmd/relay-s3/main.go)")
@@ -840,93 +791,6 @@ async def cmd_chaos(args):
             print("  " + json.dumps(entry, sort_keys=True))
 
 
-class _WarmMetricsShim:
-    """A daemon-shaped object for MetricsServer when the warm
-    orchestrator (no daemon, no beacons) serves its exposition: the
-    registry's warm/AOT collectors plus /debug/spans for the per-stage
-    tracing spans."""
-
-    processes: dict = {}
-
-
-async def cmd_warm(args):
-    """Warm-pipeline orchestrator: run/resume/status a declarative
-    warm chain, or run the environment doctor standalone.  Jax-free on
-    purpose — stages pay backend init in their own subprocesses, and
-    the doctor probes it from a subprocess precisely because it can
-    hang."""
-    from drand_tpu.warm import doctor as wdoctor
-    from drand_tpu.warm import runner as wrunner
-    from drand_tpu.warm import specs as wspecs
-    from drand_tpu.warm.spec import repo_root
-
-    if args.action == "list":
-        for name, spec in sorted(wspecs.SPECS.items()):
-            print(f"{name}: {len(spec.stages)} stages — {spec.doc}")
-            for st in spec.order():
-                deps = f" (after {', '.join(st.deps)})" if st.deps else ""
-                print(f"  {st.name:20s} timeout={int(st.timeout_s)}s"
-                      f"{deps}")
-        return
-
-    if args.action == "doctor":
-        spec = wspecs.get(args.pipeline) if args.pipeline else None
-        workdir = args.workdir or os.path.join(
-            repo_root(), spec.workdir if spec else "warm_logs")
-        results = await asyncio.to_thread(
-            wdoctor.run_doctor, workdir, args.fast_doctor)
-        if args.warm_json:
-            print(json.dumps([{"name": r.name, "ok": r.ok,
-                               "verdict": r.verdict} for r in results],
-                             indent=2))
-        ok = wdoctor.print_results(results)
-        if not ok:
-            raise SystemExit(2)
-        return
-
-    if not args.pipeline:
-        raise SystemExit(f"warm {args.action} needs a pipeline name "
-                         "(see `drand-tpu warm list`)")
-    spec = wspecs.get(args.pipeline)
-    runner = wrunner.PipelineRunner(
-        spec, args.workdir or None, seed=args.seed,
-        heartbeat_s=args.heartbeat)
-
-    if args.action == "status":
-        st = runner.status()
-        if args.warm_json:
-            print(json.dumps(st, indent=2, sort_keys=True))
-        else:
-            print(f"pipeline {st['pipeline']} "
-                  f"({'complete' if st['complete'] else 'incomplete'}) "
-                  f"— state: {st['state_file']}")
-            for row in st["stages"]:
-                print(f"  {row['stage']:20s} {row['status']:8s} "
-                      f"attempts={row['attempts']} next={row['next']} "
-                      f"({row['why']})")
-        return
-
-    # run / resume
-    if not args.no_doctor:
-        results = await asyncio.to_thread(
-            wdoctor.run_doctor, runner.workdir, args.fast_doctor)
-        if not wdoctor.print_results(results):
-            raise SystemExit(2)
-    metrics_srv = None
-    if args.warm_metrics >= 0:
-        from drand_tpu.metrics import MetricsServer
-        metrics_srv = MetricsServer(_WarmMetricsShim(), args.warm_metrics)
-        await metrics_srv.start()
-    try:
-        await runner.run(resume=(args.action == "resume"))
-    except wrunner.StageFailure:
-        raise SystemExit(1)    # the runner already printed the verdict
-    finally:
-        if metrics_srv is not None:
-            await metrics_srv.stop()
-    print(f"warm {spec.name}: complete (state: {runner.state_path})")
-
-
 class _Boto3Backend:
     """Adapt a boto3 Bucket to the put(key, body) backend protocol."""
 
@@ -1191,51 +1055,6 @@ def cmd_lint(args) -> int:
     return lint_run(argv)
 
 
-def cmd_perf(args) -> int:
-    """Perf trajectory utilities (tools/perf).  Synchronous and
-    jax-free, like `lint`: gating a bench artifact or reading the
-    history must not pay the device-stack import."""
-    import pathlib
-    root = pathlib.Path(__file__).resolve().parents[2]
-    if str(root) not in sys.path:
-        sys.path.insert(0, str(root))
-    try:
-        from tools.perf import gate, schema
-    except ImportError:
-        print("error: tools/perf not importable — `drand-tpu perf` "
-              "needs a repo checkout", file=sys.stderr)
-        return 2
-    if args.action == "gate":
-        if not args.artifacts:
-            print("perf gate needs artifact paths: "
-                  "drand-tpu perf gate BENCH_foo.json [...]",
-                  file=sys.stderr)
-            return 2
-        argv = list(args.artifacts)
-        if args.baseline:
-            argv += ["--baseline", args.baseline]
-        if args.history:
-            argv += ["--history", args.history]
-        if args.no_history:
-            argv.append("--no-history")
-        return gate.main(argv)
-    # history: newest gated entries, optionally one metric's trajectory
-    entries = gate.read_history(args.history or gate.DEFAULT_HISTORY,
-                                limit=args.limit, metric=args.metric)
-    if not entries:
-        print("no gated history"
-              + (f" for {args.metric}" if args.metric else ""))
-        return 0
-    for e in entries:
-        rec = e.get("record", {})
-        delta = e.get("delta_frac")
-        print(f"{e.get('gated_at', 0):.0f}  [{e.get('status', '?'):9s}] "
-              f"{schema.metric_key(rec)}: {rec.get('value')} "
-              f"{rec.get('unit', '')}"
-              + (f"  ({delta:+.1%})" if delta is not None else ""))
-    return 0
-
-
 _COMMANDS = {
     "start": cmd_start, "stop": cmd_stop,
     "generate-keypair": cmd_generate_keypair, "share": cmd_share,
@@ -1243,7 +1062,7 @@ _COMMANDS = {
     "show": cmd_show, "util": cmd_util,
     "relay": cmd_relay, "relay-pubsub": cmd_relay_pubsub,
     "relay-s3": cmd_relay_s3, "objectsync": cmd_objectsync,
-    "chaos": cmd_chaos, "warm": cmd_warm,
+    "chaos": cmd_chaos,
 }
 
 
@@ -1266,8 +1085,6 @@ def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     if args.command == "lint":     # sync, jax-free
         return cmd_lint(args)
-    if args.command == "perf":     # sync, jax-free
-        return cmd_perf(args)
     if args.command == "chaos":
         # the scenario nets sync only dozens of rounds: pin the small
         # verify bucket the default test suite already warms, instead of

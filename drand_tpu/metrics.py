@@ -244,24 +244,6 @@ QUEUE_DROPPED = Counter(
     "shed instead of silent backlog growth (queue = partial_verify / "
     "sync_requests / watch_fanout / dkg_fanout)",
     ["queue"], registry=REGISTRY)
-# warm-pipeline orchestrator (drand_tpu/warm): the resumable warm/
-# measure chains that replaced the hand-run stage() shell scripts —
-# per-stage outcomes (success/skipped/fatal/exhausted + the classify
-# verdicts) and wall durations, plus the AOT executable cache's
-# compile-vs-load economics the whole subsystem exists to manage
-# (fresh-process load must beat the <60 s bar; a compile is the
-# hours-long event the checkpoints protect)
-WARM_STAGE = Counter(
-    "drand_warm_stage_total",
-    "Warm-pipeline stage outcomes per pipeline and stage "
-    "(success/skipped/transient/fatal/exhausted)",
-    ["pipeline", "stage", "outcome"], registry=REGISTRY)
-WARM_STAGE_DURATION = Histogram(
-    "drand_warm_stage_duration_seconds",
-    "Wall duration of one successful warm-pipeline stage subprocess",
-    ["pipeline", "stage"], registry=REGISTRY,
-    buckets=(1.0, 5.0, 15.0, 60.0, 300.0, 900.0, 1800.0, 3600.0,
-             7200.0, 14400.0))
 AOT_COMPILE_SECONDS = Gauge(
     "drand_aot_compile_seconds",
     "Seconds the last XLA compile of this AOT cache entry took "

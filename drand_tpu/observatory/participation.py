@@ -104,6 +104,11 @@ class ParticipationLedger:
 
     def note_partial(self, idx: int, round_: int) -> None:
         """A VALID partial accepted for a live (unsettled) round."""
+        if round_ in self._records:
+            # accepted as live, but the round recovered from the others
+            # while this one's signature was being checked
+            self.note_late(idx, round_)
+            return
         self.newest[idx] = max(round_, self.newest.get(idx, 0))
         obs = self._open.get(round_)
         if obs is None:

@@ -74,13 +74,6 @@ JAX_PLATFORMS=cpu python scripts/serve_smoke.py
 # device kernel at bucket 4 and asserts verdicts match the legacy path.
 JAX_PLATFORMS=cpu python scripts/partials_smoke.py
 
-# warm smoke (drand_tpu/warm, ISSUE 8): the tiny 3-stage smoke3 spec
-# end-to-end through the real CLI — orchestrator SIGKILLed mid-stage,
-# `warm status` reads the surviving checkpoint, `warm resume` completes
-# with the finished stage skipped and the injected transient failure
-# (exit 137) retried through the RetryPolicy, then a fast doctor pass.
-JAX_PLATFORMS=cpu python scripts/warm_smoke.py
-
 # mesh smoke: seeded kill/restart/one-way-partition churn over a
 # 24-node gossip relay mesh with the monotonic/no-fork/liveness/
 # mesh-degree invariant sweep (drand_tpu/chaos/mesh.py; 100 nodes
@@ -127,15 +120,6 @@ JAX_PLATFORMS=cpu python scripts/objectsync_smoke.py
 # one member must cover all group peers over the gRPC metrics channel;
 # and the real `util fleet` CLI renders the same fleet as a table.
 JAX_PLATFORMS=cpu python scripts/observatory_smoke.py
-
-# perf observability smoke (ISSUE 17): a deterministic synthetic bench
-# through the dispatch flight recorder and the journey collator emits a
-# schema-valid unified artifact, the perfgate passes it against the
-# committed baselines, and then MUST fail (exit 1 asserted) against a
-# fixture baseline with an injected 2x regression — the stage that
-# proves a perf regression is a failed build, and that the gate itself
-# has not been lobotomized.  Jax-free, sub-second.
-python scripts/perf_smoke.py
 
 # native latency harness (ISSUE 12, was the ISSUE 9 prepared-pairing
 # smoke): parity on valid + corrupted beacons for all scheme shapes,

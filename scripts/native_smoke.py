@@ -166,23 +166,6 @@ def main() -> int:
         "targets_warm_p50_ms": WARM_TARGET_MS,
         "pass": not misses,
     }
-    # unified perf schema (tools/perf): one gateable record per scheme's
-    # warm p50; legacy fields above stay for old consumers
-    try:
-        from tools.perf import schema as perf_schema
-        ts = perf_schema.stamp()
-        report["records"] = [perf_schema.make_record(
-            bench="native",
-            metric=f"single-verify warm p50 ms ({scheme})",
-            value=entry["warm_ms"]["p50"], unit="ms", direction="lower",
-            timestamp=ts, config=report["config"], device="cpu",
-            writer="scripts/native_smoke.py",
-            extras={"scheme": scheme, "cold_ms": entry["cold_ms"],
-                    "build": report["build"]})
-            for scheme, entry in per_scheme.items()]
-    except Exception as exc:
-        print(f"native_smoke: unified record emit failed: {exc}",
-              file=sys.stderr)
     if args.json_out:
         with open(args.json_out, "w") as f:
             json.dump(report, f, indent=2)

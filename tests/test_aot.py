@@ -118,20 +118,6 @@ def test_code_hash_pins_kernel_sources(tmp_path):
     assert aot._hash_files([str(f)]) != before
 
 
-def test_entries_for_is_a_jaxfree_stem_scan(tmp_path, monkeypatch):
-    """The warm orchestrator's done-detection half: entries_for() must
-    find cache entries by logical name without computing the env tag
-    (no jax import, no backend init in the orchestrator process)."""
-    monkeypatch.setenv("DRAND_TPU_AOT_DIR", str(tmp_path))
-    assert aot.entries_for("t-entries") == []
-    x = jnp.ones((2, 2), jnp.float32)
-    aot.compile_and_save("t-entries", _fn, x, x)
-    found = aot.entries_for("t-entries")
-    assert len(found) == 1 and found[0].startswith("t-entries-")
-    assert aot.entries_for("t-entrie") == []          # stem, not prefix
-    assert aot.entries_for("absent") == []
-
-
 def _counter_value(counter, *labels) -> float:
     return counter.labels(*labels)._value.get()
 

@@ -84,8 +84,8 @@ def test_bench_partials_bookkeeping(monkeypatch, tmp_path, capsys):
     """bench_partials on a stub backend: the rebuilt config's
     bookkeeping — rounds-major dispatch, negative control, distinct-
     message/table accounting, and the BENCH_partials-shaped --json
-    artifact — pinned without device work (the real measurement runs
-    on the TPU via scripts/warm_r7.sh)."""
+    artifact — pinned without device work (on the chip the aggregation
+    path is not measured: ROADMAP M2)."""
     import json
 
     from drand_tpu.crypto import tbls

@@ -152,15 +152,12 @@ def test_metrics_naming_conventions():
     # regression check bench.py reports per dispatch
     assert "drand_layout_conversions" in names, \
         "layout-conversion metric not registered"
-    # the warm-pipeline orchestrator (drand_tpu/warm) + AOT cache
-    # economics (drand_tpu/aot): stage outcomes/durations and
-    # compile-vs-load seconds are the observability that replaced the
-    # append-only chain.log — losing one re-blinds the warm chains
-    for required in ("drand_warm_stage", "drand_warm_stage_duration_seconds",
-                     "drand_aot_compile_seconds", "drand_aot_load_seconds",
+    # AOT cache economics (drand_tpu/aot): compile-vs-load seconds and
+    # hit/miss events of the CPU tier's serialized executables
+    for required in ("drand_aot_compile_seconds", "drand_aot_load_seconds",
                      "drand_aot_cache"):
         assert required in names, \
-            f"warm/AOT metric {required} not registered"
+            f"AOT metric {required} not registered"
     # the native tier (ISSUE 12): per-scheme single-verify latency and
     # the availability gauge are how a silent fallback to the ~175 ms
     # golden model (toolchain gone, build broken) surfaces on a dashboard
@@ -183,8 +180,8 @@ def test_metrics_naming_conventions():
             f"storage recovery metric {required} not registered"
     # perf observability (ISSUE 17): the dispatch flight recorder and
     # the round-journey histogram are what /debug/dispatch,
-    # /debug/journey, and the perfgate trajectory read — a lost
-    # registration blinds the padding-waste and hop-latency dashboards
+    # /debug/journey read — a lost registration blinds the
+    # padding-waste and hop-latency dashboards
     # (counters collect without their _total suffix)
     for required in ("drand_dispatch_seconds", "drand_dispatch_fill_ratio",
                      "drand_dispatch_padding_rounds",
@@ -229,27 +226,118 @@ def test_check_script_present_and_executable():
     assert check.stat().st_mode & 0o111, "scripts/check.sh must be executable"
 
 
-def test_warm_spec_hygiene():
-    """The warm-spec contract (drand_tpu/warm/spec.py): every registered
-    pipeline validates, and every stage declares a positive timeout and
-    at least one expected artifact.  A stage without a timeout can
-    silently eat a night; a stage without artifacts cannot be
-    done-detected on resume — neither ships.  (The module is jax-free,
-    so this gate costs milliseconds.)"""
-    from drand_tpu.warm import specs
+def test_check_script_runs_only_what_exists():
+    """Every `scripts/`, `tools/` and `tests/` path that check.sh hands
+    to an interpreter, and every module it runs with `-m`, is in the
+    tree: a stage whose script was deleted fails here, not on the next
+    manual run of the check."""
+    import re
 
-    assert specs.SPECS, "warm spec registry is empty"
-    assert "warm_r8" in specs.SPECS, \
-        "the r8 measurement protocol spec must stay registered"
-    assert "smoke3" in specs.SPECS, \
-        "the check.sh warm-smoke spec must stay registered"
-    for name, spec in specs.SPECS.items():
-        spec.validate()
-        for stage in spec.stages:
-            assert stage.timeout_s > 0, \
-                f"{name}/{stage.name}: no declared timeout"
-            assert stage.artifacts, \
-                f"{name}/{stage.name}: no declared artifacts"
+    text = (REPO / "scripts" / "check.sh").read_text()
+    commands = "\n".join(ln for ln in text.splitlines()
+                         if not ln.lstrip().startswith("#"))
+    paths = set(re.findall(
+        r"(?<![\w./-])((?:scripts|tools|tests)/[\w./-]+)", commands))
+    modules = set(re.findall(r"-m ((?:tools|drand_tpu)[\w.]*)", commands))
+    assert len(paths) >= 10 and modules, (paths, modules)
+    missing = sorted(p for p in paths if not (REPO / p).exists())
+    for mod in sorted(modules):
+        base = REPO.joinpath(*mod.split("."))
+        if not (base.with_suffix(".py").exists()
+                or (base / "__main__.py").exists()):
+            missing.append(mod)
+    assert not missing, f"scripts/check.sh runs what is not there: {missing}"
+
+
+def test_readme_layout_names_only_what_exists():
+    """Every file or directory the README's "Layout" section lists, in
+    its name column or as a `scripts/...`-style path in a description,
+    is in the tree (globs may match anything): deleting a module takes
+    its entry with it."""
+    import glob
+    import re
+
+    readme = (REPO / "README.md").read_text()
+    block = readme.split("## Layout", 1)[1].split("```")[1]
+    named, parent = [], ""
+    for line in block.splitlines():
+        head, text = line[:21], line[21:]
+        for name in head.split():
+            if head.startswith("  "):
+                named.append(parent + name)
+            else:
+                named.append(name)
+                parent = name if name.endswith("/") else parent
+        named += re.findall(
+            r"(?<![\w./-])((?:scripts|tools|tests|benchmark|aot)/[\w.*/-]+\w)",
+            text)
+    assert len(named) >= 30, named
+    missing = [n for n in named if not glob.glob(str(REPO / n))]
+    assert not missing, f"README Layout names what is not there: {missing}"
+
+
+def test_every_benchmark_test_module_runs_with_this_suite():
+    """The yardstick's own tests guard it only if they run: every
+    `benchmark/tests/test_*.py` is star-imported by exactly one module
+    of `tests/`, and no two test modules of the two directories share a
+    basename (pytest imports them by basename here)."""
+    import re
+
+    theirs = sorted(p.stem for p in
+                    (REPO / "benchmark" / "tests").glob("test_*.py"))
+    assert len(theirs) >= 8
+    shimmed = []
+    for path in (REPO / "tests").glob("test_*.py"):
+        shimmed += re.findall(
+            r"^from benchmark\.tests\.(\w+) import \*", path.read_text(),
+            re.M)
+    assert sorted(shimmed) == theirs
+    ours = {p.stem for p in (REPO / "tests").glob("test_*.py")}
+    assert not ours & set(theirs)
+
+
+def test_cli_commands_are_the_parsers_and_the_jax_free_ones_stay_so():
+    """The CLI's sub-parsers are exactly what `main` can dispatch:
+    `_COMMANDS` plus `lint`, which it runs synchronously.  One process
+    for each chip: a command that starts children or reads files only
+    must not bring a backend up, so `lint` stays out of `_NEEDS_JAX`,
+    and the commands that verify stay in."""
+    import argparse
+    import importlib
+
+    cli = importlib.import_module("drand_tpu.cli.main")
+    subparsers = [a for a in cli.build_parser()._actions
+                  if isinstance(a, argparse._SubParsersAction)]
+    assert len(subparsers) == 1
+    assert set(subparsers[0].choices) == set(cli._COMMANDS) | {"lint"}
+    assert cli._NEEDS_JAX <= set(cli._COMMANDS)
+    assert {"start", "sync", "get"} <= cli._NEEDS_JAX
+    assert "lint" not in cli._NEEDS_JAX
+
+
+# ROADMAP D7's command as a test: a PR that adds or drops an option has
+# to say so here.  `DRAND_TPU_OBJECTSYNC_` is the prefix the publisher's
+# settings are read under.
+_OPTIONS = """
+AGG_MAX_BATCH AOT_DIR AOT_WARM ASYNC_SANITIZE ASYNC_SANITIZE_THRESHOLD
+BUCKETS COMPACT DEVICE_CRYPTO DKG_BATCH HOST_CRYPTO HOST_VERIFY_MAX
+LINE_MERGE MILLER_MERGED NATIVE_LIB NO_NATIVE OBJECTSYNC_ OBJECTSYNC_DIR
+OBJECTSYNC_SEGMENT SERVE_CACHE SERVE_CACHE_ROUNDS STARTUP_SCAN STORE_CODEC
+STORE_SYNC SYNC_PIPELINE_DEPTH SYNC_WIRE_CHUNK
+""".split()
+
+
+def test_the_options_are_the_ones_listed():
+    """`grep -rho --include='*.py' 'DRAND_TPU_[A-Z_0-9]*' drand_tpu |
+    sort -u`: 25 names."""
+    import re
+
+    found = set()
+    for path in (REPO / "drand_tpu").rglob("*.py"):
+        found.update(re.findall(r"DRAND_TPU_[A-Z_0-9]*", path.read_text()))
+    listed = {"DRAND_TPU_" + name for name in _OPTIONS}
+    assert found == listed, (
+        f"new: {sorted(found - listed)}, gone: {sorted(listed - found)}")
 
 
 def test_chaos_failpoint_hygiene():

@@ -71,6 +71,27 @@ def test_ledger_signer_loss_and_late_arrival():
     assert led.missing_signers() == []
 
 
+def test_ledger_counts_a_partial_verified_while_its_round_recovered():
+    """The Handler checks a partial's signature off the loop; the round
+    can recover from the other signers meanwhile, and `note_partial` then
+    comes after `note_recovery`.  The signer is alive: it is on the
+    round's books as late, and the sealed margin counts it."""
+    led = ParticipationLedger(group_size=3, threshold=2)
+    led.note_partial(0, 1)
+    led.note_partial(1, 1)
+    _recover(led, 1, (0, 1))
+    led.note_partial(2, 1)                     # after the recovery
+    assert led.is_counted(2, 1)
+    assert led.late_partials == 1 and 1 not in led._open
+    led.note_partial(0, 2)
+    led.note_partial(1, 2)
+    _recover(led, 2, (0, 1))
+    assert led._records[1].final_margin == 1
+    assert led.last_final_margin == 1 and led.miss_streak(2) == 0
+    led.note_partial(2, 1)                     # sealed: nothing moves
+    assert led._records[1].final_margin == 1 and led.newest[2] == 1
+
+
 def test_ledger_window_and_open_round_bounds():
     led = ParticipationLedger(group_size=2, threshold=2, window=4)
     for r in range(1, 11):

@@ -141,37 +141,7 @@ class ServeStats:
             # the run revalidated (304) and which serve lane answered
             # (the server's X-Drand-Cache header)
             "cache": self._cache_block(),
-            # unified perf schema (tools/perf): p99 latency and goodput
-            # as gateable records; legacy fields above stay for old
-            # consumers
-            "records": self._unified(clients, elapsed_s, tails, ok),
         }
-
-    def _unified(self, clients: int, elapsed_s: float, tails: dict,
-                 ok: int) -> list[dict]:
-        try:
-            from tools.perf import schema as perf_schema
-        except ImportError:        # run from an odd cwd: legacy-only
-            return []
-        ts = perf_schema.stamp()
-        config = {"clients": clients, "mix": "latest/round/watch/cached"}
-        try:
-            return [
-                perf_schema.make_record(
-                    bench="serve",
-                    metric="public-serve p99 latency under concurrent load",
-                    value=tails["p99"], unit="ms", direction="lower",
-                    timestamp=ts, config=config, device="cpu",
-                    writer="tools/bench_serve.py"),
-                perf_schema.make_record(
-                    bench="serve", metric="public-serve goodput",
-                    value=round(ok / elapsed_s, 1) if elapsed_s else 0.0,
-                    unit="req/sec", direction="higher", timestamp=ts,
-                    config=config, device="cpu",
-                    writer="tools/bench_serve.py"),
-            ]
-        except Exception:
-            return []
 
     def _cache_block(self) -> dict:
         served = dict(sorted(self.cache_events.items()))

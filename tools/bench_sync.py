@@ -381,27 +381,6 @@ async def _main_object(args, sigs, verifier) -> dict:
         "pass": ratio <= 2.0,
         "bit_identical_object_vs_chunked": True,
     }
-    try:
-        from tools.perf import schema as perf_schema
-        ts = perf_schema.stamp()
-        config = {"mode": args.mode, "backlog": covered,
-                  "epochs": args.epochs}
-        report["records"] = [perf_schema.make_record(
-            bench="sync",
-            metric=f"non-verify host s/16384 rounds ({name})",
-            value=p["non_verify_s_per_16384"], unit="s",
-            direction="lower", timestamp=ts, config=config,
-            device="stub-verify", writer="tools/bench_sync.py",
-            extras={"pass": name, "stats": p.get("stats", {})})
-            for name, p in report["passes"].items()
-        ] + [perf_schema.make_record(
-            bench="sync", metric="object non-verify cost vs chunked",
-            value=round(ratio, 2), unit="x", direction="lower",
-            timestamp=ts, config=config, device="stub-verify",
-            writer="tools/bench_sync.py")]
-    except Exception as exc:
-        print(f"bench_sync: unified record emit failed: {exc}",
-              file=sys.stderr)
     return report
 
 
@@ -484,29 +463,6 @@ async def _main(args) -> dict:
         "pass": speedup >= 5.0,
         "bit_identical_chunked_vs_fallback": True,
     }
-    # unified perf schema (tools/perf): one gateable record per pass
-    # plus the speedup headline; legacy fields stay for old consumers
-    try:
-        from tools.perf import schema as perf_schema
-        ts = perf_schema.stamp()
-        config = {"mode": args.mode, "backlog": backlog,
-                  "epochs": args.epochs}
-        report["records"] = [perf_schema.make_record(
-            bench="sync",
-            metric=f"non-verify host s/16384 rounds ({name})",
-            value=p["non_verify_s_per_16384"], unit="s",
-            direction="lower", timestamp=ts, config=config,
-            device=device, writer="tools/bench_sync.py",
-            extras={"pass": name, "stats": p.get("stats", {})})
-            for name, p in report["passes"].items()
-        ] + [perf_schema.make_record(
-            bench="sync", metric="chunked non-verify speedup vs legacy",
-            value=round(speedup, 1), unit="x", direction="higher",
-            timestamp=ts, config=config, device=device,
-            writer="tools/bench_sync.py")]
-    except Exception as exc:
-        print(f"bench_sync: unified record emit failed: {exc}",
-              file=sys.stderr)
     return report
 
 
