@@ -1,29 +1,39 @@
-"""Compile caches: where JAX's persistent cache lives, and the
-serialized-executable (`.aotx`) cache of the CPU/dryrun tier.
+"""Compile caches: where JAX's persistent cache lives, the verify
+programs' exported form beside it, and the serialized-executable
+(`.aotx`) cache of the CPU/dryrun tier.
 
 The one decision every entry point shares is the directory of JAX's
 persistent compilation cache: `persistent_cache_dir()` below, enabled on
-every backend by `enable_persistent_cache()`.  On the TPU that cache is
-the only one: the verify programs are built by `jit` from the sources
-(`drand_tpu/verify.py`) and nothing under `aot/` but `aot/fixtures/` is
-read or written.
+every backend by `enable_persistent_cache()`.  It stays the only store of
+executables on every backend.
+
+Beside it, in the same directory, lies one file a verify program: its
+exported form (`jax.export`: the lowered StableHLO module, on the TPU
+with the Mosaic kernels inside its `tpu_custom_call`s), which
+`Verifier.build` (`drand_tpu/verify.py`) reads before it traces anything
+and writes when it had to trace (`load_exported`, `save_exported`).  A
+started process then lowers the loaded module, and JAX's cache hands the
+executable back: no kernel body is traced again until a source changes.
 
 The rest of this module serializes whole executables
 (`jax.experimental.serialize_executable`) to `aot/*.aotx` and loads them
 back without tracing, lowering or compiling.  The CPU tier keeps it: the
 driver's dryrun entry point (`__graft_entry__.py`) and the sharded CPU
-mesh (`parallel/sharded.py`).
+mesh (`parallel/sharded.py`); on the TPU nothing under `aot/` but
+`aot/fixtures/` is read or written.
 
-Keying: entries are valid only for the exact program, so the cache key
+Keying, of both: an entry is valid only for the exact program, so the key
 hashes (a) a caller-supplied name + static config, (b) the source of every
 module that shapes the compiled graph (drand_tpu/ops/* + verify.py), and
-(c) the platform/device-kind/device-count + jax version.  Any kernel edit
-or environment change misses and the caller compiles.
+(c) the platform/device-kind/device-count + the versions of jax, jaxlib
+and the backend's own.  Any kernel edit or environment change misses and
+the caller traces or compiles.
 """
 
 from __future__ import annotations
 
 import hashlib
+import json
 import os
 import pickle
 import threading
@@ -137,12 +147,17 @@ def entry_code_hash() -> str:
 
 def _env_tag() -> str:
     import jax
+    import jaxlib
     dev = jax.devices()[0]
+    # the backend's own version (on the TPU: libtpu's build) compiles the
+    # kernels an exported program carries
+    backend = hashlib.sha256(
+        dev.client.platform_version.encode()).hexdigest()[:8]
     return (f"{dev.platform}-{dev.device_kind}-{len(jax.devices())}"
-            f"-jax{jax.__version__}")
+            f"-jax{jax.__version__}-jaxlib{jaxlib.__version__}-{backend}")
 
 
-def cache_path(name: str, extra: str = "") -> str:
+def _key(name: str, extra: str = "", compact: bool | None = None) -> str:
     # DRAND_TPU_COMPACT changes the traced program (one scan a ladder vs
     # static segmentation — drand_tpu.ops.field.compact_graphs), so it is
     # part of the key: a compact executable must never be served to a
@@ -155,14 +170,154 @@ def cache_path(name: str, extra: str = "") -> str:
     # merge) also change the traced program without changing source, so
     # executables for different paths must never collide in the cache.
     from drand_tpu.ops.field import compact_graphs, miller_path_tag
-    tag = hashlib.sha256(
-        f"{name}|{_env_tag()}|{code_hash()}|compact={int(compact_graphs())}"
-        f"|{miller_path_tag()}|{extra}".encode()).hexdigest()[:20]
-    return os.path.join(aot_dir(), f"{_safe_name(name)}-{tag}.aotx")
+    if compact is None:
+        compact = compact_graphs()
+    return (f"{name}|{_env_tag()}|{code_hash()}|compact={int(compact)}"
+            f"|{miller_path_tag()}|{extra}")
+
+
+def _keyed_file(directory: str, name: str, key: str, suffix: str) -> str:
+    tag = hashlib.sha256(key.encode()).hexdigest()[:20]
+    return os.path.join(directory, f"{_safe_name(name)}-{tag}{suffix}")
+
+
+def cache_path(name: str, extra: str = "") -> str:
+    return _keyed_file(aot_dir(), name, _key(name, extra), ".aotx")
 
 
 def _safe_name(name: str) -> str:
     return "".join(c if c.isalnum() or c in "-_." else "_" for c in name)
+
+
+# -- a program's exported form, beside JAX's cache ----------------------------
+
+_EXPORTED_FORMAT = "drand_tpu.exported.1"
+_EXPORTED_SUFFIX = ".jaxexport"
+_EXPORT_LOCK = threading.Lock()
+# a writer's temporary file lives for the write (0.35 s for 47 MB); one
+# this much older than the file just written was left by a killed writer
+_STALE_TMP_S = 3600.0
+
+
+def exported_path(name: str, compact: bool,
+                  body: str = "") -> tuple[str, str]:
+    """(file, key) of program `name`'s exported form: one file a program
+    in `persistent_cache_dir()`, named from the key's hash.  The file's
+    first line states the key in full, and a file that states another is
+    never used.  `body` says which function was traced (module and
+    qualified name): the key's source hash vouches for the sources' own
+    body only, so a body put in its place (a test's stand-in) is kept
+    under a key of its own and never read as the sources' program."""
+    key = _key(name, extra=body, compact=compact)
+    return _keyed_file(persistent_cache_dir(), name, key,
+                       _EXPORTED_SUFFIX), key
+
+
+def export_program(fn, *structs):
+    """`fn`'s exported form for `structs` on this backend: the trace and
+    the lowering, once.  The form is read back only under the versions
+    and on the device kind that wrote it (`_env_tag`), so the kernels are
+    lowered as `jit` lowers them here, not as an older runtime would need
+    them."""
+    import jax
+    flag = "jax_export_ignore_forward_compatibility"
+    # the flag is the process's: one export at a time, so that no thread's
+    # restore lands inside another's trace (a node builds its buckets
+    # lazily, from whichever thread first needs one)
+    with _EXPORT_LOCK:
+        before = getattr(jax.config, flag)
+        jax.config.update(flag, True)
+        try:
+            return jax.export.export(jax.jit(fn))(*structs)
+        finally:
+            jax.config.update(flag, before)
+
+
+def _prune_superseded(path: str) -> None:
+    """Remove `path`'s siblings: the same program's files under other
+    keys (older sources or versions: 28-48 MB each, and JAX's own
+    eviction counts only its `*-cache` entries) and temporary files a
+    killed writer left."""
+    directory, mine = os.path.split(path)
+    name = mine.rsplit("-", 1)[0]         # the rest is the key's hash
+    written = os.path.getmtime(path)
+    for fn in os.listdir(directory):
+        if fn == mine or fn.rsplit("-", 1)[0] != name:
+            continue
+        full = os.path.join(directory, fn)
+        try:
+            if fn.endswith(_EXPORTED_SUFFIX) or (
+                    fn.endswith(".tmp") and written
+                    - os.path.getmtime(full) > _STALE_TMP_S):
+                os.remove(full)
+        except OSError:
+            pass                      # another process was there first
+
+
+def save_exported(name: str, compact: bool, exported,
+                  body: str = "") -> int:
+    """Write `exported` to `name`'s file, whole or not at all (a temporary
+    name of this thread's own, then a rename), and remove the files this
+    one supersedes; returns the bytes of the exported form.  A directory
+    that cannot be written costs the next start its trace and this one a
+    line of log, no more."""
+    blob = exported.serialize()
+    path, key = exported_path(name, compact, body)
+    head = json.dumps({"format": _EXPORTED_FORMAT, "key": key,
+                       "bytes": len(blob),
+                       "sha256": hashlib.sha256(blob).hexdigest()})
+    tmp = f"{path}.{os.getpid()}.{threading.get_ident()}.tmp"
+    try:
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(tmp, "wb") as f:
+            f.write(head.encode() + b"\n")
+            f.write(blob)
+        os.replace(tmp, path)
+        _prune_superseded(path)
+    except OSError as e:
+        import sys
+        print(f"drand_tpu.aot: {os.path.basename(path)} could not be "
+              f"written ({type(e).__name__}: {e}); the next start traces "
+              "the program again", file=sys.stderr)
+    return len(blob)
+
+
+def _read_exported(path: str, key: str):
+    import jax
+    with open(path, "rb") as f:
+        head = json.loads(f.readline())
+        blob = f.read()
+    if head.get("format") != _EXPORTED_FORMAT:
+        raise ValueError(f"format {head.get('format')!r}")
+    if head.get("key") != key:
+        raise ValueError("written under another key")
+    if head.get("bytes") != len(blob) \
+            or head.get("sha256") != hashlib.sha256(blob).hexdigest():
+        raise ValueError(f"{len(blob)} bytes of {head.get('bytes')} "
+                         "or not the bytes written")
+    return jax.export.deserialize(blob), len(blob)
+
+
+def load_exported(name: str, compact: bool, body: str = ""):
+    """(exported form or None, what the read found) of program `name`.
+    No file is a plain miss, `{}`.  A file that was there says
+    `blob_bytes` when it was used and `load_error`, one short string,
+    when it could not be (unreadable, cut short, another key or
+    serialization version): that is logged, and the caller traces the
+    program and writes the file anew."""
+    path, key = exported_path(name, compact, body)
+    if not os.path.exists(path):
+        return None, {}
+    try:
+        exported, size = _read_exported(path, key)
+    except Exception as e:
+        import sys
+        error = " ".join(f"{type(e).__name__}: {e}".split())[:200]
+        print(f"drand_tpu.aot: {os.path.basename(path)} is there but "
+              f"cannot be used ({error}); tracing the program anew",
+              file=sys.stderr)
+        return None, {"load_error": error}
+    return exported, {"blob_bytes": size}
 
 
 def warming() -> bool:

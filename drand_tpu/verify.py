@@ -153,6 +153,14 @@ class Verifier:
 
         return run
 
+    def _body_tag(self) -> str:
+        """Which function `build` traces, for the key of the exported
+        form: the store's source hash covers `Verifier._run_fn` as the
+        sources have it and says nothing of a body put in its place."""
+        fn = getattr(self._run_fn, "__func__", self._run_fn)
+        return "{}.{}".format(getattr(fn, "__module__", "?"), getattr(
+            fn, "__qualname__", type(fn).__qualname__))
+
     def _kernel(self, n: int):
         if n not in self._kernels:
             from drand_tpu.ops.pallas_field import use_pallas
@@ -160,9 +168,10 @@ class Verifier:
             if not use_pallas():
                 # CPU/dryrun tier: a serialized executable from aot/ when
                 # one matches this exact program (drand_tpu/aot.py).  On
-                # the TPU nothing under aot/ is read or written: the
-                # program is built by `jit` from the sources and cached by
-                # JAX's persistent cache only (aot.enable_persistent_cache).
+                # the TPU nothing under aot/ is read or written: `build`
+                # takes the program's exported form from its file beside
+                # JAX's persistent cache, or traces it from the sources,
+                # and that cache holds the executable on every tier.
                 from drand_tpu import aot
                 name = self._aot_name(n)
                 fn = aot.load(name)
@@ -181,32 +190,57 @@ class Verifier:
                 self._pk_struct())
 
     def build(self, n: int) -> dict:
-        """Trace, lower and compile bucket `n`'s program and install it;
-        returns what the build cost, for whoever warms a bucket ahead of
-        traffic (chip_smoke.py prints it): the tracing mode, seconds to
-        trace, to lower and to compile (host clock; a persistent-cache
-        hit shows as a short compile), and the `lowered` stage, in whose
-        text the Pallas kernels can be counted."""
+        """Bucket `n`'s program, installed; returns what the build cost,
+        for whoever warms a bucket ahead of traffic (chip_smoke.py prints
+        it).
+
+        The program's exported form (`jax.export`) comes from its file
+        beside JAX's cache where one was written under this very key
+        (`aot.load_exported`: sources, versions, device, tracing mode),
+        `source` `loaded`; else the kernel bodies are traced and lowered
+        here, once, and the file written, `source` `traced`.  Either way
+        the executable is compiled from that form, so a checkout's first
+        run stores in JAX's persistent cache what its later runs ask for.
+
+        The record: the tracing mode; `trace_s` to the exported form in
+        hand (of which `load_s` reading the file, with `blob_bytes`, or
+        `load_error` where a file was there and could not be used),
+        `lower_s` and `compile_s` (host clock; a persistent-cache hit
+        shows as a short compile); and the `lowered` stage, in whose text
+        the Pallas kernels can be counted."""
+        from drand_tpu import aot
         from drand_tpu.ops.field import compact_graphs
         from drand_tpu.ops.pallas_field import use_pallas
         name = self._aot_name(n)
+        compact = use_pallas() or compact_graphs()
+        structs, body = self._arg_structs(n), self._body_tag()
         # a span of its own and one a phase, from the same clock reads as
         # the record: a bucket built lazily under an open `sync.segment`
         # or `scan.flush` shows there as what stalled it
         with tracing.span("verifier.build", bucket=n, program=name) as sp:
             t0 = sp.start_mono
-            traced = jax.jit(self._run_fn()).trace(*self._arg_structs(n))
+            exported, found = aot.load_exported(name, compact, body)
+            tl = time.perf_counter()
+            tracing.record_span("build.load", t0, tl)
+            source = "loaded"
+            if exported is None:
+                source = "traced"
+                exported = aot.export_program(self._run_fn(), *structs)
+                found["blob_bytes"] = aot.save_exported(
+                    name, compact, exported, body)
             t1 = time.perf_counter()
-            tracing.record_span("build.trace", t0, t1)
-            lowered = traced.lower()
+            if source == "traced":
+                tracing.record_span("build.trace", tl, t1)
+            lowered = jax.jit(exported.call).trace(*structs).lower()
             t2 = time.perf_counter()
             tracing.record_span("build.lower", t1, t2)
             self._kernels[n] = lowered.compile()
             t3 = time.perf_counter()
             tracing.record_span("build.compile", t2, t3)
+            sp.set(source=source, load_s=tl - t0, **found)
         return {"program": name, "bucket": n,
-                "tracing": "compact" if use_pallas() or compact_graphs()
-                else "static",
+                "tracing": "compact" if compact else "static",
+                "source": source, "load_s": tl - t0, **found,
                 "trace_s": t1 - t0, "lower_s": t2 - t1,
                 "compile_s": t3 - t2, "lowered": lowered}
 

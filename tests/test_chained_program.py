@@ -172,6 +172,32 @@ def test_the_digest_has_a_stage_of_its_own_in_the_chained_program(chained):
         assert f'"jit(run)/{stage}/' in text, stage
 
 
+def test_a_second_verifier_loads_the_program_the_first_one_left(chained):
+    """(ISSUE 32) The fixture's build wrote the program's exported form
+    beside JAX's cache, or found it there.  A verifier with nothing
+    built, as a started process has it, takes the program from that
+    file, lowers the same module, and judges a flipped segment as the
+    reference does."""
+    _config, sigs, _seed, cv, text = chained
+    v = V.Verifier(cv._pk_point, cv.scheme.shape)
+    with compact_scope(True):
+        rec = v.build(V._bucket(SEGMENT))
+    assert rec["source"] == "loaded" and "load_error" not in rec
+    assert rec["blob_bytes"] > 1_000_000
+    assert rec["load_s"] <= rec["trace_s"]
+    # the same module but for the call stacks in its locations (a whole
+    # comparison of two 17 MB texts is not for an assertion to print)
+    loaded = rec["lowered"].as_text(debug_info=True)
+    for part in ("func.func", "stablehlo.while", "stablehlo.case",
+                 '"jit(run)/digest/', '"jit(run)/miller/'):
+        assert loaded.count(part) == text.count(part) > 0, part
+    seg = _flipped(sigs, SEGMENT + 5)[SEGMENT:2 * SEGMENT]
+    got = v.verify_chain_segment(SEGMENT + 1, seg, sigs[SEGMENT - 1])
+    want = np.ones(SEGMENT, dtype=bool)
+    want[5:7] = False
+    assert (got == want).all(), np.nonzero(got != want)[0]
+
+
 # -- SyncManager over the program, into the store stack ------------------------
 
 class _PackedNet:

@@ -130,7 +130,7 @@ def test_a_consumer_that_commits_past_a_corrupted_round_fails_the_smoke(
 
 
 def test_four_chips_control_flow_on_four_virtual_devices(
-        smoke_on_cpu, monkeypatch, capsys):
+        smoke_on_cpu, monkeypatch, capsys, tmp_path):
     """`--four-chips` on four of the suite's virtual CPU devices, with the
     verify body replaced by a traceable stand-in that rejects exactly the
     round the smoke corrupts (round batch/2 + 1): the sharded path is
@@ -149,9 +149,13 @@ def test_four_chips_control_flow_on_four_virtual_devices(
 
     monkeypatch.setattr(V.Verifier, "_run_fn", fake_run_fn)
     monkeypatch.setattr(jax, "devices", lambda: _real_devices()[:4])
-    # the CPU tier would serialize the stand-in programs into aot/
+    # the CPU tier would serialize the stand-in programs into aot/, and
+    # `Verifier.build` write their exported form beside JAX's cache
+    # (under a key that names the stand-in, so never read as the
+    # sources' program: test_exported_program.py): not in the checkout
     monkeypatch.setattr("drand_tpu.aot.load", lambda name, extra="": None)
     monkeypatch.setattr("drand_tpu.aot.save", lambda *a, **k: "")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     monkeypatch.setattr(V, "_BUCKETS", (8, 64, 512, 4096, 16384))
     device = smoke_on_cpu.four_chips(batch)
     assert device["count"] == 4
@@ -160,3 +164,30 @@ def test_four_chips_control_flow_on_four_virtual_devices(
     assert len(out["devices_holding_a_shard"]) == 4
     assert out["rows_per_shard"] == [batch // 4]
     assert out["sharded_true"] == out["one_device_true"] == batch - 1
+    written = [fn for fn in os.listdir(tmp_path) if fn.endswith(".jaxexport")]
+    assert written and all(_traced_body(tmp_path / fn).endswith(
+        "<locals>.fake_run_fn") for fn in written)
+
+
+def _traced_body(path) -> str:
+    """Which function an exported program's file says was traced: the
+    last part of the key on its first line."""
+    with open(path, "rb") as f:
+        return json.loads(f.readline())["key"].split("|")[-1]
+
+
+def test_the_checkouts_cache_holds_only_the_sources_own_programs():
+    """After this file's stand-ins (loadfile keeps a file's tests on one
+    worker, in order): whatever exported programs lie in the cache that
+    this checkout's processes read, each was traced from
+    `Verifier._run_fn` as the hashed sources have it.  PR 32's first
+    draft left a stand-in that passes every round but one under the real
+    program's key there."""
+    from drand_tpu import aot
+    directory = aot.persistent_cache_dir()
+    found = [fn for fn in (os.listdir(directory)
+                           if os.path.isdir(directory) else [])
+             if fn.endswith(".jaxexport")]
+    for fn in found:
+        assert _traced_body(os.path.join(directory, fn)) \
+            == "drand_tpu.verify.Verifier._run_fn", fn
