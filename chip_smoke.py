@@ -115,7 +115,7 @@ class _CacheEvents:
         return out, self.hits - h, self.misses - m
 
 
-def _build(what: str, verifier, bucket: int, events: _CacheEvents) -> None:
+def _build(what: str, verifier, bucket: int, events: _CacheEvents) -> dict:
     """Build `bucket`'s program on `verifier` and emit its record: what
     `Verifier.build` measured, plus whether JAX's persistent cache served
     the compile."""
@@ -126,6 +126,7 @@ def _build(what: str, verifier, bucket: int, events: _CacheEvents) -> None:
             "tpu_custom_call"))
     emit(program=rec)
     check_program(rec)
+    return rec
 
 
 def _committed_sigs(db_path: str, sig_len: int):
@@ -297,13 +298,38 @@ async def smoke(backlog: int = BACKLOG) -> dict:
     return device
 
 
-def four_chips(batch: int = 16384) -> dict:
-    """The sharded verify alone: one batch through `ShardedVerifier` over
-    every device, and the same batch through the one-device `Verifier`
-    on device 0; equal verdicts, one corrupted signature among them, and
-    a shard of the input on every device."""
+def _quicknet_fixture(rows: int):
+    """(sigs[rows, 48], ChainVerifier): rounds 1..rows of the committed
+    `bls-unchained-g1-rfc9380` bench chain (quicknet's scheme: signatures
+    on G1, key on G2) under the fixture key."""
+    import bench
+    from drand_tpu.chain.scheme import scheme_by_id
+    from drand_tpu.chain.verify import ChainVerifier
+    from drand_tpu.crypto.bls12381 import curve as GC
+    _sk, pk, _shape, sigs = bench._chain_fixture("unchained_g1", rows)
+    return sigs, ChainVerifier(scheme_by_id("bls-unchained-g1-rfc9380"),
+                               GC.g2_to_bytes(pk))
+
+
+def four_chips(per_device: int = 16384) -> dict:
+    """The sharded verify alone, at the deep catch-up's size (the program
+    of the benchmark's `catchup-deep.quicknet-g1-x4`): `ShardedVerifier`
+    builds the program of `per_device` rows for every device of the mesh
+    (`build`: the only build here that may have to trace), one batch of
+    four times that goes through it, and the same rows through the
+    one-device `Verifier` on device 0, whose build has to LOAD the form
+    the mesh's build used.  Equal verdicts, one corrupted signature in
+    every device's slice, and a shard of the input on every device.
+
+    On a four-chip v5e (PR 34; observations, not metrics): the whole
+    smoke 199 s with no traced build (the mesh's build loaded the file a
+    one-chip process had written; its executable compiled in 98 s, the
+    one-device one in 70 s), 65,536 rows in 0.85 s over the mesh and in
+    3.44 s as four dispatches on one chip, 65,532 true on both."""
+    import jax
+
+    import drand_tpu.verify as V
     from drand_tpu.parallel import ShardedVerifier
-    from tools import bench_sync as H
 
     devs, cache_dir = _start()
     if len(devs) != 4:
@@ -313,43 +339,59 @@ def four_chips(batch: int = 16384) -> dict:
               "count": len(devs)}
     emit(compile_cache={"dir": cache_dir,
                         "entries_before": _cache_entries(cache_dir)})
+    events = _CacheEvents()
 
-    sigs, chain_verifier = H.real_fixture(batch)
+    chain, chain_verifier = _quicknet_fixture(per_device)
+    V._BUCKETS = (per_device,)        # one program, as `smoke` has it
     sharded = chain_verifier._verifier
     if not isinstance(sharded, ShardedVerifier) or sharded.n_dev != 4:
         raise SmokeFailure("ChainVerifier did not take the sharded path "
                            "on a four-device host")
-    sigs = sigs.copy()
-    sigs[batch // 2, 5] ^= 0xFF
-    rounds = np.arange(1, batch + 1, dtype=np.uint64)
+    # every device is handed rounds 1..per_device, each with another
+    # signature corrupted: at its slice's first row, inside it, at its
+    # last row
+    batch = 4 * per_device
+    rounds = np.tile(np.arange(1, per_device + 1, dtype=np.uint64), 4)
+    sigs = np.tile(chain, (4, 1))
+    bad = [0, per_device + per_device // 3, 2 * per_device + per_device // 2,
+           batch - 1]
+    for row in bad:
+        sigs[row, 5] ^= 0xFF
 
+    _build("the mesh's build", sharded, per_device, events)
     t0 = time.perf_counter()
     ok4 = sharded.verify_batch(rounds, sigs)
     t1 = time.perf_counter()
-    placed = sharded._shard(sigs)
+    sharded.verify_batch(rounds, sigs)
+    t2 = time.perf_counter()
+    placed = jax.device_put(sigs, sharded._named(sharded.axis, None))
     held = sorted(sh.device.id for sh in placed.addressable_shards)
     rows = {int(sh.data.shape[0]) for sh in placed.addressable_shards}
-    # one device, a shard's worth at a time: its program then has the
-    # kernel shapes of the sharded one's per-device body, whose traced
-    # bodies it re-uses (PallasField._launch), so the second build is the
-    # cheaper one.  PR 22's four-chip call built the two programs at
-    # unrelated shapes and was cut at 1,200 s before the second was
-    # lowered.
-    per = batch // 4
-    ok1 = np.concatenate([sharded.verifier.verify_batch(
-        rounds[i:i + per], sigs[i:i + per]) for i in range(0, batch, per)])
-    t2 = time.perf_counter()
+
+    # one device, a shard's worth at a time: the same program, from the
+    # same exported form, so this build traces nothing either
+    one = sharded.verifier
+    rec = _build("one device, the same form", one, per_device, events)
+    if rec["source"] != "loaded":
+        raise SmokeFailure("the one-device build traced the program again "
+                           f"(source {rec['source']!r})")
+    t3 = time.perf_counter()
+    ok1 = np.concatenate([one.verify_batch(
+        rounds[i:i + per_device], sigs[i:i + per_device])
+        for i in range(0, batch, per_device)])
+    t4 = time.perf_counter()
     emit(four_chips={"batch": batch, "devices_holding_a_shard": held,
                      "rows_per_shard": sorted(rows),
                      "sharded_true": int(ok4.sum()),
                      "one_device_true": int(ok1.sum()),
-                     "sharded_build_and_run_s": t1 - t0,
-                     "one_device_build_and_run_s": t2 - t1})
-    if held != sorted(d.id for d in devs) or rows != {batch // 4}:
+                     "sharded_first_run_s": t1 - t0,
+                     "sharded_run_s": t2 - t1,
+                     "one_device_four_runs_s": t4 - t3})
+    if held != sorted(d.id for d in devs) or rows != {per_device}:
         raise SmokeFailure(f"shards on devices {held} with {rows} rows, "
-                           f"want {batch // 4} rows on each of 4")
+                           f"want {per_device} rows on each of 4")
     want = np.ones(batch, dtype=bool)
-    want[batch // 2] = False
+    want[bad] = False
     if not (ok4 == ok1).all() or not (ok1 == want).all():
         raise SmokeFailure("sharded and one-device verdicts differ, or "
                            "differ from the fixture")

@@ -18,15 +18,17 @@ executable back: no kernel body is traced again until a source changes.
 The rest of this module serializes whole executables
 (`jax.experimental.serialize_executable`) to `aot/*.aotx` and loads them
 back without tracing, lowering or compiling.  The CPU tier keeps it: the
-driver's dryrun entry point (`__graft_entry__.py`) and the sharded CPU
-mesh (`parallel/sharded.py`); on the TPU nothing under `aot/` but
+driver's dryrun entry point (`__graft_entry__.py`) and the sharded
+partial-verify variants (`parallel/sharded.py`); on the TPU nothing under
+`aot/` but
 `aot/fixtures/` is read or written.
 
 Keying, of both: an entry is valid only for the exact program, so the key
 hashes (a) a caller-supplied name + static config, (b) the source of every
 module that shapes the compiled graph (drand_tpu/ops/* + verify.py), and
-(c) the platform/device-kind/device-count + the versions of jax, jaxlib
-and the backend's own.  Any kernel edit or environment change misses and
+(c) the platform/device-kind/device-count (of an exported form: one,
+whatever the process holds) + the versions of jax, jaxlib and the
+backend's own.  Any kernel edit or environment change misses and
 the caller traces or compiles.
 """
 
@@ -145,7 +147,10 @@ def entry_code_hash() -> str:
     return _hash_files([path])[:8]
 
 
-def _env_tag() -> str:
+def _env_tag(devices: int | None = None) -> str:
+    """Platform, device kind and count, and the versions.  `devices` is
+    the count the entry was made for where that is not the process's own
+    (a program's exported form is ONE device's, on any host)."""
     import jax
     import jaxlib
     dev = jax.devices()[0]
@@ -153,11 +158,12 @@ def _env_tag() -> str:
     # kernels an exported program carries
     backend = hashlib.sha256(
         dev.client.platform_version.encode()).hexdigest()[:8]
-    return (f"{dev.platform}-{dev.device_kind}-{len(jax.devices())}"
+    return (f"{dev.platform}-{dev.device_kind}-{devices or len(jax.devices())}"
             f"-jax{jax.__version__}-jaxlib{jaxlib.__version__}-{backend}")
 
 
-def _key(name: str, extra: str = "", compact: bool | None = None) -> str:
+def _key(name: str, extra: str = "", compact: bool | None = None,
+         devices: int | None = None) -> str:
     # DRAND_TPU_COMPACT changes the traced program (one scan a ladder vs
     # static segmentation — drand_tpu.ops.field.compact_graphs), so it is
     # part of the key: a compact executable must never be served to a
@@ -172,7 +178,7 @@ def _key(name: str, extra: str = "", compact: bool | None = None) -> str:
     from drand_tpu.ops.field import compact_graphs, miller_path_tag
     if compact is None:
         compact = compact_graphs()
-    return (f"{name}|{_env_tag()}|{code_hash()}|compact={int(compact)}"
+    return (f"{name}|{_env_tag(devices)}|{code_hash()}|compact={int(compact)}"
             f"|{miller_path_tag()}|{extra}")
 
 
@@ -207,8 +213,13 @@ def exported_path(name: str, compact: bool,
     never used.  `body` says which function was traced (module and
     qualified name): the key's source hash vouches for the sources' own
     body only, so a body put in its place (a test's stand-in) is kept
-    under a key of its own and never read as the sources' program."""
-    key = _key(name, extra=body, compact=compact)
+    under a key of its own and never read as the sources' program.
+
+    The form is one device's program (`Exported.nr_devices` 1) and the
+    key says so whatever the process holds: a host of four chips, which
+    runs it on each under `shard_map` (`parallel/sharded.py`), reads and
+    writes the very file a one-chip host does."""
+    key = _key(name, extra=body, compact=compact, devices=1)
     return _keyed_file(persistent_cache_dir(), name, key,
                        _EXPORTED_SUFFIX), key
 

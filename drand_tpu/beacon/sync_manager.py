@@ -15,6 +15,8 @@ Also implements the local-chain validation/repair pair:
 from __future__ import annotations
 
 import asyncio
+import contextlib
+import gc
 import os
 import random
 import time
@@ -49,6 +51,28 @@ HEDGE_PROBE_BOUND_S = 5.0  # real-time bound on the whole probe race
 # backlog, small enough that a failed segment wastes at most a couple
 # of already-dispatched successors
 PIPELINE_DEPTH = int(os.environ.get("DRAND_TPU_SYNC_PIPELINE_DEPTH", "2"))
+
+
+@contextlib.contextmanager
+def _collector_paused():
+    """No cyclic collection while a segment's rows are alive.
+
+    A commit materializes a Beacon a round and the store's decorators a
+    second one (65,536 rounds on four chips: 131,072 objects that hold no
+    reference cycle and die with the commit).  Left alone they outlive
+    two young collections, and in a process whose heap is small (a loaded
+    program: 142,000 objects) that sets off two full collections a
+    commit: about 50 ms each in which no Python thread runs, the event
+    loop included.  Where two chains' commits overlap, the one that
+    found the collector on turns it on again: the other's rest runs
+    with it, no worse than before."""
+    was_on = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was_on:
+            gc.enable()
 
 
 def _observe_stage(stage: str, seconds: float) -> None:
@@ -289,15 +313,18 @@ class _CatchupPipeline:
     def _commit(self, seg, anchor_sig: bytes) -> int:
         """Worker thread, under the segment's span: the store's own
         `store.commit` span becomes the segment's child."""
-        if isinstance(seg, list):
-            beacons = seg
-        else:
-            t0 = time.perf_counter()
-            beacons = seg.beacons(anchor_sig=anchor_sig)
-            tracing.record_span("store.materialize", t0,
-                                time.perf_counter(), rounds=len(beacons))
-        self.m.store.put_many(beacons)
-        return len(beacons)
+        with _collector_paused():
+            if isinstance(seg, list):
+                beacons = seg
+            else:
+                t0 = time.perf_counter()
+                beacons = seg.beacons(anchor_sig=anchor_sig)
+                tracing.record_span("store.materialize", t0,
+                                    time.perf_counter(), rounds=len(beacons))
+            self.m.store.put_many(beacons)
+            n = len(beacons)
+            del beacons          # gone before the collector is back
+        return n
 
     async def _settle_loop(self) -> None:
         while True:
@@ -531,13 +558,18 @@ class SyncManager:
         # throughput program; an idle stream (= we are at the head)
         # resets it.  The device is charged by the program, not by the
         # row: a verifier that pads 512 rows into its one 16,384-row
-        # program takes as long over them as over 16,384.  So a catch-up
-        # that knows its backlog (`up_to`) asks the verifier what a
-        # dispatch of the target's size is charged for and cuts THERE,
-        # where that program is full, or where the backlog ends
-        # (`segment_cut`).  Follow mode (`up_to == 0`), a verifier that
-        # does not answer, and one that charges the target for itself
-        # (the default buckets, the host tier) cut at the target.
+        # program takes as long over them as over 16,384, and one that
+        # lays them over four chips as long as over 65,536.  So a
+        # catch-up that knows its backlog (`up_to`) asks the verifier
+        # what a dispatch of the target's size is charged for and cuts
+        # THERE, where that program is full on every device it runs on,
+        # or where the backlog ends (`segment_cut`).  The target never
+        # passes SYNC_CHUNK_MAX and the charge grows with the rows, so a
+        # segment is at most what the verifier charges for SYNC_CHUNK_MAX
+        # rows: its program, times its mesh.  Follow mode (`up_to == 0`)
+        # and a verifier that does not answer cut at the target, as does
+        # in effect one that charges the target for itself (the default
+        # buckets on one device, the host tier).
         chunk_target = SYNC_CHUNK
         rows_charged = getattr(self.verifier, "rows_charged", None)
         self._current_peer = getattr(peer, "address", "") or str(peer)
@@ -557,10 +589,7 @@ class SyncManager:
                     # end cuts first, whatever the verifier would say
                     or req.up_to - anchor_round <= chunk_target):
                 return chunk_target, "target"
-            charged = rows_charged(chunk_target)
-            if charged > SYNC_CHUNK_MAX:
-                return SYNC_CHUNK_MAX, "target"
-            return charged, "full"
+            return rows_charged(chunk_target), "full"
 
         async def flush(cut: str) -> None:
             """Hand the buffered run to the pipeline as a segment ended

@@ -47,7 +47,10 @@ from drand_tpu.chain.segment import PackedBeacons, pack_rows
 log = dlog.get("chain.recovery")
 
 # one batched-verify dispatch per this many stored rounds — the
-# throughput bucket the catch-up kernels are warmed for (BENCH_sync)
+# throughput bucket the catch-up kernels are warmed for (BENCH_sync) —
+# or per what the verifier charges for that many where it says
+# (`rows_charged`: its program times its mesh, 65,536 on four chips), as
+# the catch-up cuts its segments (`SyncManager._fetch_stage`)
 SCAN_SEGMENT_ROUNDS = 16384
 # raw rows fetched per worker-thread sqlite crossing
 SCAN_READ_BATCH = 4096
@@ -106,8 +109,17 @@ class IntegrityReport:
         }
 
 
+def _flush_rounds(verifier) -> int:
+    """Good rows a verified segment where the caller names no number:
+    the program that SCAN_SEGMENT_ROUNDS rows are padded into, full."""
+    rows_charged = getattr(verifier, "rows_charged", None)
+    if rows_charged is None:
+        return SCAN_SEGMENT_ROUNDS
+    return rows_charged(SCAN_SEGMENT_ROUNDS)
+
+
 async def scan_store(store, verifier=None, *, beacon_id: str = "",
-                     segment_rounds: int = SCAN_SEGMENT_ROUNDS,
+                     segment_rounds: int | None = None,
                      read_batch: int = SCAN_READ_BATCH,
                      on_progress=None) -> IntegrityReport:
     """One streaming pass over the stored chain -> IntegrityReport.
@@ -117,8 +129,10 @@ async def scan_store(store, verifier=None, *, beacon_id: str = "",
     the structural checks run (decode, contiguity, linkage) — the
     jax-free fsck mode; with a ChainVerifier the good rows additionally
     stream through the batched device verifier in `segment_rounds`
-    segments.  All sqlite reads and every potentially-blocking verifier
-    dispatch happen in worker threads; the event loop stays live.
+    segments (left out: what the verifier charges for
+    SCAN_SEGMENT_ROUNDS, its program full on every device).  All sqlite
+    reads and every potentially-blocking verifier dispatch happen in
+    worker threads; the event loop stays live.
 
     One trace a scan: the root `store.scan`; under it a `scan.read` for
     every `raw_rows` batch and a `scan.decode` for that batch's per-row
@@ -136,8 +150,9 @@ async def scan_store(store, verifier=None, *, beacon_id: str = "",
     return report
 
 
-async def _scan_store(store, verifier, beacon_id: str, segment_rounds: int,
-                      read_batch: int, on_progress) -> IntegrityReport:
+async def _scan_store(store, verifier, beacon_id: str,
+                      segment_rounds: int | None, read_batch: int,
+                      on_progress) -> IntegrityReport:
     from drand_tpu import tracing
     t0 = time.perf_counter()
     report = IntegrityReport(beacon_id=beacon_id,
@@ -163,7 +178,8 @@ async def _scan_store(store, verifier, beacon_id: str, segment_rounds: int,
             return
         with tracing.span("scan.flush", rows=len(pending)):
             t0 = time.perf_counter()
-            items = list(pack_rows(pending, max_chunk=segment_rounds))
+            items = list(pack_rows(
+                pending, max_chunk=segment_rounds or len(pending)))
             tracing.record_span("scan.pack", t0, time.perf_counter(),
                                 items=len(items))
             singles: list = []
@@ -222,8 +238,16 @@ async def _scan_store(store, verifier, beacon_id: str, segment_rounds: int,
                 prev_good = (r, sig)
                 if r != GENESIS_ROUND:   # genesis is an anchor, not a sig
                     pending.append((r, sig, prev))
-                if len(pending) >= segment_rounds:
-                    await flush_bls()
+                if len(pending) >= (segment_rounds or SCAN_SEGMENT_ROUNDS):
+                    if segment_rounds is None and verifier is not None:
+                        # asked only now, with a device segment's worth
+                        # in hand: the answer may bring the verifier's
+                        # device tier up, which a short store, verified
+                        # on the host, never needs
+                        segment_rounds = _flush_rounds(verifier)
+                    if len(pending) >= (segment_rounds
+                                        or SCAN_SEGMENT_ROUNDS):
+                        await flush_bls()
             decode.set(corrupt=len(report.corrupt) - flagged[0],
                        unlinked=len(report.unlinked) - flagged[1])
         if on_progress is not None:
@@ -277,7 +301,7 @@ def repair_store(store, report: IntegrityReport,
 
 
 async def startup_recovery(store, verifier, *, beacon_id: str = "",
-                           segment_rounds: int = SCAN_SEGMENT_ROUNDS,
+                           segment_rounds: int | None = None,
                            ) -> tuple[IntegrityReport, dict | None]:
     """Boot-time scan + (if damaged) repair, with the scan's and the
     repair's spans and the `drand_store_integrity` gauge.  Returns

@@ -34,139 +34,134 @@ class ShardedVerifier:
         self.n_dev = len(devs)
         self.axis = axis
         self.mesh = Mesh(np.array(devs), (axis,))
+        self._kernels = {}         # rows over the mesh -> compiled program
+        self._pk_placed = None     # the key, on every device
 
-    def _shard(self, arr):
-        import jax
+    def _named(self, *spec):
         from jax.sharding import NamedSharding, PartitionSpec as P
+        return NamedSharding(self.mesh, P(*spec))
 
-        return jax.device_put(arr, NamedSharding(self.mesh, P(self.axis)))
+    def over(self, call, structs):
+        """(`call` as one jitted program over the mesh, the structs it
+        takes): `call` is one device's whole verify program at `structs`
+        (`Verifier.build` hands its exported form's), and every device
+        runs it on its slice of the round axis.
 
-    def _run_fn(self):
-        """The verifier's pure (msgs, sigs, pk) -> bool[B] body
-        (`Verifier._run_fn`; stubs provide the same hook)."""
-        return self.verifier._run_fn()
+        `shard_map`, not sharding propagation: rounds are independent,
+        and the TPU's compiler refuses to partition a Pallas kernel by
+        itself ("Mosaic kernels cannot be automatically partitioned").
+        The shardings are explicit on both sides, so an input that came
+        de-sharded would be refused, not silently gathered."""
+        import jax
+        from jax.sharding import PartitionSpec as P
 
-    def _sharded_kernel(self, m: int):
-        """The verify body compiled with explicit mesh in/out shardings.
+        msgs, sigs, pk = structs
+        on_each = jax.tree_util.tree_map
+        body = jax.shard_map(
+            call, mesh=self.mesh,
+            in_specs=(P(self.axis, None), P(self.axis, None),
+                      on_each(lambda _: P(), pk)),
+            out_specs=P(self.axis), check_vma=False)
+        rows = self._named(self.axis, None)
+        program = jax.jit(
+            body, out_shardings=self._named(self.axis),
+            in_shardings=(rows, rows, on_each(lambda _: self._named(), pk)))
 
-        Verifier._kernel's executables are lowered from sharding-less
-        single-device ShapeDtypeStructs: a `Compiled` does not
-        re-specialize, so calling one with NamedSharding multi-device
-        inputs either fails or silently de-shards the throughput path.
-        The multi-device path therefore compiles its own kernels, keyed
-        by batch size (mesh/axis are fixed per ShardedVerifier).  On the
-        CPU tier they persist through the same serialized-executable
-        cache as the single-device path (the mesh shape is part of the
-        cache name; aot's env tag already pins platform + device count);
-        on the TPU JAX's persistent cache is the only one."""
-        cache = getattr(self, "_skernels", None)
-        if cache is None:
-            cache = self._skernels = {}
-        if m not in cache:
-            import jax
-            from jax.sharding import NamedSharding, PartitionSpec as P
+        def wide(s):
+            return jax.ShapeDtypeStruct(
+                (s.shape[0] * self.n_dev, *s.shape[1:]), s.dtype)
+        return program, (wide(msgs), wide(sigs), pk)
 
-            from drand_tpu import aot
-            from drand_tpu.ops.pallas_field import use_pallas
+    def build(self, n: int) -> dict:
+        """The program of `n` rows a DEVICE, installed for `n` times the
+        mesh; `Verifier.build`'s algorithm, record (with `devices`) and
+        spans.  The exported form is the one-device program's own: a
+        process on a host of four loads the file a one-chip process
+        wrote, or writes the one it will load, and traces nothing
+        twice."""
+        if self.n_dev == 1:
+            return self.verifier.build(n)
+        return self.verifier.build(n, mesh=self)
 
-            cpu_tier = not use_pallas()
-            name = (f"sharded-{self.axis}{self.n_dev}-"
-                    f"{self.verifier._aot_name(m)}")
-            fn = aot.load(name) if cpu_tier else None
-            if fn is None:
-                shard_in = NamedSharding(self.mesh, P(self.axis, None))
-                out_sh = NamedSharding(self.mesh, P(self.axis))
-                repl = NamedSharding(self.mesh, P())
-                pk_sh = jax.tree_util.tree_map(lambda _: repl,
-                                               self.verifier._pk)
-                # shard_map, not sharding propagation: every device runs
-                # the whole body on its slice of the round axis (rounds
-                # are independent), and the TPU's compiler refuses to
-                # partition a Pallas kernel by itself ("Mosaic kernels
-                # cannot be automatically partitioned")
-                body = jax.shard_map(
-                    self._run_fn(), mesh=self.mesh,
-                    in_specs=(P(self.axis, None), P(self.axis, None),
-                              jax.tree_util.tree_map(lambda _: P(),
-                                                     self.verifier._pk)),
-                    out_specs=P(self.axis), check_vma=False)
-                fn = jax.jit(
-                    body,
-                    in_shardings=(shard_in, shard_in, pk_sh),
-                    out_shardings=out_sh,
-                ).lower(
-                    jax.ShapeDtypeStruct((m, self.verifier._msg_len()),
-                                         "uint8"),
-                    jax.ShapeDtypeStruct((m, self.verifier.shape.sig_len),
-                                         "uint8"),
-                    self.verifier._pk_struct()).compile()
-                if cpu_tier:
-                    try:
-                        aot.save(name, fn)
-                    except Exception as e:
-                        import sys
-                        print(f"drand_tpu.aot: sharded kernel save failed "
-                              f"({type(e).__name__}: {e}); continuing "
-                              "without persistence", file=sys.stderr)
-            cache[m] = fn
-        return cache[m]
+    def _kernel(self, m: int):
+        if m not in self._kernels:
+            self.build(m // self.n_dev)
+        return self._kernels[m]
 
     def rows_charged(self, n: int) -> int:
         """`Verifier.rows_charged` on this mesh: every device's equal
-        slice is padded into the verifier's program."""
-        from drand_tpu.verify import _bucket
-        return _bucket(-(-n // self.n_dev)) * self.n_dev
+        slice is padded into the verifier's program (its own answer; a
+        verifier that gives none is charged the slice)."""
+        per_dev = -(-n // self.n_dev)
+        charged = getattr(self.verifier, "rows_charged", None)
+        return (charged(per_dev) if charged else per_dev) * self.n_dev
 
     def verify_batch_async(self, rounds, sigs, prev_sigs=None):
         """Dispatch a sharded batch verify without blocking; returns a
         zero-arg callable yielding bool[B] (same contract as
-        Verifier.verify_batch_async, so the sync manager's one-in-flight
-        pipeline overlaps transfer with compute on multi-device hosts
-        too).
+        Verifier.verify_batch_async, so the sync manager's pipeline
+        overlaps transfer with compute on multi-device hosts too).
 
-        Pads the batch to a multiple of the mesh size so every device
-        holds an equal slice (the kernel is branchless — padded lanes
-        just redo the last element's work)."""
+        Pads the batch to the mesh times the verifier's program
+        (`rows_charged`), so every device holds an equal slice; padded
+        rows are copies of the last and their verdicts are dropped."""
+        import time
+
         import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from drand_tpu import tracing
+        from drand_tpu.verify import pad_rows
 
         rounds = np.asarray(rounds, dtype=np.uint64)
         n = rounds.shape[0]
         if n == 0 or self.n_dev == 1:
             return self.verifier.verify_batch_async(rounds, sigs, prev_sigs)
         v = self.verifier
-        msgs = v.messages(rounds, prev_sigs)
-        # pad to devices * bucket granularity
-        m = self.rows_charged(n)
-        per_dev = m // self.n_dev
-        if m != n:
-            pad = m - n
-            msgs = np.concatenate([msgs, np.repeat(msgs[-1:], pad, 0)])
-            sigs = np.concatenate([sigs, np.repeat(sigs[-1:], pad, 0)])
-        kern = self._sharded_kernel(m)
-        # pk is a replicated runtime argument (verify.py batch-3 design);
-        # only the round axis shards
-        repl = NamedSharding(self.mesh, P())
-        pk = jax.tree_util.tree_map(lambda a: jax.device_put(a, repl),
-                                    v._pk)
-        import time as _time
-        t0 = _time.perf_counter()
-        ok = kern(self._shard(jnp.asarray(msgs, jnp.uint8)),
-                  self._shard(jnp.asarray(sigs, jnp.uint8)),
-                  pk)
-        dispatch_s = _time.perf_counter() - t0
+        # `verify.dispatch` as on one device (`Verifier.verify_batch_async`)
+        # with `bucket` the rows charged over the whole mesh; its child
+        # `verify.shard_put` is the placement of every device's slice,
+        # straight from the host's rows (no stop on the first device)
+        with tracing.span("verify.dispatch", n=n, devices=self.n_dev) as sp:
+            m = self.rows_charged(n)
+            msgs, sigs = pad_rows(v.messages(rounds, prev_sigs), sigs, m)
+            msgs = np.ascontiguousarray(msgs, dtype=np.uint8)
+            sigs = np.ascontiguousarray(sigs, dtype=np.uint8)
+            t0 = time.perf_counter()
+            kernel = self._kernel(m)
+            t1 = time.perf_counter()
+            with tracing.span("verify.shard_put", devices=self.n_dev,
+                              bytes=msgs.nbytes + sigs.nbytes):
+                if self._pk_placed is None:
+                    # a replicated runtime argument, placed once
+                    self._pk_placed = jax.device_put(v._pk, self._named())
+                rows = self._named(self.axis, None)
+                placed = jax.device_put((msgs, sigs), rows)
+            ok = kernel(*placed, self._pk_placed)
+            dispatch_s = time.perf_counter() - t1
+            sp.set(bucket=m, pad_rows=m - n, per_dev=m // self.n_dev,
+                   prepare_s=t0 - sp.start_mono, enqueue_s=dispatch_s,
+                   msg_bytes=msgs.shape[1],
+                   h2d_bytes=msgs.nbytes + sigs.nbytes)
         done = [False]
 
         def resolve():
-            t1 = _time.perf_counter()
+            t1 = time.perf_counter()
+            jax.block_until_ready(ok)
+            # `verify.gather` begins with every shard's verdicts ready:
+            # it is the copies to the host and their joining in round
+            # order, and holds no wait for the device
+            t2 = time.perf_counter()
             out = np.asarray(ok)[:n]
             if not done[0]:
                 done[0] = True
+                t3 = time.perf_counter()
+                resolved = tracing.record_span("verify.resolve", t1, t3,
+                                               n=n, bucket=m)
+                tracing.record_span("verify.gather", t2, t3, parent=resolved,
+                                    devices=self.n_dev)
                 from drand_tpu.profiling import record_dispatch
-                record_dispatch("sharded", n, m,
-                                dispatch_s + (_time.perf_counter() - t1),
-                                devices=self.n_dev, per_dev=per_dev)
+                record_dispatch("sharded", n, m, dispatch_s + (t3 - t1),
+                                devices=self.n_dev, per_dev=m // self.n_dev)
             return out
         return resolve
 

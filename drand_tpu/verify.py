@@ -49,6 +49,17 @@ def _bucket(n: int) -> int:
     return ((n + _BUCKETS[-1] - 1) // _BUCKETS[-1]) * _BUCKETS[-1]
 
 
+def pad_rows(msgs: np.ndarray, sigs: np.ndarray, m: int):
+    """(msgs, sigs) padded to `m` rows with copies of the last row (the
+    program is branchless: a padded row redoes the last one's work, and
+    the caller drops its verdict)."""
+    pad = m - msgs.shape[0]
+    if pad:
+        msgs = np.concatenate([msgs, np.repeat(msgs[-1:], pad, axis=0)])
+        sigs = np.concatenate([sigs, np.repeat(sigs[-1:], pad, axis=0)])
+    return msgs, sigs
+
+
 def rounds_be8(rounds: np.ndarray) -> np.ndarray:
     """uint64 rounds -> [B, 8] big-endian bytes (vectorized)."""
     r = np.asarray(rounds, dtype=">u8")
@@ -189,10 +200,13 @@ class Verifier:
                 jax.ShapeDtypeStruct((n, self.shape.sig_len), jnp.uint8),
                 self._pk_struct())
 
-    def build(self, n: int) -> dict:
+    def build(self, n: int, mesh=None) -> dict:
         """Bucket `n`'s program, installed; returns what the build cost,
         for whoever warms a bucket ahead of traffic (chip_smoke.py prints
-        it).
+        it).  `mesh` is a `ShardedVerifier` over this one: the program of
+        `n` rows a device is then compiled once more under its
+        `shard_map` (`mesh.over`) and installed there, from the same
+        exported form, file and key as on a host of one chip.
 
         The program's exported form (`jax.export`) comes from its file
         beside JAX's cache where one was written under this very key
@@ -217,7 +231,9 @@ class Verifier:
         # a span of its own and one a phase, from the same clock reads as
         # the record: a bucket built lazily under an open `sync.segment`
         # or `scan.flush` shows there as what stalled it
-        with tracing.span("verifier.build", bucket=n, program=name) as sp:
+        devices = {} if mesh is None else {"devices": mesh.n_dev}
+        with tracing.span("verifier.build", bucket=n, program=name,
+                          **devices) as sp:
             t0 = sp.start_mono
             exported, found = aot.load_exported(name, compact, body)
             tl = time.perf_counter()
@@ -231,14 +247,18 @@ class Verifier:
             t1 = time.perf_counter()
             if source == "traced":
                 tracing.record_span("build.trace", tl, t1)
-            lowered = jax.jit(exported.call).trace(*structs).lower()
+            if mesh is None:
+                program, at, into = jax.jit(exported.call), structs, self
+            else:
+                program, at, into = *mesh.over(exported.call, structs), mesh
+            lowered = program.trace(*at).lower()
             t2 = time.perf_counter()
             tracing.record_span("build.lower", t1, t2)
-            self._kernels[n] = lowered.compile()
+            into._kernels[at[0].shape[0]] = lowered.compile()
             t3 = time.perf_counter()
             tracing.record_span("build.compile", t2, t3)
             sp.set(source=source, load_s=tl - t0, **found)
-        return {"program": name, "bucket": n,
+        return {"program": name, "bucket": n, **devices,
                 "tracing": "compact" if compact else "static",
                 "source": source, "load_s": tl - t0, **found,
                 "trace_s": t1 - t0, "lower_s": t2 - t1,
@@ -272,14 +292,8 @@ class Verifier:
         # is the share of the device's work that is padding; `msg_bytes`
         # is one row's message, `h2d_bytes` what the dispatch sends.
         with tracing.span("verify.dispatch", n=n) as sp:
-            msgs = self.messages(rounds, prev_sigs)
             m = self.rows_charged(n)
-            if m != n:
-                pad = m - n
-                msgs = np.concatenate(
-                    [msgs, np.repeat(msgs[-1:], pad, axis=0)])
-                sigs = np.concatenate(
-                    [sigs, np.repeat(sigs[-1:], pad, axis=0)])
+            msgs, sigs = pad_rows(self.messages(rounds, prev_sigs), sigs, m)
             t0 = time.perf_counter()
             kernel = self._kernel(m)
             t1 = time.perf_counter()

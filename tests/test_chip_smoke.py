@@ -131,42 +131,49 @@ def test_a_consumer_that_commits_past_a_corrupted_round_fails_the_smoke(
 
 def test_four_chips_control_flow_on_four_virtual_devices(
         smoke_on_cpu, monkeypatch, capsys, tmp_path):
-    """`--four-chips` on four of the suite's virtual CPU devices, with the
-    verify body replaced by a traceable stand-in that rejects exactly the
-    round the smoke corrupts (round batch/2 + 1): the sharded path is
-    taken, every device holds a shard, verdicts agree."""
-    import jax.numpy as jnp
-
+    """`--four-chips` on four of the suite's virtual CPU devices, at 64
+    rows a device, with the verify body replaced by a traceable stand-in
+    that rejects exactly the rows the smoke corrupts (byte 5 of a made-up
+    signature is under 0x80 until the smoke flips it): the sharded path
+    is taken, the mesh's build traces and the one-device build loads the
+    same form, every device holds a shard, verdicts agree."""
     import drand_tpu.verify as V
-    batch = 16384
-    bad = batch // 2 + 1
+    from drand_tpu.chain.scheme import scheme_by_id
+    from drand_tpu.chain.verify import ChainVerifier
+    per = 64
 
     def fake_run_fn(self, compact=None):
-        def run(msgs, sigs, pk):
-            low = msgs[..., 6].astype(jnp.int32) * 256 + msgs[..., 7]
-            return low != bad
-        return run
+        return lambda msgs, sigs, pk: sigs[:, 5] < 0x80
+
+    def made_up_fixture(rows):
+        sigs = np.random.default_rng(34).integers(
+            0, 128, size=(rows, 48), dtype=np.uint8)
+        from drand_tpu.crypto.bls12381 import curve as GC
+        return sigs, ChainVerifier(
+            scheme_by_id("bls-unchained-g1-rfc9380"),
+            GC.g2_to_bytes(GC.G2_GEN))       # any key: no row is verified
 
     monkeypatch.setattr(V.Verifier, "_run_fn", fake_run_fn)
+    monkeypatch.setattr(smoke_on_cpu, "_quicknet_fixture", made_up_fixture)
     monkeypatch.setattr(jax, "devices", lambda: _real_devices()[:4])
-    # the CPU tier would serialize the stand-in programs into aot/, and
-    # `Verifier.build` write their exported form beside JAX's cache
-    # (under a key that names the stand-in, so never read as the
+    # `Verifier.build` writes the stand-in's exported form beside JAX's
+    # cache (under a key that names the stand-in, so never read as the
     # sources' program: test_exported_program.py): not in the checkout
-    monkeypatch.setattr("drand_tpu.aot.load", lambda name, extra="": None)
-    monkeypatch.setattr("drand_tpu.aot.save", lambda *a, **k: "")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    monkeypatch.setattr(V, "_BUCKETS", (8, 64, 512, 4096, 16384))
-    device = smoke_on_cpu.four_chips(batch)
+    device = smoke_on_cpu.four_chips(per)
     assert device["count"] == 4
-    out = [l["four_chips"] for l in _json_lines(capsys.readouterr().out)
-           if "four_chips" in l][0]
+    lines = _json_lines(capsys.readouterr().out)
+    out = [l["four_chips"] for l in lines if "four_chips" in l][0]
     assert len(out["devices_holding_a_shard"]) == 4
-    assert out["rows_per_shard"] == [batch // 4]
-    assert out["sharded_true"] == out["one_device_true"] == batch - 1
+    assert out["rows_per_shard"] == [per]
+    assert out["sharded_true"] == out["one_device_true"] == 4 * per - 4
+    builds = [l["program"] for l in lines if "program" in l]
+    assert [(b["load"], b["source"], b.get("devices")) for b in builds] == [
+        ("the mesh's build", "traced", 4),
+        ("one device, the same form", "loaded", None)]
     written = [fn for fn in os.listdir(tmp_path) if fn.endswith(".jaxexport")]
-    assert written and all(_traced_body(tmp_path / fn).endswith(
-        "<locals>.fake_run_fn") for fn in written)
+    assert len(written) == 1 and _traced_body(tmp_path / written[0]).endswith(
+        "<locals>.fake_run_fn")
 
 
 def _traced_body(path) -> str:

@@ -9,6 +9,8 @@ parity of the same paths runs under --runslow in test_parallel.py.
 import numpy as np
 import pytest
 
+import drand_tpu.verify as V
+from drand_tpu.crypto.bls12381 import curve as GC
 from drand_tpu.parallel.sharded import ShardedVerifier, _pad2
 
 
@@ -21,57 +23,31 @@ def test_pad2_edge_pads_leading_axes():
     assert (p[:2, :3] == a).all()
 
 
-class _StubVerifier:
-    """Quacks like drand_tpu.verify.Verifier for the sharding layer.
-
-    Provides `_run_fn` (the pure kernel body) the way the sharding layer
-    consumes it: ShardedVerifier compiles its OWN mesh-sharded jit from
-    this body — it must NOT reuse Verifier._kernel's single-device
-    Compiled (which cannot accept NamedSharding inputs)."""
-
-    class _Shape:
-        sig_len = 96
-
-    shape = _Shape()
+class _StubVerifier(V.Verifier):
+    """`Verifier` with a stand-in body for the sharding layer: a row is
+    valid iff its signature's first byte is even.  `ShardedVerifier`
+    builds its program over the mesh from this verifier's exported form
+    (`Verifier.build(n, mesh=...)`): it must NOT reuse
+    `Verifier._kernel`'s single-device Compiled, which cannot accept
+    NamedSharding inputs."""
 
     def __init__(self):
-        self.calls = []
-        # real Verifier passes its affine pk limbs as the third kernel
-        # argument (runtime pk, one executable per scheme/batch)
-        self._pk = (np.zeros(32, np.int32), np.zeros(32, np.int32))
-
-    def messages(self, rounds, prev_sigs):
-        return np.repeat(rounds.astype(np.uint64)[:, None], 8, axis=1) \
-            .astype(np.uint8)
-
-    def _msg_len(self):
-        return 8
+        super().__init__(GC.G1_GEN, V.SHAPE_UNCHAINED)
 
     def _aot_name(self, n):
         return f"stub-verify-b{n}"
 
-    def _pk_struct(self):
-        import jax
-        return tuple(jax.ShapeDtypeStruct((32,), np.int32)
-                     for _ in range(2))
-
-    def _run_fn(self):
+    def _run_fn(self, compact=None):
         def run(msgs, sigs, pk):
-            # "valid" iff the signature's first byte is even
             return (sigs[..., 0] % 2) == 0
         return run
 
-    def verify_batch(self, rounds, sigs, prev_sigs=None):
-        m = self.messages(np.asarray(rounds, np.uint64), prev_sigs)
-        import jax
-        import jax.numpy as jnp
-        return np.asarray(jax.jit(self._run_fn())(jnp.asarray(m),
-                                                  jnp.asarray(sigs),
-                                                  self._pk))
 
-    def verify_batch_async(self, rounds, sigs, prev_sigs=None):
-        out = self.verify_batch(rounds, sigs, prev_sigs)
-        return lambda: out
+@pytest.fixture(autouse=True)
+def cache(tmp_path, monkeypatch):
+    """A stand-in body's exported form stays out of the checkout's
+    cache."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
 
 
 def test_sharded_verify_batch_async_pipelines():
@@ -116,7 +92,7 @@ def test_rows_charged_is_what_a_dispatch_is_padded_to():
     assert sv.rows_charged(n) == 8 * _bucket(3)
     sv.verify_batch(np.arange(1, n + 1, dtype=np.uint64),
                     np.zeros((n, 96), dtype=np.uint8))
-    assert list(sv._skernels) == [sv.rows_charged(n)]
+    assert list(sv._kernels) == [sv.rows_charged(n)]
     assert sv.rows_charged(8 * _bucket(3) + 1) == 2 * sv.rows_charged(n)
 
 
@@ -137,14 +113,12 @@ def test_sharded_kernel_inputs_actually_sharded():
     # and confirm the OUTPUT comes back sharded over the round axis —
     # a de-sharded kernel would place everything on one device
     import jax.numpy as jnp
-    (m, kern), = sv._skernels.items()
+    (m, kern), = sv._kernels.items()
     shard = NamedSharding(sv.mesh, P("rounds", None))
     msgs = jax.device_put(jnp.zeros((m, 8), jnp.uint8), shard)
     sgs = jax.device_put(jnp.zeros((m, 96), jnp.uint8), shard)
     repl = NamedSharding(sv.mesh, P())
-    pk = tuple(jax.device_put(jnp.zeros(32, jnp.int32), repl)
-               for _ in range(2))
-    out = kern(msgs, sgs, pk)
+    out = kern(msgs, sgs, jax.device_put(sv.verifier._pk, repl))
     assert out.sharding.is_equivalent_to(
         NamedSharding(sv.mesh, P("rounds")), out.ndim)
 

@@ -649,3 +649,263 @@ def test_a_segment_is_cut_where_the_program_is_full(chain, monkeypatch,
         links = [s for s in tracing.RECORDER.spans()
                  if s.name == "verify.genesis_link"]
         assert [s.round for s in links] == [1]
+
+
+# -- where a segment is cut on a mesh (ISSUE 34) ------------------------------
+#
+# On a host of several chips `ChainVerifier` lays a dispatch over a mesh
+# (`ShardedVerifier`): every device's slice is padded into the verifier's
+# program, so the program is full where the segment holds the bucket times
+# the devices.  Below, a real `ChainVerifier` and a real `ShardedVerifier`
+# over four of the suite's eight virtual devices; the device program is
+# BUILT as the chip's is (`Verifier.build`: exported, put under the mesh's
+# `shard_map`, compiled) from a stand-in body: a row is false iff its
+# signature's first byte is 0xFF.
+
+MESH, MESH_BUCKET, MESH_BACKLOG = 4, 64, 1024
+FULL = MESH * MESH_BUCKET
+
+
+class _FakeBody(V.Verifier):
+    def _run_fn(self, compact=None):
+        return lambda msgs_u8, sig_u8, pk: sig_u8[:, 0] != 0xFF
+
+
+def _mesh_chain(bad_round):
+    """(sigs, the chain as 8-round wire messages)."""
+    sigs = np.random.default_rng(34).integers(
+        0, 128, size=(MESH_BACKLOG, 96), dtype=np.uint8)
+    if bad_round is not None:
+        sigs[bad_round - 1, 0] = 0xFF
+    return sigs, [SM.PackedBeacons(start_round=at + 1, sigs=sigs[at:at + 8],
+                                   first_prev=b"", chained=False)
+                  for at in range(0, MESH_BACKLOG, 8)]
+
+
+def _catch_up_on_a_mesh(chain, monkeypatch, tmp_path, up_to, bad_round=None):
+    """(ok, store, sigs, the recorder's spans) of one catch-up."""
+    import jax
+
+    from drand_tpu import tracing
+    from drand_tpu.parallel import ShardedVerifier
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(V, "_BUCKETS", (MESH_BUCKET,))
+    monkeypatch.setattr(SM, "SYNC_CHUNK", 2)
+    monkeypatch.setattr(SM, "SYNC_CHUNK_MAX", MESH_BUCKET)
+    scheme = scheme_by_id("pedersen-bls-unchained")
+    cv = ChainVerifier(scheme, chain[1].public_key_bytes)
+    cv._lazy_verifier = ShardedVerifier(
+        _FakeBody(cv._pk_point, scheme.shape), devices=jax.devices()[:MESH])
+    sigs, messages = _mesh_chain(bad_round)
+    store = _seeded_store()
+    mgr = SM.SyncManager(store=store, group=FakeGroup(), verifier=cv,
+                         network=ChunkNet(messages), nodes=[object()],
+                         clock=FixedClock())
+    tracing.RECORDER.clear()
+    ok = asyncio.run(mgr._try_node(object(), SM.SyncRequest(1, up_to)))
+    return ok, store, sigs, tracing.RECORDER.spans()
+
+
+MESH_CASES = {
+    # name: up_to -> (segments as (rounds, cut), rounds committed)
+    "a_known_backlog_fills_the_mesh": (
+        MESH_BACKLOG, [(FULL, "full")] * 3 + [(FULL, "backlog_end")],
+        MESH_BACKLOG),
+    "b_follow_mode_keeps_the_ramp": (
+        0, [(8, "target")] + [(MESH_BUCKET, "target")] * 15
+        + [(56, "stream_end")], MESH_BACKLOG),
+    "c_a_backlog_that_is_no_multiple_pads_its_last_segment": (
+        600, [(FULL, "full")] * 2 + [(88, "backlog_end")], 600),
+    "d_a_backlog_inside_the_mesh_is_one_segment": (
+        100, [(100, "backlog_end")], 100),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MESH_CASES))
+def test_a_segment_is_cut_where_the_mesh_is_full(chain, monkeypatch,
+                                                 tmp_path, case):
+    up_to, want_segments, want_committed = MESH_CASES[case]
+    ok, store, sigs, spans = _catch_up_on_a_mesh(chain, monkeypatch,
+                                                 tmp_path, up_to)
+    assert ok
+    assert sorted(store.by_round) == list(range(0, want_committed + 1))
+    assert all(store.by_round[r].signature == sigs[r - 1].tobytes()
+               for r in range(1, want_committed + 1))
+    segments = [s for s in spans if s.name == "sync.segment"]
+    assert [(s.attrs["rounds"], s.attrs["cut"]) for s in segments] \
+        == want_segments
+    assert all(s.status == "ok" for s in segments)
+    # every dispatch is charged the mesh's program, and says so in the
+    # attributes the benchmark reads `verify.pad_share` from; a padded
+    # row's verdict never reached the store (the count above)
+    dispatches = [s for s in spans if s.name == "verify.dispatch"]
+    assert [{k: s.attrs[k] for k in ("n", "bucket", "pad_rows", "devices",
+                                     "per_dev")} for s in dispatches] \
+        == [{"n": n, "bucket": FULL, "pad_rows": FULL - n, "devices": MESH,
+             "per_dev": MESH_BUCKET} for n, _cut in want_segments]
+    assert all(s.attrs["h2d_bytes"] == FULL * (8 + 96) for s in dispatches)
+    # one placement a dispatch, under it; one gather a resolve, under it
+    by_id = {s.span_id: s for s in spans}
+    for child, parent in (("verify.shard_put", "verify.dispatch"),
+                          ("verify.gather", "verify.resolve")):
+        mine = [s for s in spans if s.name == child]
+        assert len(mine) == len(dispatches)
+        assert all(by_id[s.parent_id].name == parent for s in mine)
+        assert all(s.attrs["devices"] == MESH for s in mine)
+    # the program was built once, for the mesh, from one device's form
+    (build,) = [s for s in spans if s.name == "verifier.build"]
+    assert build.attrs["bucket"] == MESH_BUCKET
+    assert build.attrs["devices"] == MESH
+
+
+# a false row in the second full segment (rounds 257..512): first, last
+# and inside the slice of each of the four devices
+FALSE_ROWS = {f"shard{k}_{where}": FULL + MESH_BUCKET * k + at
+              for k in range(MESH)
+              for where, at in (("first", 1), ("inside", 30),
+                                ("last", MESH_BUCKET))}
+
+
+@pytest.mark.parametrize("where", sorted(FALSE_ROWS))
+def test_a_false_row_on_any_device_fails_its_whole_segment(
+        chain, monkeypatch, tmp_path, where):
+    bad_round = FALSE_ROWS[where]
+    ok, store, sigs, spans = _catch_up_on_a_mesh(
+        chain, monkeypatch, tmp_path, MESH_BACKLOG, bad_round)
+    assert not ok
+    # what is committed ends where that segment begins, and is the chain
+    assert sorted(store.by_round) == list(range(0, FULL + 1))
+    assert all(store.by_round[r].signature == sigs[r - 1].tobytes()
+               for r in range(1, FULL + 1))
+    segments = [(s.attrs["first_round"], s.status) for s in spans
+                if s.name == "sync.segment"]
+    assert segments[:2] == [(1, "ok"), (FULL + 1, "verify_failed")]
+    assert all(status == "discarded" for _first, status in segments[2:])
+
+
+# -- the start-up scan on a mesh (ISSUE 34) -----------------------------------
+
+def test_the_scan_flushes_where_the_mesh_is_full(chain, monkeypatch,
+                                                 tmp_path):
+    """`scan_store` asks the verifier what its throughput segment is
+    charged for, as the catch-up's cut does: four devices under one
+    bucket of 64 verify 256 stored rounds a flush, no padded row but in
+    the last, and `bad_sigs` names exactly the false rows, one in every
+    device's slice of the first flush and one in the padded last."""
+    import jax
+
+    from drand_tpu import tracing
+    from drand_tpu.chain import recovery
+    from drand_tpu.chain.store import SqliteStore
+    from drand_tpu.parallel import ShardedVerifier
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    monkeypatch.setattr(V, "_BUCKETS", (MESH_BUCKET,))
+    monkeypatch.setattr(recovery, "SCAN_SEGMENT_ROUNDS", MESH_BUCKET)
+    scheme = scheme_by_id("pedersen-bls-unchained")
+    cv = ChainVerifier(scheme, chain[1].public_key_bytes)
+    cv._lazy_verifier = ShardedVerifier(
+        _FakeBody(cv._pk_point, scheme.shape), devices=jax.devices()[:MESH])
+    stored = 600
+    bad = [1, 64 + 30, 128 + 64, 192 + 7, 590]
+    sigs, _messages = _mesh_chain(None)
+    sigs[np.array(bad) - 1, 0] = 0xFF
+    store = SqliteStore(str(tmp_path / "scan.db"))
+    store.put_many([Beacon(round=r, signature=sigs[r - 1].tobytes())
+                    for r in range(1, stored + 1)])
+    tracing.RECORDER.clear()
+    try:
+        report = asyncio.run(recovery.scan_store(store, cv))
+    finally:
+        store.close()
+    assert report.verify_checked and report.scanned == stored
+    assert report.bad_sigs == bad and report.verified_tip == 0
+    spans = tracing.RECORDER.spans()
+    assert [s.attrs["rows"] for s in spans if s.name == "scan.flush"] \
+        == [FULL, FULL, stored - 2 * FULL]
+    assert [(s.attrs["n"], s.attrs["bucket"], s.attrs["devices"])
+            for s in spans if s.name == "verify.dispatch"] \
+        == [(FULL, FULL, MESH), (FULL, FULL, MESH),
+            (stored - 2 * FULL, FULL, MESH)]
+
+
+@pytest.mark.parametrize("who,want", [
+    ("one_device_under_its_one_bucket", 64),
+    ("a_verifier_that_does_not_answer", 64)])
+def test_on_one_device_the_scan_flushes_what_it_did(chain, monkeypatch,
+                                                    who, want):
+    from drand_tpu.chain import recovery
+    monkeypatch.setattr(V, "_BUCKETS", (64,))
+    monkeypatch.setattr(recovery, "SCAN_SEGMENT_ROUNDS", 64)
+    scheme = scheme_by_id("pedersen-bls-unchained")
+    cv = ChainVerifier(scheme, chain[1].public_key_bytes)
+    cv._lazy_verifier = _FakeDevice(scheme.shape)
+    verifier = cv if who.startswith("one_device") else _Unasked(cv)
+    assert recovery._flush_rounds(verifier) == want
+
+
+def test_a_short_store_is_scanned_without_the_device_verifier(chain,
+                                                              tmp_path):
+    """The scan asks the verifier for its segment size only with a
+    device segment's worth of rows in hand: a store of a few rounds is
+    verified on the host tier, and asking sooner would bring the device
+    verifier up (and route every later small batch to a program that has
+    to be built first)."""
+    from drand_tpu.chain import recovery
+    from drand_tpu.chain.store import SqliteStore
+    beacons, cv = chain
+    cv = ChainVerifier(cv.scheme, cv.public_key_bytes)
+    store = SqliteStore(str(tmp_path / "short.db"))
+    store.put_many([Beacon(round=0, signature=SEED)] + list(beacons))
+    try:
+        report = asyncio.run(recovery.scan_store(store, cv))
+    finally:
+        store.close()
+    assert report.ok and report.verify_checked and report.scanned == N + 1
+    assert cv._lazy_verifier is None
+
+
+# -- the collector while a segment is committed (ISSUE 34) --------------------
+#
+# A commit's rows are a Beacon a round, twice over in the store's
+# decorators, and die with it; on four chips a segment is 65,536 rounds.
+# Left to the cyclic collector they set off two full collections a commit
+# (about 50 ms each on the chip's host, every thread stopped), so the
+# commit holds the collector off and gives the process's setting back.
+
+def _segment(first_round: int, rows: int):
+    sigs = np.zeros((rows, 96), dtype=np.uint8)
+    return [SM.PackedBeacons(start_round=first_round, sigs=sigs,
+                             first_prev=b"", chained=False)]
+
+
+@pytest.mark.parametrize("was_on", [True, False])
+def test_no_cyclic_collection_while_a_segment_s_rows_are_alive(
+        monkeypatch, was_on):
+    import gc
+
+    class Watching(MemStore):
+        seen = []
+
+        def put_many(self, beacons):
+            self.seen.append(gc.isenabled())
+            super().put_many(beacons)
+
+    store = Watching()
+    store.put(Beacon(round=0, signature=SEED))
+    mgr = SM.SyncManager(store=store, group=FakeGroup(),
+                         verifier=_YesVerifier(),
+                         network=ChunkNet(_segment(1, 8) + _segment(9, 8)),
+                         nodes=[object()], clock=FixedClock())
+    monkeypatch.setattr(SM, "SYNC_CHUNK", 8)
+    (gc.enable if was_on else gc.disable)()
+    try:
+        assert asyncio.run(mgr._try_node(object(), SM.SyncRequest(1, 16)))
+        # the process's own setting is back, whichever it was
+        assert gc.isenabled() is was_on
+        with SM._collector_paused(), SM._collector_paused():
+            assert not gc.isenabled()
+        assert gc.isenabled() is was_on
+    finally:
+        gc.enable()
+    assert store.seen == [False, False]
+    assert sorted(store.by_round) == list(range(0, 17))
