@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import asyncio
 import time
+from collections import deque
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -54,6 +55,10 @@ log = dlog.get("chain.recovery")
 SCAN_SEGMENT_ROUNDS = 16384
 # raw rows fetched per worker-thread sqlite crossing
 SCAN_READ_BATCH = 4096
+# flushes a scan keeps dispatched and not yet settled while it reads and
+# decodes on: one runs on the device, the next is packed and enqueued
+# behind it before the scan waits for the first
+SCAN_DISPATCH_AHEAD = 1
 
 
 @dataclass
@@ -134,27 +139,145 @@ async def scan_store(store, verifier=None, *, beacon_id: str = "",
     reads and every potentially-blocking verifier dispatch happen in
     worker threads; the event loop stays live.
 
-    One trace a scan: the root `store.scan`; under it a `scan.read` for
-    every `raw_rows` batch and a `scan.decode` for that batch's per-row
-    loop (rows, corrupt and unlinked counted as attributes, never a span
-    a row); a `scan.flush` for every verified segment, holding
-    `scan.pack` (`pack_rows`), the verifier's own `verify.segment` and
-    `verify.dispatch`, and `scan.verify_wait` over its `verify.resolve`.
+    A flush dispatches one segment ahead (SCAN_DISPATCH_AHEAD): it
+    packs its rows, dispatches them (the resolver comes back once the
+    work is enqueued), and only then settles the flush BEFORE it: waits
+    for that one's verdicts and files its `bad_sigs`.  The row loop then
+    reads and decodes the next segment while the device runs this one.
+    After the last row what is pending is dispatched and the flushes
+    still out are settled, oldest first: the report is the sequential
+    scan's, list for list.  Whatever ends the scan (a dispatch, a
+    resolver or the row loop raising, the task cancelled), every
+    resolver handed out has been called before `scan_store` returns.
+
+    One trace a scan: the root `store.scan` (scanned, flagged;
+    `overlapped`: flushes that dispatched with another in flight, 3 of 4
+    over 65,536 rounds on one chip); under it a `scan.read` for every
+    `raw_rows` batch and a `scan.decode` for that batch's per-row loop
+    (rows, corrupt and unlinked counted as attributes, never a span a
+    row); a `scan.flush` (rows; `in_flight`: flushes dispatched and not
+    settled as this one dispatched, 0 on the first and 1 after) for
+    every verified segment, holding `scan.pack` (`pack_rows`), the
+    verifier's own `verify.dispatch`, and `scan.verify_wait` where the
+    scan blocks: around the wait for the flush before, over its
+    `verify.resolve`; the last flush's `scan.verify_wait` lies under the
+    root.  The verifier's `verify.segment` runs from a segment's
+    dispatch to its resolver, so it outlives the flush it began under,
+    as it crosses the catch-up's stages.
     """
     from drand_tpu import tracing
     with tracing.span("store.scan", beacon_id=beacon_id,
                       verify=verifier is not None) as root:
-        report = await _scan_store(store, verifier, beacon_id,
-                                   segment_rounds, read_batch, on_progress)
-        root.set(scanned=report.scanned, flagged=len(report.damaged_rounds))
+        ahead = _DispatchedAhead(verifier)
+        try:
+            report = await _scan_store(store, ahead, beacon_id,
+                                       segment_rounds, read_batch,
+                                       on_progress)
+        except BaseException:
+            await ahead.abandon()
+            raise
+        root.set(scanned=report.scanned, flagged=len(report.damaged_rounds),
+                 overlapped=ahead.overlapped)
     return report
 
 
-async def _scan_store(store, verifier, beacon_id: str,
+async def _in_worker(fn, *args):
+    """`asyncio.to_thread` that a cancelled caller waits out: a thread
+    cannot be stopped, and what it dispatches has to be on record
+    before the scan cleans up after itself."""
+    work = asyncio.ensure_future(asyncio.to_thread(fn, *args))
+    try:
+        return await asyncio.shield(work)
+    except asyncio.CancelledError:
+        await asyncio.wait([work])
+        if not work.cancelled():
+            work.exception()        # retrieved: the cancellation wins
+        raise
+
+
+class _DispatchedAhead:
+    """The flushes a scan has dispatched and not settled, oldest first.
+    A flush is its packed segments' (start_round, resolver) in dispatch
+    order, each taken off as it is called, and the rows that verify one
+    by one."""
+
+    def __init__(self, verifier):
+        self.verifier = verifier
+        self.flushes: deque[tuple[deque, list]] = deque()
+        self.overlapped = 0     # flushes dispatched with another out
+
+    def __len__(self) -> int:
+        return len(self.flushes)
+
+    async def dispatch(self, items) -> None:
+        """Enqueue a flush's packed segments behind what is out."""
+        self.overlapped += bool(self.flushes)
+        # on record before its first dispatch: a resolver handed out is
+        # then found by whatever ends the scan
+        self.flushes.append((deque(), []))
+        await _in_worker(self._dispatch, items, *self.flushes[-1])
+
+    def _dispatch(self, items, resolvers: deque, singles: list) -> None:
+        for item in items:
+            if isinstance(item, PackedBeacons):
+                # anchor = the row's own STORED prev: linkage against the
+                # actual predecessor sig was already judged structurally,
+                # so here the batch checks pure signature validity over
+                # exactly the bytes on disk
+                resolvers.append((
+                    item.start_round,
+                    self.verifier.verify_packed_segment_async(
+                        item, item.first_prev)))
+            else:
+                singles.append(item)
+
+    async def settle_oldest(self) -> list[int]:
+        """Wait for the oldest flush's verdicts -> its bad rounds, the
+        packed segments' first, then those of the rows verified one by
+        one.  `scan.verify_wait` is opened here, where the scan blocks,
+        and not in the worker."""
+        from drand_tpu import tracing
+        with tracing.span("scan.verify_wait"):
+            bad = await _in_worker(self._settle, *self.flushes[0])
+        self.flushes.popleft()
+        return bad
+
+    def _settle(self, resolvers: deque, singles: list) -> list[int]:
+        bad: list[int] = []
+        while resolvers:
+            start_round, resolver = resolvers.popleft()
+            ok = np.asarray(resolver())
+            bad.extend(int(start_round + int(i)) for i in np.nonzero(~ok)[0])
+        if singles:
+            ok = np.asarray(self.verifier.verify_beacons(singles))
+            bad.extend(b.round for b, good in zip(singles, ok)
+                       if not bool(good))
+        return bad
+
+    async def abandon(self) -> None:
+        """Call every resolver still out: the verifier's `verify.segment`
+        is closed by its resolver alone, and none stays open behind a
+        scan that failed or was cancelled."""
+        if self.flushes:
+            await _in_worker(self._abandon)
+
+    def _abandon(self) -> None:
+        for resolvers, _singles in self.flushes:
+            while resolvers:
+                _, resolver = resolvers.popleft()
+                try:
+                    resolver()
+                except Exception:
+                    log.debug("an abandoned segment's resolver raised",
+                              exc_info=True)
+
+
+async def _scan_store(store, ahead: _DispatchedAhead, beacon_id: str,
                       segment_rounds: int | None, read_batch: int,
                       on_progress) -> IntegrityReport:
     from drand_tpu import tracing
-    t0 = time.perf_counter()
+    began = time.perf_counter()
+    verifier = ahead.verifier
     report = IntegrityReport(beacon_id=beacon_id,
                              path=getattr(store, "path", ""),
                              verify_checked=verifier is not None)
@@ -162,42 +285,20 @@ async def _scan_store(store, verifier, beacon_id: str,
     prev_good: tuple[int, bytes] | None = None   # (round, sig) last good row
     pending: list[tuple[int, bytes, bytes]] = []  # BLS backlog (r, sig, prev)
 
-    def verify_packed(item) -> np.ndarray:
-        """Worker thread: dispatch one packed segment and wait for it."""
-        # anchor = the row's own STORED prev: linkage against the
-        # actual predecessor sig was already judged structurally,
-        # so here the batch checks pure signature validity over
-        # exactly the bytes on disk
-        resolver = verifier.verify_packed_segment_async(item,
-                                                        item.first_prev)
-        with tracing.span("scan.verify_wait"):
-            return np.asarray(resolver())
-
     async def flush_bls() -> None:
         if verifier is None or not pending:
             return
-        with tracing.span("scan.flush", rows=len(pending)):
+        with tracing.span("scan.flush", rows=len(pending),
+                          in_flight=len(ahead)):
             t0 = time.perf_counter()
             items = list(pack_rows(
                 pending, max_chunk=segment_rounds or len(pending)))
             tracing.record_span("scan.pack", t0, time.perf_counter(),
                                 items=len(items))
-            singles: list = []
-            for item in items:
-                if isinstance(item, PackedBeacons):
-                    ok = await asyncio.to_thread(verify_packed, item)
-                    for i in np.nonzero(~ok)[0]:
-                        report.bad_sigs.append(
-                            int(item.start_round + int(i)))
-                else:
-                    singles.append(item)
-            if singles:
-                ok = np.asarray(await asyncio.to_thread(
-                    verifier.verify_beacons, singles))
-                for b, good in zip(singles, ok):
-                    if not bool(good):
-                        report.bad_sigs.append(b.round)
-        pending.clear()
+            pending.clear()
+            await ahead.dispatch(items)
+            if len(ahead) > SCAN_DISPATCH_AHEAD:
+                report.bad_sigs.extend(await ahead.settle_oldest())
 
     next_round = GENESIS_ROUND
     while True:
@@ -254,6 +355,8 @@ async def _scan_store(store, verifier, beacon_id: str,
             on_progress(report.tip_round)
         next_round = rows[-1][0] + 1
     await flush_bls()
+    while len(ahead):
+        report.bad_sigs.extend(await ahead.settle_oldest())
 
     problems = (report.corrupt + report.unlinked + report.bad_sigs
                 + [a for (a, _) in report.missing])
@@ -263,7 +366,7 @@ async def _scan_store(store, verifier, beacon_id: str,
         report.verified_tip = min(problems) - 1
     else:
         report.verified_tip = report.tip_round
-    report.elapsed_s = time.perf_counter() - t0
+    report.elapsed_s = time.perf_counter() - began
     return report
 
 

@@ -150,27 +150,51 @@ def test_scan_flags_broken_linkage(tmp_path):
     s.close()
 
 
-class _FakeVerifier:
-    """Marks a fixed round's signature bad; mirrors the two entry points
-    scan_store uses (packed segments + single-beacon batches)."""
+class _RecordingVerifier:
+    """The two entry points `scan_store` uses, with every dispatch and
+    every resolver call written down in the order it happened.  `bad`
+    rounds verify false; a packed segment that starts at `dispatch_raises`
+    is refused at its dispatch, one that starts at `resolver_raises` by
+    its resolver."""
 
-    def __init__(self, bad_round):
-        self.bad = bad_round
+    def __init__(self, bad=(), dispatch_raises=None, resolver_raises=None):
+        self.bad = set(bad)
+        self.dispatch_raises = dispatch_raises
+        self.resolver_raises = resolver_raises
+        self.calls: list[tuple[str, int]] = []
+        self.out: set[int] = set()          # dispatched, not resolved
+        self.most_out = 0
 
     def verify_packed_segment_async(self, packed, anchor):
-        ok = np.array([r != self.bad for r in packed.rounds()], dtype=bool)
-        return lambda: ok
+        start = int(packed.start_round)
+        if start == self.dispatch_raises:
+            raise RuntimeError(f"no dispatch at {start}")
+        self.calls.append(("dispatch", start))
+        self.out.add(start)
+        self.most_out = max(self.most_out, len(self.out))
+        ok = np.array([int(r) not in self.bad for r in packed.rounds()],
+                      dtype=bool)
+
+        def resolve():
+            self.calls.append(("resolve", start))
+            self.out.discard(start)
+            if start == self.resolver_raises:
+                raise RuntimeError(f"no verdicts at {start}")
+            return ok
+        return resolve
 
     def verify_beacons(self, beacons):
-        return np.array([b.round != self.bad for b in beacons], dtype=bool)
+        self.calls.append(("singles", beacons[0].round))
+        return np.array([b.round not in self.bad for b in beacons],
+                        dtype=bool)
 
 
 def test_scan_bls_stage_flags_bad_signature(tmp_path):
     s, _ = _chain_db(tmp_path, 8)
-    rep = _scan(s, _FakeVerifier(5))
+    rep = _scan(s, _RecordingVerifier([5]))
     assert rep.verify_checked
     assert rep.bad_sigs == [5] and rep.verified_tip == 4
-    clean = _scan(s, _FakeVerifier(-1))
+    clean = _scan(s, _RecordingVerifier())
     assert clean.ok and clean.verified_tip == 8
     s.close()
 
@@ -314,17 +338,22 @@ def test_util_fsck_repairs_and_reports_json(tmp_path, capsys):
     assert out["ok"] and out["tip_round"] == 3
 
 
-# -- the scan's span tree (ISSUE 25) -----------------------------------------
+# -- the scan's span tree (ISSUE 25; the order of a flush: ISSUE 35) ----------
+
+def _scan_spans():
+    from drand_tpu import tracing
+    return [sp for sp in tracing.RECORDER.spans() if sp.name != "gc.full"]
+
 
 def test_a_scan_is_one_trace_whose_self_times_add_up(tmp_path):
     from benchmark.readers.program_spans import self_seconds
     from drand_tpu import tracing
     s, _ = _chain_db(tmp_path, 40)
     tracing.RECORDER.clear()
-    rep = _scan(s, _FakeVerifier(23), segment_rounds=16, read_batch=16)
+    rep = _scan(s, _RecordingVerifier([23]), segment_rounds=16, read_batch=16)
     s.close()
     assert rep.bad_sigs == [23] and rep.scanned == 40
-    spans = [sp for sp in tracing.RECORDER.spans() if sp.name != "gc.full"]
+    spans = _scan_spans()
     by_id = {sp.span_id: sp for sp in spans}
     (root,) = [sp for sp in spans if sp.parent_id is None]
     assert root.name == "store.scan"
@@ -349,17 +378,246 @@ def test_a_scan_is_one_trace_whose_self_times_add_up(tmp_path):
             assert p.start_mono - 1e-6 <= sp.start_mono, (sp.name, p.name)
             assert sp.start_mono + sp.duration_s \
                 <= p.start_mono + p.duration_s + 1e-6, (sp.name, p.name)
+    for p in spans:         # no two siblings overlap
+        kids = sorted((sp.start_mono, sp.start_mono + sp.duration_s)
+                      for sp in spans if sp.parent_id == p.span_id)
+        for (_, end), (start, _) in zip(kids, kids[1:]):
+            assert end <= start + 1e-6, p.name
     # a flush inside the row loop is the decode's child, the last one the
     # root's: either way no two siblings overlap, so the self times of
     # the tree add up to the scan
-    flush_parents = {by_id[sp.parent_id].name for sp in spans
-                     if sp.name == "scan.flush"}
-    assert flush_parents == {"scan.decode", "store.scan"}
+    flushes = sorted((sp for sp in spans if sp.name == "scan.flush"),
+                     key=lambda sp: sp.start_mono)
+    assert [by_id[sp.parent_id].name for sp in flushes] \
+        == ["scan.decode", "scan.decode", "store.scan"]
+    # a wait is opened where the scan blocks: in the flush AFTER the one
+    # it waits for, which has dispatched by then; the last under the root
+    waits = sorted((sp for sp in spans if sp.name == "scan.verify_wait"),
+                   key=lambda sp: sp.start_mono)
+    assert [sp.parent_id for sp in waits] \
+        == [flushes[1].span_id, flushes[2].span_id, root.span_id]
+    for flush, wait in zip(flushes[1:], waits):
+        (pack,) = [sp for sp in spans if sp.name == "scan.pack"
+                   and sp.parent_id == flush.span_id]
+        assert pack.start_mono + pack.duration_s <= wait.start_mono
     own = self_seconds([(sp.span_id, sp.parent_id, sp.name, sp.start_mono,
                          sp.start_mono + sp.duration_s) for sp in spans])
     assert all(v >= -1e-9 for v in own.values())
     assert sum(own.values()) == pytest.approx(root.duration_s, rel=0.02)
     assert rep.elapsed_s <= root.duration_s
+
+
+# -- one segment dispatched ahead (ISSUE 35) ---------------------------------
+
+def test_a_flush_dispatches_before_it_settles_the_one_before(tmp_path):
+    s, _ = _chain_db(tmp_path, 72)
+    v = _RecordingVerifier()
+    rep = _scan(s, v, segment_rounds=16, read_batch=10)
+    s.close()
+    assert rep.ok and rep.verified_tip == 72
+    starts = [1, 17, 33, 49, 65]                  # four full, a tail of 8
+    want = [("dispatch", starts[0])]
+    for before, start in zip(starts, starts[1:]):
+        want += [("dispatch", start), ("resolve", before)]
+    want.append(("resolve", starts[-1]))
+    assert v.calls == want
+    assert v.most_out == 2 and not v.out
+
+
+def _sequential_report(store, verifier, segment_rounds) -> dict:
+    """The plain scan the dispatching one has to equal: every row read
+    and judged, then every segment of good rows packed, verified and
+    filed before the next is looked at."""
+    report = recovery.IntegrityReport(path=store.path, verify_checked=True)
+    rows = store.raw_rows(0, 1 << 30)
+    good, expected, prev_good = [], None, None
+    for r, blob in rows:
+        report.scanned += 1
+        if report.first_round < 0:
+            report.first_round = r
+        report.tip_round = r
+        if expected is not None and r > expected:
+            report.missing.append((expected, r - 1))
+        expected = r + 1
+        try:
+            decoded, sig, prev = codec.decode_fields(blob)
+            if decoded != r:
+                raise codec.CodecError("round")
+        except codec.CodecError:
+            report.corrupt.append(r)
+            prev_good = None
+            continue
+        linked = not (prev and prev_good is not None
+                      and prev_good[0] == r - 1 and prev != prev_good[1])
+        prev_good = (r, sig)
+        if not linked:
+            report.unlinked.append(r)
+        elif r != 0:
+            good.append((r, sig, prev))
+    from drand_tpu.chain.segment import PackedBeacons, pack_rows
+    for at in range(0, len(good), segment_rounds):
+        singles = []
+        for item in pack_rows(good[at:at + segment_rounds],
+                              max_chunk=segment_rounds):
+            if isinstance(item, PackedBeacons):
+                ok = verifier.verify_packed_segment_async(
+                    item, item.first_prev)()
+                report.bad_sigs += [int(item.start_round + i)
+                                    for i in np.nonzero(~ok)[0]]
+            else:
+                singles.append(item)
+        if singles:
+            ok = verifier.verify_beacons(singles)
+            report.bad_sigs += [b.round for b, good_ in zip(singles, ok)
+                                if not good_]
+    problems = (report.corrupt + report.unlinked + report.bad_sigs
+                + [a for a, _ in report.missing])
+    report.verified_tip = -1 if not rows else (
+        min(problems) - 1 if problems else report.tip_round)
+    return report.to_dict()
+
+
+# 72 rounds in segments of 16: the first is rounds 1-16, a middle one
+# 33-48, the last (a tail of 8) 65-72
+_DAMAGE = {
+    "bad_sig_first_segment_first_row": dict(bad=[1]),
+    "bad_sig_first_segment_last_row": dict(bad=[16]),
+    "bad_sig_middle_segment_first_row": dict(bad=[33]),
+    "bad_sig_middle_segment_last_row": dict(bad=[48]),
+    "bad_sig_last_segment_first_row": dict(bad=[65]),
+    "bad_sig_last_segment_last_row": dict(bad=[72]),
+    "bad_sigs_in_every_segment": dict(bad=[72, 5, 49, 17, 32, 64]),
+    # a corrupt row cuts its segment in two packed items; two of them two
+    # rows apart leave the row between to be verified alone, after the
+    # packed items of its flush
+    "corrupt_rows_split_a_segment": dict(torn=[20, 22, 40], bad=[21, 23, 41]),
+    # an unlinked row is not verified and leaves a hole in its segment
+    "an_unlinked_row_splits_a_segment": dict(rot=[37], bad=[36, 38]),
+    "corrupt_unlinked_and_bad_together": dict(
+        torn=[3, 5, 70], rot=[18, 52], bad=[4, 19, 51, 53, 71, 72]),
+    "a_gap": dict(drop=[30, 31], bad=[29, 32]),
+    "shorter_than_one_segment": dict(n=9, bad=[4]),
+    "exactly_one_segment": dict(n=16, bad=[16]),
+    "an_empty_store": dict(n=0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DAMAGE))
+def test_the_report_is_the_sequential_scans(tmp_path, case):
+    damage = _DAMAGE[case]
+    path = str(tmp_path / "d.db")
+    s = SqliteStore(path)
+    s.put_many([b for b in _beacons(damage.get("n", 72))
+                if b.round not in damage.get("drop", ())])
+    for r in damage.get("torn", ()):
+        faults.torn_write(path, r)
+    for r in damage.get("rot", ()):
+        faults.bit_rot(path, r)          # last byte: inside previous_sig
+    bad = damage.get("bad", ())
+    want = _sequential_report(s, _RecordingVerifier(bad), 16)
+    v = _RecordingVerifier(bad)
+    got = _scan(s, v, segment_rounds=16, read_batch=10).to_dict()
+    s.close()
+    got.pop("elapsed_s"), want.pop("elapsed_s")
+    assert got == want
+    assert sorted(got["corrupt"]) == sorted(damage.get("torn", ()))
+    assert sorted(got["unlinked"]) == sorted(damage.get("rot", ()))
+    assert sorted(got["bad_sigs"]) == sorted(
+        r for r in bad if r <= damage.get("n", 72))
+    # two flushes out at most: two dispatches where damage splits none
+    split = {"torn", "rot", "drop"} & set(damage)
+    assert not v.out and (split or v.most_out <= 2)
+
+
+@pytest.mark.parametrize("fault", [
+    dict(dispatch_raises=1), dict(dispatch_raises=33),
+    dict(dispatch_raises=65), dict(resolver_raises=1),
+    dict(resolver_raises=33), dict(resolver_raises=65)],
+    ids=lambda f: "-".join(f"{k}_at_{v}" for k, v in f.items()))
+def test_a_scan_that_fails_leaves_no_resolver_behind(tmp_path, fault):
+    from drand_tpu import tracing
+    s, _ = _chain_db(tmp_path, 72)
+    v = _RecordingVerifier(**fault)
+    tracing.RECORDER.clear()
+    with pytest.raises(RuntimeError, match="no (dispatch|verdicts) at"):
+        _scan(s, v, segment_rounds=16, read_batch=10)
+    s.close()
+    dispatched = [at for what, at in v.calls if what == "dispatch"]
+    resolved = [at for what, at in v.calls if what == "resolve"]
+    assert not v.out and resolved == dispatched     # each once, in order
+    spans = _scan_spans()
+    # every span of the scan is ended (the recorder holds ended spans
+    # only) and the failure is written on the root
+    (root,) = [sp for sp in spans if sp.name == "store.scan"]
+    assert root.status == "error"
+    assert sum(sp.name == "scan.flush" for sp in spans) \
+        == len(dispatched) + ("dispatch_raises" in fault)
+
+
+def test_a_split_flush_that_fails_midway_resolves_what_it_dispatched(
+        tmp_path):
+    # the second flush holds two packed items (a corrupt row between
+    # them); its second dispatch raises with its first already out
+    s, path = _chain_db(tmp_path, 72)
+    faults.torn_write(path, 24)
+    v = _RecordingVerifier(dispatch_raises=25)
+    with pytest.raises(RuntimeError, match="no dispatch at 25"):
+        _scan(s, v, segment_rounds=16, read_batch=10)
+    s.close()
+    assert v.calls == [("dispatch", 1), ("dispatch", 17),
+                       ("resolve", 1), ("resolve", 17)]
+    assert not v.out
+
+
+def test_a_cancelled_scan_leaves_no_resolver_behind(tmp_path):
+    import threading
+    s, _ = _chain_db(tmp_path, 72)
+    v = _RecordingVerifier()
+    waiting, go = threading.Event(), threading.Event()
+    dispatch = v.verify_packed_segment_async
+
+    def slow(packed, anchor):
+        resolver = dispatch(packed, anchor)
+
+        def resolve():
+            waiting.set()
+            go.wait(10)
+            return resolver()
+        return resolve
+    v.verify_packed_segment_async = slow
+
+    async def run():
+        task = asyncio.ensure_future(recovery.scan_store(
+            s, v, segment_rounds=16, read_batch=10))
+        await asyncio.to_thread(waiting.wait, 10)   # the first wait is on
+        task.cancel()
+        await asyncio.sleep(0.05)
+        assert not task.done()      # it waits its worker out
+        go.set()
+        with pytest.raises(asyncio.CancelledError):
+            await task
+    asyncio.run(run())
+    s.close()
+    assert v.calls == [("dispatch", 1), ("dispatch", 17),
+                       ("resolve", 1), ("resolve", 17)]
+    assert not v.out
+
+
+@pytest.mark.parametrize("rounds,flushes", [(64, 4), (72, 5), (9, 1)])
+def test_the_flush_says_what_was_in_flight(tmp_path, rounds, flushes):
+    from drand_tpu import tracing
+    s, _ = _chain_db(tmp_path, rounds)
+    tracing.RECORDER.clear()
+    assert _scan(s, _RecordingVerifier(), segment_rounds=16,
+                 read_batch=10).ok
+    s.close()
+    spans = _scan_spans()
+    in_flight = [sp.attrs["in_flight"] for sp in
+                 sorted((sp for sp in spans if sp.name == "scan.flush"),
+                        key=lambda sp: sp.start_mono)]
+    assert in_flight == [0] + [1] * (flushes - 1)
+    (root,) = [sp for sp in spans if sp.name == "store.scan"]
+    assert root.attrs["overlapped"] == flushes - 1
 
 
 def test_the_structural_scan_has_no_verify_spans(tmp_path):
