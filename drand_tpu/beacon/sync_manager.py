@@ -19,6 +19,7 @@ import contextlib
 import gc
 import os
 import random
+import threading
 import time
 from dataclasses import dataclass
 
@@ -53,6 +54,10 @@ HEDGE_PROBE_BOUND_S = 5.0  # real-time bound on the whole probe race
 PIPELINE_DEPTH = int(os.environ.get("DRAND_TPU_SYNC_PIPELINE_DEPTH", "2"))
 
 
+_pause_lock = threading.Lock()
+_paused = {"depth": 0, "was_on": False}     # under `_pause_lock`
+
+
 @contextlib.contextmanager
 def _collector_paused():
     """No cyclic collection while a segment's rows are alive.
@@ -63,16 +68,23 @@ def _collector_paused():
     two young collections, and in a process whose heap is small (a loaded
     program: 142,000 objects) that sets off two full collections a
     commit: about 50 ms each in which no Python thread runs, the event
-    loop included.  Where two chains' commits overlap, the one that
-    found the collector on turns it on again: the other's rest runs
-    with it, no worse than before."""
-    was_on = gc.isenabled()
-    gc.disable()
+    loop included.  The collector is one for the process and a daemon
+    commits for every chain it carries, each from a worker thread of its
+    own: where commits overlap, the first to begin notes what it found
+    and the last to end puts that back, so none of them runs its rest
+    with the collector on because another has ended."""
+    with _pause_lock:
+        if not _paused["depth"]:
+            _paused["was_on"] = gc.isenabled()
+            gc.disable()
+        _paused["depth"] += 1
     try:
         yield
     finally:
-        if was_on:
-            gc.enable()
+        with _pause_lock:
+            _paused["depth"] -= 1
+            if not _paused["depth"] and _paused["was_on"]:
+                gc.enable()
 
 
 def _observe_stage(stage: str, seconds: float) -> None:
@@ -231,7 +243,8 @@ class _CatchupPipeline:
         """Hand a flushed segment of `rounds` rounds to the pack stage;
         `cut` is what ended it (`_fetch_stage`)."""
         sp = tracing.begin_span(  # lint: disable=span-balance
-            "sync.segment", first_round=_item_span(items[0])[0],
+            "sync.segment", beacon_id=self.m.beacon_id,
+            first_round=_item_span(items[0])[0],
             rounds=rounds, cut=cut)  # ended where it leaves a stage
         await self._enqueue(self._q_verify,
                             self._Work(items, anchor_sig, sp), "verify")
@@ -385,7 +398,7 @@ class _CatchupPipeline:
 
 class SyncManager:
     def __init__(self, store, group, verifier, network, nodes, clock,
-                 insecure_store=None, resilience=None):
+                 insecure_store=None, resilience=None, beacon_id: str = ""):
         """store: decorated chain store; verifier: ChainVerifier;
         network: BeaconNetwork (sync_chain); nodes: peer identities;
         insecure_store: the UNDECORATED store (no append-only check) that
@@ -393,7 +406,9 @@ class SyncManager:
         reference passes the same pair (sync_manager.go:234-265);
         resilience: the daemon's Resilience hub — peer selection becomes
         breaker-aware and dispatch hedged when wired (None keeps the
-        plain shuffled iteration for unit-test fakes)."""
+        plain shuffled iteration for unit-test fakes); beacon_id: whose
+        chain this is, on `sync.catchup` and every span under it (a
+        daemon runs one manager a chain, all in one trace ring)."""
         self.store = store
         self.group = group
         self.verifier = verifier
@@ -402,6 +417,7 @@ class SyncManager:
         self.clock = clock
         self.insecure_store = insecure_store
         self.resilience = resilience
+        self.beacon_id = beacon_id
         # bounded: sync requests are cheap hints (the next sync reads
         # the live tip anyway), so a backlog past this is pure overload
         # — drop visibly rather than queue stale targets
@@ -520,7 +536,8 @@ class SyncManager:
         for every message, which is no span each."""
         before = dict(self.stats)
         with tracing.span(
-                "sync.catchup", from_round=req.from_round, up_to=req.up_to,
+                "sync.catchup", beacon_id=self.beacon_id,
+                from_round=req.from_round, up_to=req.up_to,
                 peer=getattr(peer, "address", "") or str(peer)) as root:
             try:
                 return await self._fetch_stage(peer, req, root)

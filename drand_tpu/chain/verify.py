@@ -68,7 +68,8 @@ class ChainVerifier:
         if self._lazy_verifier is None:
             import jax
             v = Verifier(self._pk_point, self.scheme.shape,
-                         single_host=self._verify_single)
+                         single_host=self._verify_single,
+                         beacon_id=self.beacon_id)
             if len(jax.devices()) > 1:
                 from drand_tpu.parallel import ShardedVerifier
                 v = ShardedVerifier(v)

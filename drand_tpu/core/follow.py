@@ -80,7 +80,7 @@ async def follow_chain(daemon, request):
     sm = SyncManager(store, _FollowGroup, verifier, network, nodes,
                      daemon.config.clock,
                      insecure_store=getattr(store, "insecure", None),
-                     resilience=daemon.resilience)
+                     resilience=daemon.resilience, beacon_id=beacon_id)
 
     from drand_tpu.chain.time import current_round
     target = request.up_to or current_round(

@@ -192,7 +192,7 @@ class BeaconProcess:
             self._store, group, self.verifier, self.network, others,
             self.config.clock,
             insecure_store=getattr(self._store, "insecure", None),
-            resilience=self.resilience)
+            resilience=self.resilience, beacon_id=self.beacon_id)
         self.handler.on_sync_needed = self.sync_manager.request_sync
 
     def _note_latency(self, round_: int, latency_ms: float) -> None:
@@ -408,7 +408,7 @@ class BeaconProcess:
             self._store, new_group, self.verifier, self.network, others,
             self.config.clock,
             insecure_store=getattr(self._store, "insecure", None),
-            resilience=self.resilience)
+            resilience=self.resilience, beacon_id=self.beacon_id)
         self.handler.on_sync_needed = self.sync_manager.request_sync
 
     def _note_group_transition(self) -> None:

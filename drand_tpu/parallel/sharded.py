@@ -110,6 +110,7 @@ class ShardedVerifier:
         import jax
 
         from drand_tpu import tracing
+        from drand_tpu.profiling import DISPATCH, record_dispatch
         from drand_tpu.verify import pad_rows
 
         rounds = np.asarray(rounds, dtype=np.uint64)
@@ -117,11 +118,13 @@ class ShardedVerifier:
         if n == 0 or self.n_dev == 1:
             return self.verifier.verify_batch_async(rounds, sigs, prev_sigs)
         v = self.verifier
+        beacon_id = getattr(v, "beacon_id", "")
         # `verify.dispatch` as on one device (`Verifier.verify_batch_async`)
         # with `bucket` the rows charged over the whole mesh; its child
         # `verify.shard_put` is the placement of every device's slice,
         # straight from the host's rows (no stop on the first device)
-        with tracing.span("verify.dispatch", n=n, devices=self.n_dev) as sp:
+        with tracing.span("verify.dispatch", beacon_id=beacon_id, n=n,
+                          devices=self.n_dev) as sp:
             m = self.rows_charged(n)
             msgs, sigs = pad_rows(v.messages(rounds, prev_sigs), sigs, m)
             msgs = np.ascontiguousarray(msgs, dtype=np.uint8)
@@ -136,12 +139,14 @@ class ShardedVerifier:
                     self._pk_placed = jax.device_put(v._pk, self._named())
                 rows = self._named(self.axis, None)
                 placed = jax.device_put((msgs, sigs), rows)
+            flight, in_flight, behind_other = DISPATCH.enqueue(v)
             ok = kernel(*placed, self._pk_placed)
             dispatch_s = time.perf_counter() - t1
             sp.set(bucket=m, pad_rows=m - n, per_dev=m // self.n_dev,
                    prepare_s=t0 - sp.start_mono, enqueue_s=dispatch_s,
                    msg_bytes=msgs.shape[1],
-                   h2d_bytes=msgs.nbytes + sigs.nbytes)
+                   h2d_bytes=msgs.nbytes + sigs.nbytes, dispatches=1,
+                   in_flight=in_flight, behind_other=behind_other)
         done = [False]
 
         def resolve():
@@ -155,11 +160,12 @@ class ShardedVerifier:
             if not done[0]:
                 done[0] = True
                 t3 = time.perf_counter()
+                DISPATCH.resolved(flight)
                 resolved = tracing.record_span("verify.resolve", t1, t3,
-                                               n=n, bucket=m)
+                                               beacon_id=beacon_id, n=n,
+                                               bucket=m)
                 tracing.record_span("verify.gather", t2, t3, parent=resolved,
                                     devices=self.n_dev)
-                from drand_tpu.profiling import record_dispatch
                 record_dispatch("sharded", n, m, dispatch_s + (t3 - t1),
                                 devices=self.n_dev, per_dev=m // self.n_dev)
             return out

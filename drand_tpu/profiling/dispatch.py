@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import threading
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -71,8 +72,22 @@ class DispatchRecord:
         }
 
 
+class Enqueued:
+    """One device dispatch between its enqueue and its resolve, held by
+    its resolver alone: a resolver dropped unresolved (a catch-up that
+    failed discards the segments behind the bad one) takes it along."""
+
+    __slots__ = ("owner", "__weakref__")
+
+    def __init__(self, owner):
+        self.owner = owner
+
+
 class DispatchRecorder:
-    """Bounded ring of DispatchRecords plus per-seam running totals.
+    """Bounded ring of DispatchRecords plus per-seam running totals, and
+    the one process-wide count of device dispatches enqueued and not yet
+    resolved (`enqueue`/`resolved`): every verifier of a daemon feeds one
+    device queue, and none of them sees the others' programs on it.
 
     Thread-safe: dispatches land from the event loop, the crypto worker
     thread, and batched-verify resolvers alike."""
@@ -80,6 +95,7 @@ class DispatchRecorder:
     def __init__(self, maxlen: int = 2048):
         self._ring: deque[DispatchRecord] = deque(maxlen=maxlen)
         self._lock = threading.Lock()
+        self._in_flight: weakref.WeakSet[Enqueued] = weakref.WeakSet()
         # seam -> running totals since process start (the ring forgets;
         # the totals are what the watchdog and perf deltas read)
         self._totals: dict[str, dict] = {}
@@ -111,6 +127,24 @@ class DispatchRecorder:
             pass    # metrics must never fail a dispatch
         return rec
 
+    def enqueue(self, owner) -> tuple[Enqueued, int, int]:
+        """Count a dispatch that `owner` (a verifier) is about to put on
+        the device queue: (its token, `in_flight`, `behind_other`).
+        `in_flight` is how many dispatches of the whole process were
+        enqueued before it and are not resolved yet, `behind_other` 1
+        where at least one of those is another owner's, else 0.  The
+        resolver keeps the token and gives it back to `resolved`."""
+        token = Enqueued(owner)
+        with self._lock:
+            ahead = list(self._in_flight)
+            self._in_flight.add(token)
+        return token, len(ahead), int(any(t.owner is not owner
+                                          for t in ahead))
+
+    def resolved(self, token: Enqueued) -> None:
+        with self._lock:
+            self._in_flight.discard(token)
+
     def __len__(self) -> int:
         with self._lock:
             return len(self._ring)
@@ -140,7 +174,10 @@ class DispatchRecorder:
         return totals
 
     def snapshot(self, limit: int = 50) -> dict:
+        with self._lock:
+            in_flight = len(self._in_flight)
         return {
+            "in_flight": in_flight,
             "seams": self.seam_summary(),
             "recent": [r.to_dict() for r in self.records(limit=limit)][::-1],
         }
@@ -149,6 +186,7 @@ class DispatchRecorder:
         with self._lock:
             self._ring.clear()
             self._totals.clear()
+            self._in_flight.clear()
 
 
 def _wall_stamp() -> float:

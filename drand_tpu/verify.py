@@ -30,7 +30,7 @@ from drand_tpu.crypto.bls12381.constants import DST_G1, DST_G2
 from drand_tpu.ops import DIGEST
 from drand_tpu.ops import bls as BLS
 from drand_tpu.ops.sha256 import sha256
-from drand_tpu.profiling import record_dispatch
+from drand_tpu.profiling import DISPATCH, record_dispatch
 
 # Batch buckets: requests are padded up to the nearest size so only a few
 # XLA programs are ever compiled per scheme.  Overridable for tests/small
@@ -86,13 +86,18 @@ SHAPE_UNCHAINED_G1 = SchemeShape(chained=False, sig_on_g1=True, dst=DST_G1)
 class Verifier:
     """Batched beacon verifier for one chain (public key + scheme shape)."""
 
-    def __init__(self, public_key, shape: SchemeShape, single_host=None):
+    beacon_id = ""      # label of the spans; `ChainVerifier` hands its own
+
+    def __init__(self, public_key, shape: SchemeShape, single_host=None,
+                 beacon_id: str = ""):
         """public_key: golden-model Jacobian point — G1 for G2-signature
         schemes, G2 for the short-sig scheme.  `single_host(round, sig,
         prev_sig) -> (ok, tier)` is the owner's check of ONE round off the
         device (`ChainVerifier` hands its live path's: native when built,
-        golden model else); without it the golden model checks."""
+        golden model else); without it the golden model checks.
+        `beacon_id` labels the spans, as `ChainVerifier`'s does."""
         self.shape = shape
+        self.beacon_id = beacon_id
         self._pk_golden = public_key
         self._single_host = single_host
         if shape.sig_on_g1:
@@ -291,18 +296,26 @@ class Verifier:
         # lazily is its child `verifier.build`.  `pad_rows` over `bucket`
         # is the share of the device's work that is padding; `msg_bytes`
         # is one row's message, `h2d_bytes` what the dispatch sends.
-        with tracing.span("verify.dispatch", n=n) as sp:
+        # `in_flight` and `behind_other` say what the device queue held
+        # at this enqueue (`DispatchRecorder.enqueue`: every verifier of
+        # the process feeds one queue), beside `dispatches: 1`, so that
+        # sums over spans give the share of dispatches that waited behind
+        # another verifier's program.
+        with tracing.span("verify.dispatch", beacon_id=self.beacon_id,
+                          n=n) as sp:
             m = self.rows_charged(n)
             msgs, sigs = pad_rows(self.messages(rounds, prev_sigs), sigs, m)
             t0 = time.perf_counter()
             kernel = self._kernel(m)
             t1 = time.perf_counter()
+            flight, in_flight, behind_other = DISPATCH.enqueue(self)
             ok = kernel(jnp.asarray(msgs, dtype=jnp.uint8),
                         jnp.asarray(sigs, dtype=jnp.uint8), self._pk)
             dispatch_s = time.perf_counter() - t1
             sp.set(bucket=m, pad_rows=m - n, prepare_s=t0 - sp.start_mono,
                    enqueue_s=dispatch_s, msg_bytes=msgs.shape[1],
-                   h2d_bytes=msgs.nbytes + sigs.nbytes)
+                   h2d_bytes=msgs.nbytes + sigs.nbytes, dispatches=1,
+                   in_flight=in_flight, behind_other=behind_other)
         done = [False]    # split dispatch/resolve: record exactly once
 
         def resolve():
@@ -311,7 +324,9 @@ class Verifier:
             if not done[0]:
                 done[0] = True
                 t2 = time.perf_counter()
-                tracing.record_span("verify.resolve", t1, t2, n=n, bucket=m)
+                DISPATCH.resolved(flight)
+                tracing.record_span("verify.resolve", t1, t2,
+                                    beacon_id=self.beacon_id, n=n, bucket=m)
                 # host wall = async dispatch + the blocking resolve
                 # (queue-wait is the gap the CALLER leaves before
                 # resolving — that overlap is the pipelining win, not
@@ -343,6 +358,7 @@ class Verifier:
                 start_round + 1, sigs[1:], sigs[0]) if b > 1 else \
                 (lambda: np.zeros(0, dtype=bool))
             with tracing.span("verify.genesis_link",
+                              beacon_id=self.beacon_id,
                               round_=int(start_round)) as sp:
                 first_ok, tier = self._verify_single_host(
                     start_round, bytes(sigs[0]), bytes(anchor_prev_sig))

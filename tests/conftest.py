@@ -42,14 +42,18 @@ def pytest_addoption(parser):
                      help="run the slow device-kernel KAT suite")
 
 
-# The one case of the benchmark's own tests known to fail: at seed 7 all
-# three planted faults of the chained cell fall into `previous_sig`, which
-# the packed wire never carries, so the stub's faulted catch-up succeeds.
-# The repair is a `benchmark` PR's (ROADMAP S12 (c)).
-_KNOWN_TO_FAIL = (
+# The one case of the benchmark's own tests known to fail, in both cells
+# of the chained configuration: at seed 7 all three planted faults fall
+# into `previous_sig`, which the packed wire never carries, so the stub's
+# faulted catch-up succeeds, and which the scan's link walk finds without
+# asking a verifier, so the stub's faulted scan reports what it should;
+# the stub is not correct by `verdicts.*` alone, and the case asks for a
+# `faulted.*`.  The repair is a `benchmark` PR's (ROADMAP S12 (c)).
+_KNOWN_TO_FAIL = tuple(
     "test_benchmark_rehearsal.py::"
-    "test_the_stub_verifier_comes_out_not_correct"
-    "[catchup-deep.default-chained]")
+    f"test_the_stub_verifier_comes_out_not_correct[{cell}]"
+    for cell in ("catchup-deep.default-chained",
+                 "restart-scan.default-chained"))
 
 
 def pytest_collection_modifyitems(config, items):
