@@ -126,18 +126,18 @@ def _const_g1_affine(pt_jac):
     return (jnp.asarray(FP.to_mont_host(aff[0])), jnp.asarray(FP.to_mont_host(aff[1])))
 
 
-def _const_g2_affine(pt_jac):
+def const_g2_lines(pk_jac):
+    """Golden G2 Jacobian public key -> the G1-signature program's third
+    argument: the lines of the Miller ladder for the check's two fixed G2
+    points, the generator and the key (`pairing.fixed_q_table`, pairs in
+    that order), computed once a key with Python integers."""
     from drand_tpu.crypto.bls12381 import curve as GC
-    aff = GC.g2_affine(pt_jac)
-    return (T.fp2_const(aff[0]), T.fp2_const(aff[1]))
+    return jnp.asarray(DP.fixed_q_table(
+        [GC.g2_affine(GC.G2_GEN), GC.g2_affine(pk_jac)]))
 
 
 def _bcast_fp_pair(pair, shape):
     return tuple(jnp.broadcast_to(c, shape + (N_LIMBS,)).astype(jnp.int32) for c in pair)
-
-
-def _bcast_fp2_pair(pair, shape):
-    return tuple(T.fp2_broadcast(c, shape) for c in pair)
 
 
 def verify_g2_sigs(msgs: jnp.ndarray, sig_bytes: jnp.ndarray, pk_aff, dst: bytes,
@@ -171,9 +171,15 @@ def verify_g2_sigs(msgs: jnp.ndarray, sig_bytes: jnp.ndarray, pk_aff, dst: bytes
     return ok & s_valid & ~s_inf & in_sub
 
 
-def verify_g1_sigs(msgs: jnp.ndarray, sig_bytes: jnp.ndarray, pk_g2_aff, dst: bytes):
+def verify_g1_sigs(msgs: jnp.ndarray, sig_bytes: jnp.ndarray, pk_lines, dst: bytes):
     """Batched BLS verify, signatures on G1, public key on G2 (short-sig
     scheme, BASELINE.md config 4).  Checks e(-sigma, g2) * e(H(m), pk) == 1.
+
+    Both G2 arguments are the same in every row, so the program takes
+    neither: `pk_lines` is `const_g2_lines(pk)`, int32 [68, 2, 6, 32], the
+    Miller loop's lines for the generator and the key, and the loop
+    (`pairing.miller_loop_fixed_q`) carries no G2 point.  A run-time
+    value: one program serves every key.
     """
     shape = msgs.shape[:-1]
     with jax.named_scope(SIG_DECODE):
@@ -185,14 +191,8 @@ def verify_g1_sigs(msgs: jnp.ndarray, sig_bytes: jnp.ndarray, pk_g2_aff, dst: by
         h_jac = DH.hash_to_g1(msgs, dst)
         (hx, hy), h_inf = DC.point_to_affine(h_jac, DC.FpOps)
 
-    from drand_tpu.crypto.bls12381 import curve as GC
-    g2_aff = _const_g2_affine(GC.G2_GEN)
-    q1 = _bcast_fp2_pair(g2_aff, shape)
-    q2 = _bcast_fp2_pair(pk_g2_aff, shape) if pk_g2_aff[0][0].ndim == 1 else pk_g2_aff
-    neg_sig = (sx, T.fp_neg(sy))
-    ok = DP.pairing_check_pairs(
-        [(neg_sig, q1), ((hx, hy), q2)],
-        active=[~s_inf, ~h_inf])
+    ok = DP.pairing_check_fixed_q(
+        [(sx, T.fp_neg(sy)), (hx, hy)], pk_lines, active=[~s_inf, ~h_inf])
     return ok & s_valid & ~s_inf & in_sub
 
 

@@ -26,6 +26,19 @@ line steps):
   - Lines are sparse flat elements: 3 Fp2 coefficients at w-powers
     {0, 2, 3}, i.e. 6 of 12 flat slots, so a line multiply is a 12x6
     product stack.
+
+Two Miller loops, one a kind of G2 argument, sharing the squaring, the
+line multiply and the ladder's driver, and no branch:
+  - `miller_loop_pairs` carries a Jacobian G2 point a row and pair
+    through the ladder.  The G2-signature programs (`bls.verify_g2_sigs`,
+    the partial-signature builders) need it: there Q is the signature and
+    H(m), another in every row.
+  - `miller_loop_fixed_q` holds no G2 point.  In the G1-signature program
+    (`bls.verify_g1_sigs`) both Qs, the generator and the chain's key,
+    are the whole batch's, and T = [k]Q and the line through it depend on
+    Q and the step alone: the lines come from `fixed_q_table`, computed
+    once a key on the host, and a row's P enters by four Fp products a
+    pair and step.
 """
 
 from __future__ import annotations
@@ -34,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from drand_tpu.crypto.bls12381 import fp as G
 from drand_tpu.crypto.bls12381.constants import X as _BLS_X
 from drand_tpu.ops import FINAL_EXP, MILLER
 from drand_tpu.ops import flat12 as F
@@ -309,6 +323,130 @@ def _miller_loop_pairs_merged(pf, pairs, active, shape, _keep_tiled=False):
 
 
 # ---------------------------------------------------------------------------
+# Fixed-Q Miller loop: where a pair's G2 argument is the whole batch's, its
+# lines come from a table computed once a key, and the loop holds no G2 point
+# ---------------------------------------------------------------------------
+
+# table rows: one a doubling step and one more on each set bit, in the
+# ladder's order
+LINE_STEPS = len(_X_BITS) - 1 + _X_BITS[1:].count("1")
+
+
+def _host_dbl(Tj):
+    """`_dbl_step` on Python integers, before the scaling by P:
+    (2T, (a, nb3, cc2)), so that b = nb3 * xp and c = cc2 * yp."""
+    X, Y, Z = Tj
+    XX, YY, ZZ, YZ = G.fp2_sqr(X), G.fp2_sqr(Y), G.fp2_sqr(Z), G.fp2_mul(Y, Z)
+    xyy = G.fp2_add(X, YY)
+    E = G.fp2_mul_fp(XX, 3)
+    X3c, YZ3, XXZZ = G.fp2_mul(XX, X), G.fp2_mul(YZ, ZZ), G.fp2_mul(XX, ZZ)
+    C, S2, F_ = G.fp2_sqr(YY), G.fp2_sqr(xyy), G.fp2_sqr(E)
+    a = G.fp2_sub(G.fp2_mul_fp(X3c, 3), G.fp2_mul_fp(YY, 2))
+    nb3 = G.fp2_neg(G.fp2_mul_fp(XXZZ, 3))
+    cc2 = G.fp2_mul_fp(YZ3, 2)
+    D = G.fp2_mul_fp(G.fp2_sub(S2, G.fp2_add(XX, C)), 2)
+    X2 = G.fp2_sub(F_, G.fp2_mul_fp(D, 2))
+    Y2 = G.fp2_sub(G.fp2_mul(E, G.fp2_sub(D, X2)), G.fp2_mul_fp(C, 8))
+    return (X2, Y2, G.fp2_mul_fp(YZ, 2)), (a, nb3, cc2)
+
+
+def _host_add(Tj, Q):
+    """`_add_step` likewise: (T + Q, (a, -r, 2HZ))."""
+    X, Y, Z = Tj
+    xq, yq = Q
+    ZZ = G.fp2_sqr(Z)
+    H = G.fp2_sub(G.fp2_mul(xq, ZZ), X)
+    r = G.fp2_mul_fp(G.fp2_sub(G.fp2_mul(G.fp2_mul(yq, Z), ZZ), Y), 2)
+    HH, HZ2 = G.fp2_sqr(H), G.fp2_mul_fp(G.fp2_mul(H, Z), 2)
+    I = G.fp2_mul_fp(HH, 4)
+    J, V = G.fp2_mul(H, I), G.fp2_mul(X, I)
+    X3 = G.fp2_sub(G.fp2_sub(G.fp2_sqr(r), J), G.fp2_mul_fp(V, 2))
+    Y3 = G.fp2_sub(G.fp2_mul(r, G.fp2_sub(V, X3)),
+                   G.fp2_mul_fp(G.fp2_mul(Y, J), 2))
+    Z3 = G.fp2_sub(G.fp2_sqr(G.fp2_add(Z, H)), G.fp2_add(ZZ, HH))
+    a = G.fp2_sub(G.fp2_mul(r, xq), G.fp2_mul(HZ2, yq))
+    return (X3, Y3, Z3), (a, G.fp2_neg(r), HZ2)
+
+
+def fixed_q_table(qs) -> np.ndarray:
+    """The lines of the ladder over |x| for K fixed affine G2 points
+    (golden-model Fp2 pairs): int32 [LINE_STEPS, K, 6, 32].
+
+    A row is one step's triple a pair, before its scaling by P (`a`, `nb3`,
+    `cc2` of `_dbl_step`; `a`, `-r`, `2HZ` of `_add_step`), by the same
+    denominator-cleared Jacobian formulas, in the sparse flat slots of
+    `line_to_flat` (`x - y` of the three coefficients, then `y`) and in
+    Montgomery limbs.  Scaling by an Fp value commutes with `x - y`, and
+    a canonical residue has one limb form, so the line a row of the batch
+    gets from this table is the per-row path's to the limb."""
+    rows = []
+    Ts = [(q[0], q[1], G.FP2_ONE) for q in qs]
+    for bit in _X_BITS[1:]:
+        steps = [[_host_dbl(t) for t in Ts]]
+        if bit == "1":
+            steps.append([_host_add(t, q) for (t, _), q in zip(steps[0], qs)])
+        for step in steps:
+            Ts = [t for t, _ in step]
+            rows.append([[(c[0] - c[1]) % G.P for c in line]
+                         + [c[1] for c in line] for _, line in step])
+    assert len(rows) == LINE_STEPS
+    return np.stack([np.stack([np.stack([FP.to_mont_host(v) for v in pair])
+                               for pair in row]) for row in rows])
+
+
+_LINE_ONE_FLAT = F.FLAT_ONE[:6]       # line_to_flat(line_one): (1, 0, 0)
+
+
+def _line_scaler(ps, active, shape):
+    """What turns a table row [K, 6, 32] into that step's K sparse flat
+    lines for the batch: the `b` and `c` slots times the row's `xp` and
+    `yp` (4 Fp products a pair, all K pairs' in one call), an inactive
+    row's line the neutral one."""
+    pf = FP._pallas()
+    if pf is not None:
+        return pf.line_scaler(ps, active, shape, _LINE_ONE_FLAT)
+    ps = [[jnp.broadcast_to(c, shape + c.shape[-1:]) for c in p] for p in ps]
+
+    def lines(row):
+        sc = FP_products([(row[k, s], ps[k][j]) for k in range(len(ps))
+                          for s, j in ((1, 0), (2, 1), (4, 0), (5, 1))])
+        out = []
+        for k, mask in enumerate(active):
+            b_lo, c_lo, b_y, c_y = sc[4 * k:4 * k + 4]
+            a_lo, a_y = (jnp.broadcast_to(row[k, s], b_lo.shape)
+                         for s in (0, 3))
+            line = jnp.stack([a_lo, b_lo, c_lo, a_y, b_y, c_y], axis=-2)
+            if mask is not None:
+                line = F.flat_select(mask, line, _LINE_ONE_FLAT)
+            out.append(line)
+        return out
+    return lines
+
+
+def miller_loop_fixed_q(ps, table, active=None, _keep_tiled=False):
+    """`miller_loop_pairs` for K pairs whose G2 arguments are the whole
+    batch's: `ps` the K affine G1 points (xp, yp), `table` their Qs'
+    `fixed_q_table` (a run-time value: one program serves every key),
+    `active` as there.  The state is f alone; a step squares f (on a
+    doubling), scales the step's table row by the rows' P and multiplies
+    the K lines in.  The same f as `miller_loop_pairs`, to the limb."""
+    shape = ps[0][0].shape[:-1]
+    lines = _line_scaler(ps, active or [None] * len(ps), shape)
+
+    def mul_lines(f, i):
+        for line in lines(jax.lax.dynamic_index_in_dim(table, i, 0, False)):
+            f = F.flat_mul(f, line, LINE_IDX)
+        return f, i + 1
+
+    f = F.flat_tile(F.flat_broadcast(F.FLAT_ONE, shape))
+    f, _ = segmented_ladder(_X_SEGMENTS, (f, jnp.int32(0)),
+                            lambda c: mul_lines(F.flat_sqr(c[0]), c[1]),
+                            lambda c: mul_lines(*c))
+    f = F.flat_conj(f)                    # x < 0 (packed on Pallas)
+    return f if _keep_tiled else F.flat_untile(f)
+
+
+# ---------------------------------------------------------------------------
 # Final exponentiation (flat)
 # ---------------------------------------------------------------------------
 
@@ -371,5 +509,16 @@ def pairing_check_pairs(pairs, active=None):
     with jax.named_scope(MILLER):
         f = miller_loop_pairs(pairs, active,
                               _keep_tiled=FP._pallas() is not None)
+    with jax.named_scope(FINAL_EXP):
+        return F.flat_is_one(final_exp(f))
+
+
+def pairing_check_fixed_q(ps, table, active=None):
+    """`pairing_check_pairs` where every pair's Q is the whole batch's:
+    prod of e(P_i, Q_i) == 1 with the Qs' lines from `table`
+    (`miller_loop_fixed_q`), one final exp."""
+    with jax.named_scope(MILLER):
+        f = miller_loop_fixed_q(ps, table, active,
+                                _keep_tiled=FP._pallas() is not None)
     with jax.named_scope(FINAL_EXP):
         return F.flat_is_one(final_exp(f))

@@ -1214,6 +1214,47 @@ class PallasField:
         return TileForm(out, shape, n).unwrap(
             ).reshape(shape + (12, N_LIMBS))
 
+    def line_scaler(self, ps, active, shape, line_one):
+        """Tile-resident `pairing._line_scaler`: the rows' P coordinates
+        and masks cross into tile layout once, here; the returned
+        function turns a step's table row [K, 6, 32] into the K masked
+        sparse lines as TileForms in `flat_mul`'s packed layout.  The
+        step's 4K Fp products are ONE launch of the `mont_mul` kernel,
+        its operands joined along the tile (grid) axis; a table entry is
+        the same for every row, so it is broadcast straight into tile
+        layout, and no boundary is crossed inside the ladder."""
+        K = len(ps)
+        pt = [[self.tile(jnp.broadcast_to(c, shape + (N_LIMBS,)))
+               for c in p] for p in ps]
+        ref = pt[0][0]
+        nt = ref.tiles.shape[0]
+        rhs = jnp.concatenate([c.tiles for xp, yp in pt
+                               for c in (xp, yp, xp, yp)], 0)
+        masks = [m if m is None else self.mask_wrap(m, shape)[:, None]
+                 for m in active]
+
+        def lift(c):
+            return jnp.broadcast_to(c.reshape(1, -1, 1, 1),
+                                    (nt, c.size, *_ROW))
+        one = lift(line_one)
+
+        def lines(row):
+            sc = self._call(
+                self._mont_mul_kernel, N_LIMBS,
+                jnp.concatenate([lift(row[k, s]) for k in range(K)
+                                 for s in (1, 2, 4, 5)], 0), rhs)
+            sc = sc.reshape(K, 4, nt, N_LIMBS, *_ROW)
+            out = []
+            for k, mask in enumerate(masks):
+                b_lo, c_lo, b_y, c_y = sc[k]
+                line = jnp.concatenate([lift(row[k, 0]), b_lo, c_lo,
+                                        lift(row[k, 3]), b_y, c_y], 1)
+                if mask is not None:
+                    line = jnp.where(mask, line, one)
+                out.append(TileForm(line, ref.shape, ref.b))
+            return out
+        return lines
+
     # -- fused Fermat-chain step: 4 squarings + one table multiply ---------
     #
     # pow_const's windowed scan body ran 5 kernel launches per step (4

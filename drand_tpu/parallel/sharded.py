@@ -35,7 +35,7 @@ class ShardedVerifier:
         self.axis = axis
         self.mesh = Mesh(np.array(devs), (axis,))
         self._kernels = {}         # rows over the mesh -> compiled program
-        self._pk_placed = None     # the key, on every device
+        self._pk_placed = None     # `verifier._pk`, on every device
 
     def _named(self, *spec):
         from jax.sharding import NamedSharding, PartitionSpec as P

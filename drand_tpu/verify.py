@@ -100,8 +100,12 @@ class Verifier:
         self.beacon_id = beacon_id
         self._pk_golden = public_key
         self._single_host = single_host
+        # the program's third, run-time argument: the key as affine limbs,
+        # or on the short-signature scheme, where both G2 arguments of the
+        # check are the whole batch's, their Miller lines (once a key,
+        # a few milliseconds of Python integers)
         if shape.sig_on_g1:
-            self._pk = BLS._const_g2_affine(public_key)
+            self._pk = BLS.const_g2_lines(public_key)
         else:
             self._pk = BLS._const_g1_affine(public_key)
         self._kernels = {}
@@ -120,15 +124,16 @@ class Verifier:
     def _aot_name(self, n: int) -> str:
         import hashlib
 
-        # The public key is a runtime argument, not a baked constant: one
-        # executable per (scheme shape, batch) serves every chain.
+        # The public key (G1 signatures: its table of Miller lines) is a
+        # runtime argument, not a baked constant: one executable per
+        # (scheme shape, batch) serves every chain.
         kind = "g1sig" if self.shape.sig_on_g1 else "g2sig"
         link = "ch" if self.shape.chained else "un"
         dst_h = hashlib.sha256(self.shape.dst).hexdigest()[:8]
         return f"verify-{kind}-{link}-{dst_h}-anykey-b{n}"
 
     def _pk_struct(self):
-        """ShapeDtypeStruct pytree matching self._pk (affine limb arrays)."""
+        """ShapeDtypeStruct pytree matching self._pk (limb arrays)."""
         return jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self._pk)
 
@@ -137,7 +142,8 @@ class Verifier:
         return self.shape.sig_len + 8 if self.shape.chained else 8
 
     def _run_fn(self, compact: bool | None = None):
-        """The pure (msgs, sigs, pk) -> bool[B] verify body.  Exposed so
+        """The pure (msgs, sigs, pk) -> bool[B] verify body (`pk` is
+        `self._pk`: the key's limbs or its table of lines).  Exposed so
         the multi-device path (parallel/sharded.py) compiles the SAME
         body with mesh shardings instead of duplicating it.
 
@@ -221,7 +227,11 @@ class Verifier:
         the executable is compiled from that form, so a checkout's first
         run stores in JAX's persistent cache what its later runs ask for.
 
-        The record: the tracing mode; `trace_s` to the exported form in
+        The record, and the `verifier.build` span with it: `miller_lines`,
+        which Miller loop the program holds (`table`, with `line_steps`
+        rows, where both G2 arguments are the batch's and their lines
+        come with the key; `per_row` else).  The record alone: the
+        tracing mode; `trace_s` to the exported form in
         hand (of which `load_s` reading the file, with `blob_bytes`, or
         `load_error` where a file was there and could not be used),
         `lower_s` and `compile_s` (host clock; a persistent-cache hit
@@ -236,9 +246,16 @@ class Verifier:
         # a span of its own and one a phase, from the same clock reads as
         # the record: a bucket built lazily under an open `sync.segment`
         # or `scan.flush` shows there as what stalled it
-        devices = {} if mesh is None else {"devices": mesh.n_dev}
+        what = {} if mesh is None else {"devices": mesh.n_dev}
+        # which Miller loop the program holds (ops/pairing.py): the lines
+        # of both G2 arguments from the key's table, or a G2 point a row
+        if self.shape.sig_on_g1:
+            from drand_tpu.ops.pairing import LINE_STEPS
+            what.update(miller_lines="table", line_steps=LINE_STEPS)
+        else:
+            what["miller_lines"] = "per_row"
         with tracing.span("verifier.build", bucket=n, program=name,
-                          **devices) as sp:
+                          **what) as sp:
             t0 = sp.start_mono
             exported, found = aot.load_exported(name, compact, body)
             tl = time.perf_counter()
@@ -263,7 +280,7 @@ class Verifier:
             t3 = time.perf_counter()
             tracing.record_span("build.compile", t2, t3)
             sp.set(source=source, load_s=tl - t0, **found)
-        return {"program": name, "bucket": n, **devices,
+        return {"program": name, "bucket": n, **what,
                 "tracing": "compact" if compact else "static",
                 "source": source, "load_s": tl - t0, **found,
                 "trace_s": t1 - t0, "lower_s": t2 - t1,

@@ -31,7 +31,8 @@ N = 8
 # `chip_smoke.py:_build` read of the record
 READ_BY_THE_BENCHMARK = {"program", "bucket", "tracing", "trace_s",
                          "lower_s", "compile_s", "lowered"}
-NEW_IN_THE_RECORD = {"source", "load_s", "blob_bytes"}
+NEW_IN_THE_RECORD = {"source", "load_s", "blob_bytes",
+                     "miller_lines"}        # ISSUE 37: which Miller loop
 
 
 class StandIn(V.Verifier):
@@ -111,6 +112,9 @@ def test_the_record_and_the_spans_keep_their_shape(cache, source):
     build = spans["verifier.build"]
     assert build["attrs"]["source"] == source
     assert build["attrs"]["blob_bytes"] == rec["blob_bytes"]
+    # a G2-signature shape: a G2 point a row, no table of lines
+    assert build["attrs"]["miller_lines"] == rec["miller_lines"] == "per_row"
+    assert "line_steps" not in build["attrs"]
     phases = {"build.load", "build.lower", "build.compile"} | (
         {"build.trace"} if source == "traced" else set())
     assert {n for n in spans if n.startswith("build.")} == phases

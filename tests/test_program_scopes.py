@@ -21,6 +21,7 @@ import drand_tpu.verify as V
 from drand_tpu import ops
 from drand_tpu.crypto.bls12381 import curve as GC
 from drand_tpu.crypto.bls12381.constants import P
+from drand_tpu.ops import pairing as DP
 from drand_tpu.ops import pallas_field as PFm
 
 _LOC = re.compile(r'= loc\("(jit\(run\)[^"]*)"')
@@ -33,14 +34,27 @@ def test_one_vocabulary_for_both_schemes():
             ops.FINAL_EXP) == ops.STAGES
 
 
-@pytest.mark.parametrize("shape,pk", [
-    (V.SHAPE_UNCHAINED_G1, GC.G2_GEN),      # quicknet: signatures on G1
-    (V.SHAPE_UNCHAINED, GC.G1_GEN),         # signatures on G2
+LOOPS = ("miller_loop_fixed_q", "miller_loop_pairs")
+
+
+@pytest.mark.parametrize("shape,pk,loop", [
+    # quicknet: signatures on G1, both G2 arguments the whole batch's
+    (V.SHAPE_UNCHAINED_G1, GC.G2_GEN, "miller_loop_fixed_q"),
+    (V.SHAPE_UNCHAINED, GC.G1_GEN, "miller_loop_pairs"),  # signatures on G2
 ], ids=["g1sig", "g2sig"])
-def test_every_stage_scopes_the_lowered_verify_program(shape, pk):
+def test_every_stage_scopes_the_lowered_verify_program(shape, pk, loop,
+                                                       monkeypatch):
+    traced = []
+    for name in LOOPS:
+        def spy(*args, _name=name, _fn=getattr(DP, name), **kw):
+            traced.append(_name)
+            return _fn(*args, **kw)
+        monkeypatch.setattr(DP, name, spy)
     v = V.Verifier(pk, shape)
     text = jax.jit(v._run_fn(compact=True)).trace(
         *v._arg_structs(8)).lower().as_text(debug_info=True)
+    # the scheme's structure selects the Miller loop (ISSUE 37), once
+    assert traced == [loop]
     paths = _LOC.findall(text)
     assert len(paths) > 1000
     by_stage = {s: 0 for s in ops.STAGES}
@@ -60,6 +74,39 @@ def test_every_stage_scopes_the_lowered_verify_program(shape, pk):
     # has a stage of its own since ISSUE 29)
     assert len(unscoped) < 0.005 * len(paths), unscoped[:5]
     assert not any("sha256" in p or "digest" in p for p in unscoped)
+
+
+@pytest.mark.parametrize("loop,kernels", [
+    ("miller_loop_fixed_q", {"flat_sqr", "mont_mul", "flat_mul",
+                             "flat_conj"}),
+    ("miller_loop_pairs", {"flat_sqr", "g2_dbl_line", "g2_add_line",
+                           "flat_mul", "flat_conj"}),
+], ids=["g1sig", "g2sig"])
+def test_each_programs_miller_stage_names_its_kernels(loop, kernels):
+    """The Miller stage as the TPU traces it (compact ladders), lowered
+    for a TPU across platforms: the G1-signature program's loop holds no
+    `g2_dbl_line` and no `g2_add_line` (its lines come with the key), a
+    G2-signature program's holds both."""
+    from unittest import mock
+
+    from drand_tpu.ops.field import compact_scope
+    fp = jax.ShapeDtypeStruct((PFm.TILE, 32), jnp.int32)
+    if loop == "miller_loop_fixed_q":
+        args = ([(fp, fp), (fp, fp)],
+                jax.ShapeDtypeStruct((DP.LINE_STEPS, 2, 6, 32), jnp.int32))
+    else:
+        args = ([((fp, fp), ((fp, fp), (fp, fp)))] * 2,)
+
+    def run(*a):
+        with compact_scope(True), jax.named_scope(ops.MILLER):
+            return getattr(DP, loop)(*a)
+
+    with mock.patch.object(PFm, "use_pallas", return_value=True):
+        text = jax.jit(run).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert set(re.findall(r'kernel_name = "([^"]*)"', text)) == kernels
+    sites = re.findall(r'loc\("jit\(run\)/([^"]*)/jit\(wrapped\)"', text)
+    assert sites and all(s.split("/")[0] == ops.MILLER for s in sites)
 
 
 def _two_stage_program(pf, scoped: bool):

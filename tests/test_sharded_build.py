@@ -33,8 +33,9 @@ from test_exported_program import StandIn, cache  # noqa: F401  (its stand-in
 
 N = 8               # rows a device
 MESH = 4
-RECORD = {"program", "bucket", "devices", "tracing", "source", "load_s",
-          "blob_bytes", "trace_s", "lower_s", "compile_s", "lowered"}
+RECORD = {"program", "bucket", "devices", "miller_lines", "tracing",
+          "source", "load_s", "blob_bytes", "trace_s", "lower_s",
+          "compile_s", "lowered"}
 
 
 def _one():
@@ -144,6 +145,9 @@ def quicknet(tmp_path_factory):
             rec = cv._verifier.build(N)
         assert rec["program"].startswith("verify-g1sig-un-")
         assert (rec["tracing"], rec["devices"]) == ("compact", MESH)
+        # the key's table of lines is the replicated argument (ISSUE 37)
+        assert (rec["miller_lines"], rec["line_steps"]) == ("table", 68)
+        assert cv._verifier.verifier._pk.shape == (68, 2, 6, 32)
         yield config, sigs, cv
 
 

@@ -22,7 +22,9 @@ although each takes minutes: they are the ones the compiler refused.
 One composition is compiled too: a compact ladder (`cyclo_sqr` on every
 bit, dense `flat_mul` under a `lax.cond` on the set ones), because what
 the served program executes rests on the compiler keeping that
-conditional (ISSUE 26).
+conditional (ISSUE 26).  And the G1-signature program's Miller loop
+(ISSUE 37), whose lines come from a table indexed by a counter in the
+ladder's state.
 
 As the on-chip-measurement guide sets out: the topology is described
 inside a module-scoped fixture that skips where it cannot be, nothing
@@ -142,3 +144,33 @@ def test_compact_ladder_keeps_its_conditional_on_v5e(one_chip):
     # one call site a kernel: the body is not unrolled, the add step is
     # in one branch only
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_the_fixed_q_miller_loop_compiles_for_v5e(one_chip):
+    """ISSUE 37's loop as the served program traces it: one `while` with
+    the addition's lines under one `conditional`, the table read by a
+    dynamic index inside it, the eight Fp products of a step one
+    `mont_mul` launch over eight times the tiles, and no G2 kernel."""
+    from unittest import mock
+
+    from drand_tpu.ops import pairing as DP
+    from drand_tpu.ops.field import compact_scope
+
+    def miller(xp, yp, hx, hy, table, m1, m2):
+        with compact_scope():
+            return DP.miller_loop_fixed_q([(xp, yp), (hx, hy)], table,
+                                          [m1, m2], _keep_tiled=True).tiles
+
+    rows = NT * PFm.TILE
+    fp = jax.ShapeDtypeStruct((rows, 32), jnp.int32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((DP.LINE_STEPS, 2, 6, 32), jnp.int32,
+                                 sharding=one_chip)
+    with mock.patch.object(PFm, "use_pallas", return_value=True):
+        text = jax.jit(miller).lower(fp, fp, fp, fp, table, mask,
+                                     mask).compile().as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r" conditional\(", text)) == 1
+    names = re.findall(r"/(\w+)/pallas_call", text)
+    assert set(names) == {"flat_sqr", "mont_mul", "flat_mul", "flat_conj"}
+    assert f"s32[{8 * NT},32,8,128]" in text      # the step's one launch
