@@ -532,13 +532,17 @@ class SyncManager:
     async def _try_node(self, peer, req: SyncRequest) -> bool:
         """One catch-up from one peer, as one trace: the root span
         `sync.catchup` over `_fetch_stage`.  The wire wait stays a
-        counter on it (`fetch_s`, `messages`): a catch-up makes a wait
-        for every message, which is no span each."""
+        counter on it (`fetch_s`, `messages`; from the network layer
+        `recv_s`, `decode_s`, `bytes`): a catch-up makes a wait for
+        every message, which is no span each; `_fetch_stage` files them
+        on one `sync.fetch` a segment.  While the root is open the event
+        loop's lag is counted on it (`tracing.loop_watched`)."""
         before = dict(self.stats)
         with tracing.span(
                 "sync.catchup", beacon_id=self.beacon_id,
                 from_round=req.from_round, up_to=req.up_to,
-                peer=getattr(peer, "address", "") or str(peer)) as root:
+                peer=getattr(peer, "address", "") or str(peer)) as root, \
+                tracing.loop_watched(root):
             try:
                 return await self._fetch_stage(peer, req, root)
             finally:
@@ -597,6 +601,15 @@ class SyncManager:
 
         fetch_acc = 0.0            # wire-wait seconds since the last flush
         messages = 0               # stream items taken off the wire
+        # `sync.fetch`, one span a segment's fill: from the first wait
+        # after the last flush to the end of the last wait before this
+        # one, both readings the loop takes anyway
+        fill_began: float | None = None
+        fill_ended = 0.0
+        # what the network layer has counted on the root so far
+        # (`net/client.py:sync_chain`; a fake network counts nothing)
+        filed = {"recv_s": 0.0, "decode_s": 0.0, "bytes": 0}
+        filed_messages = 0
 
         def segment_cut() -> tuple[int, str]:
             """(rounds, why): the buffered run is flushed at `rounds`,
@@ -612,12 +625,22 @@ class SyncManager:
             """Hand the buffered run to the pipeline as a segment ended
             by `cut`; advance the anchor."""
             nonlocal anchor_round, anchor_sig, buffered, fetch_acc
+            nonlocal fill_began, filed_messages
             if not buffer:
                 return
             seg = list(buffer)
             buffer.clear()
             n, buffered = buffered, 0
             _observe_stage("fetch", fetch_acc)
+            counted = {k: root.attrs.get(k, 0) for k in filed}
+            tracing.record_span(
+                "sync.fetch", fill_began, fill_ended, parent=root,
+                messages=messages - filed_messages, rounds=n,
+                wait_s=fetch_acc,
+                **{k: counted[k] - filed[k] for k in filed})
+            filed.update(counted)
+            filed_messages = messages
+            fill_began = None
             fetch_acc = 0.0
             from drand_tpu.chaos import failpoints as chaos
             # an injected error aborts this peer try before the device
@@ -653,8 +676,11 @@ class SyncManager:
                 if pending is None:
                     pending = asyncio.ensure_future(stream.__anext__())
                 t0 = time.perf_counter()
+                if fill_began is None:
+                    fill_began = t0
                 done, _ = await asyncio.wait({pending}, timeout=idle_s)
-                dt = time.perf_counter() - t0
+                fill_ended = time.perf_counter()
+                dt = fill_ended - t0
                 self.stats["fetch_s"] += dt
                 fetch_acc += dt
                 if not done:
@@ -837,6 +863,12 @@ class SyncManager:
         return fixed
 
 
+def _payload_bytes(item) -> int:
+    if isinstance(item, PackedBeacons):
+        return item.sigs.nbytes + len(item.first_prev)
+    return len(item.signature) + len(item.previous_sig)
+
+
 async def serve_sync_chain(store, from_round: int, live_queue=None,
                            chunk_size: int = 0):
     """Server side: cursor-walk from the requested round, then attach to
@@ -850,51 +882,118 @@ async def serve_sync_chain(store, from_round: int, live_queue=None,
     event loop on sqlite.  Stores without `read_fields` (in-memory
     fakes) and the live tail fall back to per-beacon items, which the
     wire layer sends as plain BeaconPackets — the transparent-fallback
-    half of the capability negotiation."""
+    half of the capability negotiation.
+
+    One span a served backlog, `sync.serve` (under the RPC's server
+    span, and so under the consumer's `sync.catchup` where both ends
+    share a process), never one a message: every second of it goes to
+    one of three counters, `read_s` (the awaited reads: thread hop,
+    sqlite, row decode; `read_thread_s` is the same reads timed inside
+    the worker, so the hop is the difference), `pack_s` (`pack_rows`)
+    and `send_s` (suspended at `yield`: the packet's conversion and
+    serialisation, the transport's write and its flow control), beside
+    `messages`, `rows` and `bytes` (the items' payload).  It ends
+    `closed` where the client closed the stream, which is how every
+    bounded catch-up ends, `error` only on an exception of this side's
+    own, and before the live tail begins."""
     last_sent = from_round - 1
     reader = getattr(store, "read_fields", None) if chunk_size > 0 else None
-    if reader is not None:
-        next_round = from_round
-        while True:
-            try:
-                rows = await asyncio.to_thread(reader, next_round, chunk_size)
-            except StoreError as exc:
-                # A damaged row on OUR disk must not error the stream: the
-                # CorruptRowError carries the offending round, so re-read
-                # the good prefix below it, serve that, and end the stream
-                # cleanly — the client renews against another peer while
-                # the startup scan / fsck deals with the damage here.
-                bad = getattr(exc, "round", None)
-                rows = []
-                if bad is not None and bad > next_round:
-                    try:
-                        rows = await asyncio.to_thread(
-                            reader, next_round, bad - next_round)
-                    except StoreError:
-                        rows = []
-                log.warning("serve: corrupt row at round %s; ending stream "
-                            "after last good round", bad)
-                for item in pack_rows(rows, max_chunk=chunk_size):
-                    yield item
-                return
-            if not rows:
-                break
-            for item in pack_rows(rows, max_chunk=chunk_size):
-                if isinstance(item, PackedBeacons):
-                    last_sent = item.end_round
-                else:
-                    last_sent = item.round
-                yield item
-            next_round = rows[-1][0] + 1
-    else:
+    sp = tracing.begin_span("sync.serve", from_round=from_round,
+                            chunk_size=chunk_size)
+    n = {"read_s": 0.0, "pack_s": 0.0, "send_s": 0.0, "read_thread_s": 0.0,
+         "messages": 0, "rows": 0, "bytes": 0}
+    lapped, part = sp.start_mono, "read_s"   # the last reading; the part since
+
+    def lap(then: str) -> None:
+        nonlocal lapped, part
+        now = time.perf_counter()
+        n[part] += now - lapped
+        lapped, part = now, then
+
+    def sending(item):
+        """An item on its way out: what ran since the last lap made it."""
+        lap("send_s")
+        n["messages"] += 1
+        n["bytes"] += _payload_bytes(item)
+        return item
+
+    def read(start: int, limit: int):
+        """Worker thread."""
+        t0 = time.perf_counter()
+        rows = reader(start, limit)
+        n["read_thread_s"] += time.perf_counter() - t0
+        return rows
+
+    async def read_rows(start: int, limit: int):
         try:
-            for beacon in store.iter_range(from_round):
-                last_sent = beacon.round
-                yield beacon
-        except StoreError as exc:
-            log.warning("serve: store error mid-stream (%s); ending stream "
-                        "at round %d", exc, last_sent)
-            return
+            rows = await asyncio.to_thread(read, start, limit)
+        finally:
+            lap("pack_s")
+        n["rows"] += len(rows)
+        return rows
+
+    status = "ok"
+    try:
+        if reader is not None:
+            next_round = from_round
+            while True:
+                try:
+                    rows = await read_rows(next_round, chunk_size)
+                except StoreError as exc:
+                    # A damaged row on OUR disk must not error the stream:
+                    # the CorruptRowError carries the offending round, so
+                    # re-read the good prefix below it, serve that, and end
+                    # the stream cleanly — the client renews against
+                    # another peer while the startup scan / fsck deals with
+                    # the damage here.
+                    bad = getattr(exc, "round", None)
+                    rows = []
+                    if bad is not None and bad > next_round:
+                        lap("read_s")
+                        try:
+                            rows = await read_rows(next_round,
+                                                   bad - next_round)
+                        except StoreError:
+                            rows = []
+                    log.warning("serve: corrupt row at round %s; ending "
+                                "stream after last good round", bad)
+                    for item in pack_rows(rows, max_chunk=chunk_size):
+                        yield sending(item)
+                        lap("pack_s")
+                    return
+                if not rows:
+                    break
+                for item in pack_rows(rows, max_chunk=chunk_size):
+                    if isinstance(item, PackedBeacons):
+                        last_sent = item.end_round
+                    else:
+                        last_sent = item.round
+                    yield sending(item)
+                    lap("pack_s")
+                lap("read_s")
+                next_round = rows[-1][0] + 1
+        else:
+            try:
+                # the store's iterator is the read, on the event loop
+                for beacon in store.iter_range(from_round):
+                    last_sent = beacon.round
+                    n["rows"] += 1
+                    yield sending(beacon)
+                    lap("read_s")
+            except StoreError as exc:
+                log.warning("serve: store error mid-stream (%s); ending "
+                            "stream at round %d", exc, last_sent)
+                return
+    except tracing.STREAM_CLOSED:
+        status = "closed"
+        raise
+    except BaseException:
+        status = "error"
+        raise
+    finally:
+        lap(part)
+        sp.set(**n)
+        sp.end(status, at=lapped)
     if live_queue is not None:
         while True:
             beacon = await live_queue.get()

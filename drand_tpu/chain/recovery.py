@@ -163,11 +163,13 @@ async def scan_store(store, verifier=None, *, beacon_id: str = "",
     `verify.resolve`; the last flush's `scan.verify_wait` lies under the
     root.  The verifier's `verify.segment` runs from a segment's
     dispatch to its resolver, so it outlives the flush it began under,
-    as it crosses the catch-up's stages.
+    as it crosses the catch-up's stages.  While the root is open the
+    event loop's lag is counted on it (`tracing.loop_watched`).
     """
     from drand_tpu import tracing
     with tracing.span("store.scan", beacon_id=beacon_id,
-                      verify=verifier is not None) as root:
+                      verify=verifier is not None) as root, \
+            tracing.loop_watched(root):
         ahead = _DispatchedAhead(verifier)
         try:
             report = await _scan_store(store, ahead, beacon_id,

@@ -198,12 +198,30 @@ class SqliteStore(Store):
 
     def put_many(self, beacons) -> None:
         """ONE transaction for a whole verified segment (one commit/fsync
-        instead of per-beacon)."""
+        instead of per-beacon).  Its three parts go as counters to the
+        span that encloses the call (`store.commit`, in the worker that
+        commits): `encode_s` (the row tuples built in Python),
+        `insert_s` (`executemany`, which opens the transaction) and
+        `flush_s` (its COMMIT: the WAL's write, at the `synchronous`
+        level the connection has); an exception rolls it back, as the
+        connection's context manager did."""
+        from drand_tpu import tracing
         enc = self._encode
-        with self._conn() as conn:
+        conn = self._conn()
+        t0 = _time.perf_counter()
+        rows = [(b.round, enc(b)) for b in beacons]
+        t1 = _time.perf_counter()
+        try:
             conn.executemany(
                 "INSERT OR REPLACE INTO beacons (round, data) VALUES (?, ?)",
-                [(b.round, enc(b)) for b in beacons])
+                rows)
+            t2 = _time.perf_counter()
+            conn.commit()
+        except BaseException:
+            conn.rollback()
+            raise
+        tracing.count(encode_s=t1 - t0, insert_s=t2 - t1,
+                      flush_s=_time.perf_counter() - t2)
 
     def last(self) -> Beacon:
         row = self._conn().execute(

@@ -133,8 +133,16 @@ class GrpcBeaconNetwork(BeaconNetwork):
                                  deadline=dl, breaker=breaker)
 
     async def sync_chain(self, node, from_round: int):
+        """The peer's stream as Beacons or PackedBeacons.  Two readings
+        a message go as counters to the span current in the consumer's
+        context (`sync.catchup`, whose fetch loop files them on its
+        `sync.fetch` span a segment): `recv_s`, the stream's read, which
+        is the serving node and the transport, and `decode_s`, from
+        there to the `yield`; with them the message's `bytes`."""
         import os as _os
+        import time
 
+        from drand_tpu import tracing
         from drand_tpu.chain.segment import WIRE_CHUNK_DEFAULT, PackedBeacons
         from drand_tpu.chaos import failpoints as chaos
         from drand_tpu.core import convert
@@ -149,31 +157,49 @@ class GrpcBeaconNetwork(BeaconNetwork):
                                     chunk_size=max(0, wire_chunk),
                                     metadata=make_metadata(self.beacon_id))
         call = stub.SyncChain(req)
-        async for pkt in call:
-            item = convert.packet_to_item(pkt)
-            packed = isinstance(item, PackedBeacons)
-            # drop = the stream is cut mid-flight (the consumer's peer
-            # loop falls back); delay = a slow stream.  src is the
-            # SERVING peer: chaos ctx follows message direction.  One
-            # site visit per wire MESSAGE — for a chunk that is one
-            # visit per 512 rounds, the protocol-level win made visible
-            # to chaos rules.  The ctx round is the chunk's START (the
-            # cut position): the stream start is pinned by the request's
-            # from_round, while the chunk END rides the serving peer's
-            # tip — a value that races the rest of the scenario and
-            # would make seeded injection logs unreplayable.
-            await chaos.failpoint(
-                "net.sync_recv", src=node.address, dst=self.local_addr,
-                round=item.start_round if packed else item.round)
-            try:
-                from drand_tpu import metrics as M
-                M.SYNC_ROUNDS.labels(
-                    self.beacon_id,
-                    "chunk" if packed else "single").inc(
-                        len(item) if packed else 1)
-            except Exception:
-                pass
-            yield item
+        stream = call.__aiter__()
+        try:
+            while True:
+                t0 = time.perf_counter()
+                try:
+                    pkt = await stream.__anext__()
+                except StopAsyncIteration:
+                    return
+                t1 = time.perf_counter()
+                item = convert.packet_to_item(pkt)
+                packed = isinstance(item, PackedBeacons)
+                # drop = the stream is cut mid-flight (the consumer's peer
+                # loop falls back); delay = a slow stream.  src is the
+                # SERVING peer: chaos ctx follows message direction.  One
+                # site visit per wire MESSAGE — for a chunk that is one
+                # visit per 512 rounds, the protocol-level win made visible
+                # to chaos rules.  The ctx round is the chunk's START (the
+                # cut position): the stream start is pinned by the request's
+                # from_round, while the chunk END rides the serving peer's
+                # tip — a value that races the rest of the scenario and
+                # would make seeded injection logs unreplayable.
+                await chaos.failpoint(
+                    "net.sync_recv", src=node.address, dst=self.local_addr,
+                    round=item.start_round if packed else item.round)
+                try:
+                    from drand_tpu import metrics as M
+                    M.SYNC_ROUNDS.labels(
+                        self.beacon_id,
+                        "chunk" if packed else "single").inc(
+                            len(item) if packed else 1)
+                except Exception:
+                    pass
+                tracing.count(recv_s=t1 - t0,
+                              decode_s=time.perf_counter() - t1,
+                              bytes=pkt.ByteSize())
+                yield item
+        finally:
+            # a consumer that stops reading (a bounded catch-up at its
+            # `up_to`) closes this generator, which by itself tells the
+            # peer nothing: it would send until flow control stops it
+            # and hold the stream open for good.  No-op once the stream
+            # has ended.
+            call.cancel()
 
     async def status(self, node) -> dict:
         from drand_tpu.chaos import failpoints as chaos

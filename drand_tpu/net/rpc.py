@@ -100,9 +100,14 @@ def _with_server_span(fn, service: str, method: str, streaming: bool):
         async def stream_traced(req, ctx):
             with tracing.server_span(span_name,
                                      getattr(req, "metadata", None),
-                                     round_=_req_round(req)):
-                async for item in fn(req, ctx):
-                    yield item
+                                     round_=_req_round(req)) as sp:
+                try:
+                    async for item in fn(req, ctx):
+                        yield item
+                except tracing.STREAM_CLOSED:
+                    # a bounded catch-up does, at its `up_to`
+                    sp.end("closed")
+                    raise
         return stream_traced
 
     async def unary_traced(req, ctx):

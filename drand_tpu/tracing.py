@@ -35,6 +35,17 @@ timeline.  This module is the TPU-native replacement (SURVEY §5.1):
   - `gc.full`: a `gc.callbacks` hook records every generation-2
     collection (after a program build one stops all Python threads for
     seconds) as a span of its own trace.
+  - counters on a span: what happens once a message or a row is no span
+    (an operation takes hundreds of messages, the ring holds 4,096
+    spans); it is two clock reads added to an attribute of the span a
+    segment or a stream already has (`Span.add`, `count`).
+  - the event loop's lag: `loop_watched(root)` keeps one task a running
+    loop that sleeps `LOOP_LAG_TICK_S` and reads by how much it woke
+    late, while a root span of a catch-up or a scan is open; the
+    overshoots add up on every such root, and one of `LOOP_LAG_SPAN_S`
+    or more is also a `loop.lag` span, so the spans open over it (a
+    commit in a worker, `gc.full`, a build) say what held the loop, or
+    that nothing of the program did.
 
 Every ended span also feeds the `drand_stage_duration_seconds{stage,
 beacon_id}` Prometheus histogram (drand_tpu/metrics.py), which is how
@@ -47,6 +58,7 @@ Non-context-manager use MUST balance `begin_span()` with `Span.end()`
 
 from __future__ import annotations
 
+import asyncio
 import contextlib
 import contextvars
 import gc
@@ -151,6 +163,13 @@ class Span:
         self.attrs.update(attrs)
         return self
 
+    def add(self, **counters) -> "Span":
+        """Add to numeric attributes (absent ones count from 0): the
+        form of whatever happens more often than a span may."""
+        for key, value in counters.items():
+            self.attrs[key] = self.attrs.get(key, 0) + value
+        return self
+
     def to_dict(self) -> dict:
         return {
             "trace_id": self.trace_id, "span_id": self.span_id,
@@ -237,8 +256,24 @@ class SpanRecorder:
 RECORDER = SpanRecorder()
 
 
+# What ends a server's stream when its CLIENT closed it (the handler's
+# generator is closed, or the RPC's task cancelled): no failure of the
+# serving side's, so its spans end `closed`, not `error`.
+STREAM_CLOSED = (GeneratorExit, asyncio.CancelledError)
+
+
 def current() -> Span | None:
     return _current.get()
+
+
+def count(**counters) -> None:
+    """Add to counters on the context's current span, where there is
+    one: a layer below the span's own (the wire's client under
+    `sync.catchup`, sqlite under `store.commit`) hands up what it timed
+    without knowing who asked."""
+    sp = _current.get()
+    if sp is not None:
+        sp.add(**counters)
 
 
 def begin_span(name: str, *, beacon_id: str = "", round_: int | None = None,
@@ -394,6 +429,69 @@ def _drain_full_collections() -> None:
 
 
 gc.callbacks.append(_on_gc)
+
+
+# -- the event loop's lag ---------------------------------------------------
+#
+# Two constants and no knob: a tick short enough that the wait of one
+# wire message (3 ms) shows, a span only for what a run's clock would
+# show as a stall.
+
+LOOP_LAG_TICK_S = 0.005
+LOOP_LAG_SPAN_S = 0.020
+
+
+class _LoopWatch:
+    """The one monitor of one running loop, and the roots it counts for
+    (opened first, first)."""
+
+    def __init__(self, loop):
+        self.roots: list[Span] = []
+        self.task = loop.create_task(self._run())
+
+    async def _run(self) -> None:
+        while True:
+            due = time.perf_counter() + LOOP_LAG_TICK_S
+            await asyncio.sleep(LOOP_LAG_TICK_S)
+            now = time.perf_counter()
+            late = max(0.0, now - due)
+            for root in self.roots:
+                root.add(loop_lag_s=late, loop_ticks=1)
+                if late > root.attrs.get("loop_lag_max_s", 0.0):
+                    root.attrs["loop_lag_max_s"] = late
+            if late >= LOOP_LAG_SPAN_S and self.roots:
+                # from when the task was due to when it ran; the child
+                # of a root, never of a stage whose self time is read
+                record_span("loop.lag", due, now, parent=self.roots[0],
+                            roots=len(self.roots))
+
+
+_loop_watches: dict = {}        # running loop -> its _LoopWatch
+
+
+@contextlib.contextmanager
+def loop_watched(root: Span):
+    """While the block runs, the running loop's lag is counted on `root`
+    (`loop_lag_s`, `loop_lag_max_s`, `loop_ticks`).  Overlapping blocks
+    on one loop (two chains' catch-ups) share one monitor: the first to
+    begin starts its task and the last to end cancels it.  Outside a
+    running loop there is nothing to watch."""
+    try:
+        loop = asyncio.get_running_loop()
+    except RuntimeError:
+        yield
+        return
+    watch = _loop_watches.get(loop)
+    if watch is None:
+        watch = _loop_watches[loop] = _LoopWatch(loop)
+    watch.roots.append(root)
+    try:
+        yield
+    finally:
+        watch.roots[:] = [r for r in watch.roots if r is not root]
+        if not watch.roots:
+            del _loop_watches[loop]
+            watch.task.cancel()
 
 
 # -- RPC propagation (protobuf Metadata fields 4/5) -----------------------

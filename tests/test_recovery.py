@@ -342,7 +342,8 @@ def test_util_fsck_repairs_and_reports_json(tmp_path, capsys):
 
 def _scan_spans():
     from drand_tpu import tracing
-    return [sp for sp in tracing.RECORDER.spans() if sp.name != "gc.full"]
+    return [sp for sp in tracing.RECORDER.spans()
+            if sp.name not in ("gc.full", "loop.lag")]
 
 
 def test_a_scan_is_one_trace_whose_self_times_add_up(tmp_path):
@@ -627,4 +628,5 @@ def test_the_structural_scan_has_no_verify_spans(tmp_path):
     assert _scan(s).ok
     s.close()
     names = {sp.name for sp in tracing.RECORDER.spans()}
-    assert names - {"gc.full"} == {"store.scan", "scan.read", "scan.decode"}
+    assert names - {"gc.full", "loop.lag"} == {
+        "store.scan", "scan.read", "scan.decode"}

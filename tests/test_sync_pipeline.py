@@ -434,14 +434,17 @@ def test_a_catch_up_is_one_trace_of_bounded_spans(chain, monkeypatch):
                                          SM.SyncRequest(1, up_to=N)))
     finally:
         store._pool.shutdown(wait=False)
-    spans = [s for s in tracing.RECORDER.spans() if s.name != "gc.full"]
+    # a full collection or a late event loop is the machine's, not the
+    # catch-up's: both are spans of their own where they happen
+    spans = [s for s in tracing.RECORDER.spans()
+             if s.name not in ("gc.full", "loop.lag")]
     by_id = {s.span_id: s for s in spans}
     (root,) = [s for s in spans if s.parent_id is None]
     assert root.name == "sync.catchup"
     assert {s.trace_id for s in spans} == {root.trace_id}
     assert {s.name for s in spans} == {
-        "sync.catchup", "sync.segment", "sync.queue_wait", "sync.pack",
-        "sync.settle", "store.materialize", "store.commit"}
+        "sync.catchup", "sync.fetch", "sync.segment", "sync.queue_wait",
+        "sync.pack", "sync.settle", "store.materialize", "store.commit"}
     # every child lies inside its parent's interval
     for s in spans:
         if s.parent_id is not None:
@@ -466,7 +469,16 @@ def test_a_catch_up_is_one_trace_of_bounded_spans(chain, monkeypatch):
         assert set(waits) == {"verify", "commit"}
         assert all(0 <= w.attrs["depth"] <= SM.PIPELINE_DEPTH
                    for w in waits.values())
-    assert len(spans) == 1 + 3 * 7
+    # and one `sync.fetch` a segment's fill, a child of the root, whose
+    # waits are the very readings the stat is made of
+    fills = [s for s in spans if s.name == "sync.fetch"]
+    assert all(s.parent_id == root.span_id for s in fills)
+    assert [(s.attrs["rounds"], s.attrs["messages"]) for s in fills] == [
+        (4, 2), (4, 2), (2, 1)]
+    assert sum(s.attrs["wait_s"] for s in fills) == pytest.approx(
+        mgr.stats["fetch_s"], abs=1e-9)
+    assert all(s.duration_s >= s.attrs["wait_s"] - 1e-9 for s in fills)
+    assert len(spans) == 1 + 3 * 8
     # the stats and the spans that share their clock reads agree exactly
     settle = sum(s.duration_s for s in spans if s.name == "sync.settle")
     assert settle == pytest.approx(mgr.stats["verify_s"], abs=1e-9)
@@ -909,3 +921,144 @@ def test_no_cyclic_collection_while_a_segment_s_rows_are_alive(
         gc.enable()
     assert store.seen == [False, False]
     assert sorted(store.by_round) == list(range(0, 17))
+
+
+# -- the catch-up's two ends, opened (ISSUE 38) -------------------------------
+
+class _Rows:
+    """A store of `read_fields` alone: rounds 1..n, or a reader that
+    fails on its second batch."""
+
+    def __init__(self, n, fail=None):
+        self.n, self.fail, self.reads = n, fail, 0
+
+    def read_fields(self, start, limit):
+        self.reads += 1
+        if self.fail is not None and self.reads == 2:
+            raise self.fail
+        return [(r, bytes([r % 251]) * 48, b"")
+                for r in range(start, min(start + limit, self.n + 1))]
+
+
+def _self_seconds(span, spans):
+    kids = sum(k.duration_s for k in spans if k.parent_id == span.span_id)
+    return span.duration_s - kids
+
+
+@pytest.mark.filterwarnings("ignore::pytest.PytestUnraisableExceptionWarning")
+def test_the_wire_s_two_ends_and_the_commit_s_parts_over_the_stand(
+        tmp_path, monkeypatch):
+    """A bounded catch-up over the in-process stand (the real
+    `Protocol.SyncChain` over localhost gRPC, sqlite on both sides): the
+    consumer stops at `up_to` and closes a stream whose serving side
+    has more to send than the transport will take ahead (a backlog
+    without end), which is how every bounded catch-up ends."""
+    import tools.bench_sync as bs
+    from drand_tpu import tracing
+    monkeypatch.setenv(bs.WIRE_ENV, "16")
+    monkeypatch.delenv(bs.CODEC_ENV, raising=False)
+    monkeypatch.setattr(SM, "SYNC_CHUNK", 32)
+    monkeypatch.setattr(SM, "SYNC_CHUNK_GROWTH", 1)
+    served = _Rows(10 ** 9)
+
+    def of(name):
+        return [s for s in tracing.RECORDER.spans() if s.name == name]
+
+    async def main():
+        server, addr = await bs._serve(served)
+        tracing.RECORDER.clear()
+        try:
+            ok, _, stats, _, last = await bs.catch_up(
+                addr, bs._StubVerifier(), 64)
+            # the serving side learns of the close from the transport
+            for _ in range(500):
+                if of("sync.serve") and of("rpc.Protocol.SyncChain"):
+                    break
+                await asyncio.sleep(0.01)
+        finally:
+            await server.stop(None)
+        return ok, stats, last
+
+    ok, stats, last = asyncio.run(main())
+    assert ok and last == 64 and stats["rounds"] == 64
+    spans = tracing.RECORDER.spans()
+    (root,) = of("sync.catchup")
+
+    # the consumer's side: one `sync.fetch` a segment's fill
+    fills, segments = of("sync.fetch"), of("sync.segment")
+    assert len(fills) == len(segments) == 2
+    assert [f.attrs["rounds"] for f in fills] == [32, 32]
+    assert all(f.parent_id == root.span_id and f.beacon_id == root.beacon_id
+               for f in fills)
+    assert sum(f.attrs["wait_s"] for f in fills) == pytest.approx(
+        stats["fetch_s"], abs=1e-9)
+    assert sum(f.attrs["messages"] for f in fills) \
+        == root.attrs["messages"] == 4
+    for f in fills:
+        assert 0 < f.attrs["recv_s"] and 0 < f.attrs["decode_s"]
+        assert f.attrs["recv_s"] + f.attrs["decode_s"] <= f.attrs["wait_s"]
+        assert f.attrs["bytes"] >= 32 * 48
+        assert f.attrs["wait_s"] <= f.duration_s + 1e-9
+    # what the network layer counted on the root is what the fills hold
+    for key in ("recv_s", "decode_s", "bytes"):
+        assert sum(f.attrs[key] for f in fills) == pytest.approx(
+            root.attrs[key])
+
+    # the serving side: one span a served stream, under the RPC's, which
+    # the consumer's root parents across the wire; the client closed it
+    (rpc,), (serve,) = of("rpc.Protocol.SyncChain"), of("sync.serve")
+    assert rpc.parent_id == root.span_id and serve.parent_id == rpc.span_id
+    assert serve.trace_id == rpc.trace_id == root.trace_id
+    assert rpc.status == "closed" and serve.status == "closed"
+    a = serve.attrs
+    assert a["read_s"] + a["pack_s"] + a["send_s"] == pytest.approx(
+        serve.duration_s, abs=1e-3)
+    assert 0 < a["read_thread_s"] <= a["read_s"]
+    assert a["messages"] >= 4 and a["rows"] >= a["messages"] * 16 - 15
+    assert a["bytes"] == a["messages"] * 16 * 48
+    assert min(a["read_s"], a["pack_s"], a["send_s"]) > 0
+
+    # the store's commit: three parts inside the span's self time
+    commits = [c for c in of("store.commit") if c.attrs.get("rows") == 32]
+    assert len(commits) == 2
+    for c in commits:
+        parts = [c.attrs[k] for k in ("encode_s", "insert_s", "flush_s")]
+        assert min(parts) > 0
+        assert sum(parts) <= _self_seconds(c, spans) + 1e-9
+
+
+@pytest.mark.parametrize("how, status, messages", [
+    ("to_its_end", "ok", 4),
+    ("closed_by_the_client", "closed", 2),
+    ("an_exception_of_its_own", "error", 1),
+])
+def test_a_served_stream_ends_with_its_counters_set(how, status, messages):
+    """A stream the client closed is not an error; one that failed on
+    this side is.  Either way the parts add up to the span."""
+    from drand_tpu import tracing
+    store = _Rows(64, RuntimeError("disk") if status == "error" else None)
+
+    async def main():
+        gen = SM.serve_sync_chain(store, 1, chunk_size=16)
+        got = []
+        try:
+            async for item in gen:
+                got.append(item)
+                if how == "closed_by_the_client" and len(got) == 2:
+                    await gen.aclose()
+                    break
+        except RuntimeError:
+            assert status == "error"
+        return got
+
+    tracing.RECORDER.clear()
+    got = asyncio.run(main())
+    assert len(got) == messages
+    (serve,) = [s for s in tracing.RECORDER.spans() if s.name == "sync.serve"]
+    a = serve.attrs
+    assert serve.status == status
+    assert a["messages"] == messages and a["rows"] >= 16 * messages
+    assert a["bytes"] == messages * 16 * 48
+    assert a["read_s"] + a["pack_s"] + a["send_s"] == pytest.approx(
+        serve.duration_s, abs=1e-6)
+    assert 0 < a["read_thread_s"] <= a["read_s"]

@@ -370,3 +370,111 @@ def test_full_collections_are_spans_and_young_ones_are_not():
     assert full[0].duration_s > 0 and full[0].parent_id is None
     assert full[0].attrs["collected"] >= 1000
     assert full[0].start_mono > 0
+
+
+# -- counters on a span, and the event loop's lag (ISSUE 38) -----------------
+
+def test_counters_add_up_on_the_current_span_and_nowhere_else():
+    tracing.count(recv_s=1.0)               # no span: nothing to count on
+    with tracing.span("sync.catchup") as root:
+        tracing.count(recv_s=0.25, bytes=100)
+        tracing.count(recv_s=0.5, bytes=20)
+        with tracing.span("store.commit") as inner:
+            tracing.count(flush_s=0.125)
+    assert root.attrs == {"recv_s": 0.75, "bytes": 120}
+    assert inner.attrs == {"flush_s": 0.125}
+    assert root.add(bytes=1).attrs["bytes"] == 121
+
+
+def _warm_span_end():
+    """A process's first `Span.end` imports the stage histogram and the
+    journey feed, a stall of its own that is not the test's."""
+    tracing.record_span("warm", 0.0, 1.0)
+    tracing.RECORDER.clear()
+
+
+def _lags():
+    return [s for s in tracing.RECORDER.spans() if s.name == "loop.lag"]
+
+
+def test_a_blocked_loop_is_a_lag_span_under_the_root(monkeypatch):
+    import time
+    _warm_span_end()
+
+    async def main():
+        with tracing.span("sync.catchup", beacon_id="b") as root, \
+                tracing.loop_watched(root):
+            await asyncio.sleep(0.03)           # the monitor is ticking
+            before = time.perf_counter()
+            asyncio.get_running_loop().call_soon(time.sleep, 0.05)
+            await asyncio.sleep(0.03)
+        return root, before
+
+    root, before = asyncio.run(main())
+    longest = max(_lags(), key=lambda s: s.duration_s)
+    # from when the monitor was due (inside the block, at most a tick
+    # after it began) to when it ran: about the 50 ms the loop stood
+    assert 0.04 <= longest.duration_s < 0.5
+    assert longest.start_mono >= before - tracing.LOOP_LAG_TICK_S
+    assert longest.parent_id == root.span_id
+    assert longest.trace_id == root.trace_id and longest.beacon_id == "b"
+    assert longest.attrs == {"roots": 1}
+    assert root.attrs["loop_lag_max_s"] == pytest.approx(longest.duration_s)
+    assert root.attrs["loop_lag_s"] >= longest.duration_s
+    assert root.attrs["loop_ticks"] >= 3
+
+
+def test_a_quiet_loop_ticks_and_is_no_span(monkeypatch):
+    _warm_span_end()
+    # a loaded machine's own hiccups are not this test's: only what
+    # would be a stall of a quarter of a second is a span here
+    monkeypatch.setattr(tracing, "LOOP_LAG_SPAN_S", 0.25)
+
+    async def main():
+        with tracing.span("store.scan") as root, \
+                tracing.loop_watched(root):
+            await asyncio.sleep(0.1)
+        return root
+
+    root = asyncio.run(main())
+    assert _lags() == []
+    assert 3 <= root.attrs["loop_ticks"] <= 0.1 / tracing.LOOP_LAG_TICK_S
+    assert 0 <= root.attrs["loop_lag_max_s"] <= root.attrs["loop_lag_s"] \
+        < 0.25 * root.attrs["loop_ticks"]
+
+
+def test_two_roots_share_one_monitor_and_the_last_to_end_stops_it():
+    _warm_span_end()
+
+    async def chain(name, seconds, seen):
+        with tracing.span("sync.catchup", beacon_id=name) as root, \
+                tracing.loop_watched(root):
+            await asyncio.sleep(seconds)
+            seen[name] = (len(tracing._loop_watches),
+                          tracing._loop_watches[
+                              asyncio.get_running_loop()].task)
+        return root
+
+    async def main():
+        seen = {}
+        tasks_before = len(asyncio.all_tasks())
+        a, b = await asyncio.gather(chain("a", 0.05, seen),
+                                    chain("b", 0.12, seen))
+        await asyncio.sleep(0)              # the cancelled monitor ends
+        return a, b, seen, len(asyncio.all_tasks()) - tasks_before
+
+    a, b, seen, tasks_left = asyncio.run(main())
+    # one monitor, the same task for both, still running after the first
+    # root had ended and gone once the last has
+    assert seen["a"][0] == seen["b"][0] == 1
+    assert seen["a"][1] is seen["b"][1]
+    assert seen["a"][1].cancelled()
+    assert tasks_left == 0 and tracing._loop_watches == {}
+    # each root counts what happened while IT was open
+    assert 3 <= a.attrs["loop_ticks"] < b.attrs["loop_ticks"]
+
+
+def test_outside_a_running_loop_there_is_nothing_to_watch():
+    with tracing.span("store.scan") as root, tracing.loop_watched(root):
+        pass
+    assert "loop_ticks" not in root.attrs and tracing._loop_watches == {}
