@@ -32,7 +32,9 @@ line multiply and the ladder's driver, and no branch:
   - `miller_loop_pairs` carries a Jacobian G2 point a row and pair
     through the ladder.  The G2-signature programs (`bls.verify_g2_sigs`,
     the partial-signature builders) need it: there Q is the signature and
-    H(m), another in every row.
+    H(m), another in every row.  On the TPU the point, like f, is in the
+    kernels' tile layout from before the ladder to its end
+    (`_tile_halves`).
   - `miller_loop_fixed_q` holds no G2 point.  In the G1-signature program
     (`bls.verify_g1_sigs`) both Qs, the generator and the chain's key,
     are the whole batch's, and T = [k]Q and the line through it depend on
@@ -109,12 +111,10 @@ def _dbl_step(Tj, xp, yp):
     Line (scaled by 2YZ^3 in Fp2, killed by final exp):
       a = 3X^3 - 2Y^2,  b = -3X^2 Z^2 * xp,  c = 2YZ^3 * yp.
 
-    On TPU the whole step runs as one fused Pallas kernel
-    (PallasField.g2_dbl_line, identical formulas).
+    The XLA form, which the CPU tier traces.  On TPU the loop never
+    calls it: the step is one fused Pallas kernel on packed state
+    (PallasField.g2_dbl_line, identical formulas, see `_tile_halves`).
     """
-    pf = FP._pallas()
-    if pf is not None:
-        return pf.g2_dbl_line(Tj, xp, yp)
     X, Y, Z = Tj
     XX, YY, ZZ, YZ = T.fp2_products([(X, X), (Y, Y), (Z, Z), (Y, Z)])
     xyy = T.fp2_add(X, YY)
@@ -145,12 +145,9 @@ def _add_step(Tj, Q, xp, yp):
     With H = xq Z^2 - X, r = 2(yq Z^3 - Y), line scaled by -2*(mu Z) where
     mu = -H:  a = r*xq - 2HZ*yq,  b = -r*xp,  c = 2HZ*yp.
 
-    On TPU the whole step runs as one fused Pallas kernel
-    (PallasField.g2_add_line, identical formulas).
+    The XLA form, as `_dbl_step`; on TPU the loop runs
+    PallasField.g2_add_line on packed state instead.
     """
-    pf = FP._pallas()
-    if pf is not None:
-        return pf.g2_add_line(Tj, Q, xp, yp)
     X, Y, Z = Tj
     xq, yq = Q
     ZZ, yqZ = T.fp2_products([(Z, Z), (yq, Z)])
@@ -202,11 +199,71 @@ def miller_loop_pairs(pairs, active=None, _keep_tiled=False):
         return _miller_loop_pairs_merged(pf, pairs, active, shape,
                                          _keep_tiled)
 
-    # On the Pallas path the accumulator f lives in TileForm for the whole
-    # loop: flat_sqr and the line multiplies consume/produce it without
-    # the per-call tile relayout (only the lines re-tile, at half f's
-    # size).
     f = F.flat_tile(F.flat_broadcast(F.FLAT_ONE, shape))
+    if pf is not None:
+        T0, dbl_half, add_half = _tile_halves(pf, pairs, active, shape)
+    else:
+        T0, dbl_half, add_half = _xla_halves(pairs, active, shape)
+    # The parameter's bits are static (field.tail_segments): every bit
+    # runs the doubling half, only the 5 set bits the addition half —
+    # nothing is computed just to be masked away.
+    f, _ = segmented_ladder(_X_SEGMENTS, (f, T0), dbl_half, add_half)
+    f = F.flat_conj(f)                    # x < 0 (packed on Pallas)
+    return f if _keep_tiled else F.flat_untile(f)
+
+
+def _tile_halves(pf, pairs, active, shape):
+    """The ladder's two halves on the Pallas path, and the T they start
+    from.  The whole state stays in the kernels' tile layout: f as
+    `flat_tile` gives it, T one packed TileForm of 6 coordinates with
+    the K pairs joined on the tile axis (`tile_stack`), so a step
+    launches once over all pairs.  Every coordinate of every pair, T =
+    (xq, yq, 1) and the fixed P, crosses the layout boundary in ONE
+    `pack_coords` before the ladder, each `active` mask in one
+    `mask_wrap`; Q is T's first four coordinates.  A half is then
+    kernels only: `flat_sqr`, the step kernel, which writes T' and the K
+    masked lines already in `flat_mul`'s sparse layout, and K `flat_mul`,
+    each reading its pair's run of the line's tiles in place."""
+    from drand_tpu.ops.pallas_field import N_LIMBS, tile_split, tile_stack
+    K = len(pairs)
+    one = T.fp2_broadcast(T.FP2_ONE, shape)
+    coords = []
+    for (xp, yp), (xq, yq) in pairs:
+        coords += [xq[0], xq[1], yq[0], yq[1], one[0], one[1], xp, yp]
+    packed = pf.pack_coords([jnp.broadcast_to(c, shape + c.shape[-1:])
+                             for c in coords])
+    Tt, Pt = tile_split(tile_stack(tile_split(packed, [8 * N_LIMBS] * K)),
+                        [6 * N_LIMBS, 2 * N_LIMBS])
+    Qt = tile_split(Tt, [4 * N_LIMBS, 2 * N_LIMBS])[0]
+    Mt = jnp.concatenate(
+        [pf.mask_wrap(True if m is None else m, shape) for m in active],
+        axis=0).astype(jnp.int32)[:, None]
+
+    def mul_lines(f, lines):
+        for k in range(K):
+            f = pf.flat_mul(f, lines, LINE_IDX, b_run=k)
+        return f
+
+    def dbl_half(carry):
+        """Shared squaring + all pairs' doubling step (every iteration)."""
+        f, Tc = carry
+        f = F.flat_sqr(f)
+        Tc, lines = pf.g2_dbl_line(Tc, Pt, Mt)
+        return mul_lines(f, lines), Tc
+
+    def add_half(carry):
+        f, Tc = carry
+        Tc, lines = pf.g2_add_line(Tc, Qt, Pt, Mt)
+        return mul_lines(f, lines), Tc
+
+    return Tt, dbl_half, add_half
+
+
+def _xla_halves(pairs, active, shape):
+    """The ladder's two halves in XLA (`_dbl_step`/`_add_step`), which
+    the CPU tier traces, and the T they start from: a tuple of Jacobian
+    points, one a pair."""
+    K = len(pairs)
     Ts = tuple((q[0], q[1], T.fp2_broadcast(T.FP2_ONE, shape)) for _, q in pairs)
 
     def masked_line(line, mask):
@@ -216,8 +273,7 @@ def miller_loop_pairs(pairs, active=None, _keep_tiled=False):
 
     # The K pairs' curve steps run STACKED on one fresh leading axis (the
     # step formulas are batch-generic), so each Miller iteration traces
-    # ONE doubling/addition program instead of K — and on TPU each fused
-    # step kernel launches once over the doubled batch.
+    # ONE doubling/addition program instead of K.
     def _stack_pts(pts):
         return tuple(
             tuple(jnp.stack(
@@ -237,8 +293,9 @@ def miller_loop_pairs(pairs, active=None, _keep_tiled=False):
         for j in range(2))
     _Q_STACK = _stack_pts([q for _, q in pairs])
 
-    def dbl_half(f, Ts):
+    def dbl_half(carry):
         """Shared squaring + stacked-pair doubling step (every iteration)."""
+        f, Ts = carry
         f = F.flat_sqr(f)
         Tst, lines = _dbl_step(_stack_pts(Ts), *_P_STACK)
         newTs = _unstack_pts(Tst, 3)
@@ -263,13 +320,7 @@ def miller_loop_pairs(pairs, active=None, _keep_tiled=False):
             newTs.append(Tk)
         return f, tuple(newTs)
 
-    # The parameter's bits are static (field.tail_segments): every bit
-    # runs the doubling half, only the 5 set bits the addition half —
-    # nothing is computed just to be masked away.
-    f, _ = segmented_ladder(_X_SEGMENTS, (f, Ts),
-                            lambda c: dbl_half(*c), add_half)
-    f = F.flat_conj(f)                    # x < 0 (packed on Pallas)
-    return f if _keep_tiled else F.flat_untile(f)
+    return Ts, dbl_half, add_half
 
 
 def _miller_loop_pairs_merged(pf, pairs, active, shape, _keep_tiled=False):

@@ -146,6 +146,19 @@ def tile_split(tf: TileForm, sizes) -> list:
     return outs
 
 
+def tile_stack(tfs) -> TileForm:
+    """Join K TileForms of one batch along the TILE axis, each keeping
+    its own padding: run k of the result's tiles is tfs[k]'s, so one
+    kernel launch walks the K batches as one grid and `flat_mul`'s
+    `b_run` reads one of them back in place.  Layout-preserving, not a
+    crossing; the logical shape is (K, padded rows)."""
+    nt = tfs[0].tiles.shape[0]
+    for t in tfs[1:]:
+        assert t.tiles.shape == tfs[0].tiles.shape, (t.tiles.shape, nt)
+    return TileForm(jnp.concatenate([t.tiles for t in tfs], axis=0),
+                    (len(tfs), nt * TILE), len(tfs) * nt * TILE)
+
+
 def _to_tiles_impl(x, limbs):
     """[..., limbs] -> ([Nt, limbs, 8, 128], batch, count).  Called ONLY
     by TileForm.wrap (tile-seam lint rule)."""
@@ -715,20 +728,25 @@ class PallasField:
         return (arr[..., :N_LIMBS], arr[..., N_LIMBS:])
 
     def _call(self, kernel, limbs_out, *tiles, scratch=None):
+        """One grid step a tile over operands of one tile count; a list
+        `limbs_out` gives a kernel of as many outputs."""
         nt = tiles[0].shape[0]
         spec = lambda l: pl.BlockSpec((1, l, *_ROW), lambda i: (i, 0, 0, 0),
                                       memory_space=pltpu.VMEM)
+        shape = lambda l: jax.ShapeDtypeStruct((nt, l, *_ROW), jnp.int32)
+        many = isinstance(limbs_out, list)
         return self._launch(
             kernel, tiles,
-            out_shape=jax.ShapeDtypeStruct((nt, limbs_out, *_ROW),
-                                           jnp.int32),
+            out_shape=[shape(l) for l in limbs_out] if many
+            else shape(limbs_out),
             grid=(nt,),
             in_specs=[spec(t.shape[1]) for t in tiles],
-            out_specs=spec(limbs_out),
+            out_specs=[spec(l) for l in limbs_out] if many
+            else spec(limbs_out),
             scratch_shapes=scratch or [],
         )
 
-    def _launch(self, kernel, args, **call):
+    def _launch(self, kernel, args, site=(), **call):
         """`pl.pallas_call(kernel, **call)(*args)` through ONE jitted
         wrapper per distinct kernel and operand shapes.
 
@@ -743,7 +761,8 @@ class PallasField:
 
         `kernel` is a bound method or a `functools.partial` of one over
         static (hashable) arguments; `call` is determined by the kernel
-        and the operand shapes, so those two key the wrapper.  The
+        and the operand shapes, so those two key the wrapper, with
+        `site` for what they leave open (an index map's offset).  The
         method's name, less its `_kernel`, names the Pallas call: a
         device trace then says `.../miller/.../mont_mul/pallas_call`
         where the caller's `jax.named_scope` (ops.STAGES) gives the
@@ -753,7 +772,7 @@ class PallasField:
         else:
             key = (kernel.__name__, ())
         name = key[0].strip("_").removesuffix("_kernel")
-        key += (tuple((a.shape, a.dtype.name) for a in args),)
+        key += (tuple((a.shape, a.dtype.name) for a in args), site)
         fn = self._launchers.get(key)
         if fn is None:
             fn = self._launchers[key] = _jit(
@@ -1162,10 +1181,14 @@ class PallasField:
         tf = TileForm(mask.astype(jnp.int32)[:, None], shape, b)
         return tf.unwrap()[..., 0] != 0
 
-    def flat_mul(self, a, b, b_idx):
+    def flat_mul(self, a, b, b_idx, b_run=0):
         """Drop-in for flat12.flat_mul: a [..., 12, 32], b [..., J, 32]
         (or TileForm operands in the 12*32 / J*32 packed row layouts —
-        the Miller accumulator path; output kind follows `a`)."""
+        the Miller accumulator path; output kind follows `a`).  Where b
+        holds several batches of a's joined on the tile axis
+        (`tile_stack`: the Miller step kernels' lines, a run a pair),
+        `b_run` says which multiplies; the block index map reads it in
+        place, no slice."""
         J = len(b_idx)
         K = 11 + max(b_idx) + 1
         a_tiled = isinstance(a, TileForm)
@@ -1190,21 +1213,24 @@ class PallasField:
                                 J * N_LIMBS)
             at, bt, n = atf.tiles, btf.tiles, atf.b
         nt = at.shape[0]
+        first = b_run * nt                # b's first tile of the run
+        assert first + nt <= bt.shape[0], (b_run, nt, bt.shape)
         tab, pairs, K = _flat_mul_tab(tuple(b_idx))
         offs = self._flat_acc_offsets(K, pairs)
         kernel = functools.partial(
             self._flat_mul_kernel, tuple(b_idx), offs)
-        spec = lambda l: pl.BlockSpec((1, l, *_ROW), lambda i: (i, 0, 0, 0),
-                                      memory_space=pltpu.VMEM)
+        spec = lambda l, first=0: pl.BlockSpec(
+            (1, l, *_ROW), lambda i: (i + first, 0, 0, 0),
+            memory_space=pltpu.VMEM)
         out = self._launch(
-            kernel, (jnp.asarray(tab), at, bt),
+            kernel, (jnp.asarray(tab), at, bt), site=(first,),
             out_shape=jax.ShapeDtypeStruct((nt, 12 * N_LIMBS, *_ROW),
                                            jnp.int32),
             grid=(nt,),
             in_specs=[
                 pl.BlockSpec((K, 12), lambda i: (0, 0),
                              memory_space=pltpu.SMEM),
-                spec(12 * N_LIMBS), spec(J * N_LIMBS)],
+                spec(12 * N_LIMBS), spec(J * N_LIMBS, first)],
             out_specs=spec(12 * N_LIMBS),
             scratch_shapes=[pltpu.VMEM((13 * 2 * N_LIMBS, *_ROW),
                                        jnp.int32)],
@@ -1470,14 +1496,33 @@ class PallasField:
     def _unstk(rows, i):
         return [r[i] for r in rows]
 
-    def _g2_dbl_line_kernel(self, off, a_ref, o_ref):
-        c = self._read_coords(a_ref, 8)
+    def _line_flat_rows(self, line):
+        """`pairing.line_to_flat` on row lists: the Fp2 triple's six
+        sparse flat slots, `lo = re - im` of a, b, c (one stacked
+        subtraction), then the three `im`."""
+        a_l, b_l, c_l = line
+        los = self._sub_rows(self._stack3(a_l[0], b_l[0], c_l[0]),
+                             self._stack3(a_l[1], b_l[1], c_l[1]))
+        return [self._unstk(los, i) for i in range(3)] + \
+            [a_l[1], b_l[1], c_l[1]]
+
+    def _write_masked_line(self, lo_ref, mask, line):
+        """The step's line as `flat_mul`'s sparse packed operand (slot s
+        at limb rows [s*32, (s+1)*32)), the neutral line (1, 0, ..., 0)
+        where `mask` is false."""
+        for s, rows in enumerate(self._line_flat_rows(line)):
+            for l in range(N_LIMBS):
+                neutral = int(self.ONE_MONT[l]) if s == 0 else 0
+                lo_ref[0, s * N_LIMBS + l] = jnp.where(
+                    mask, rows[l], jnp.full(_ROW, neutral, jnp.int32))
+
+    def _g2_dbl_line_kernel(self, off, t_ref, p_ref, m_ref, to_ref, lo_ref):
+        c = self._read_coords(t_ref, 6)
+        xp, yp = self._read_coords(p_ref, 2)
         T2, line = self._g2_dbl_line_rows(
-            off, (c[0], c[1]), (c[2], c[3]), (c[4], c[5]), c[6], c[7])
-        (X2, Y2, Z2), (a_l, b_l, c_l) = T2, line
-        self._write_coords(o_ref, [
-            X2[0], X2[1], Y2[0], Y2[1], Z2[0], Z2[1],
-            a_l[0], a_l[1], b_l[0], b_l[1], c_l[0], c_l[1]])
+            off, (c[0], c[1]), (c[2], c[3]), (c[4], c[5]), xp, yp)
+        self._write_coords(to_ref, [r for coord in T2 for r in coord])
+        self._write_masked_line(lo_ref, m_ref[0, 0] != 0, line)
 
     def _g2_dbl_line_rows(self, off, X, Y, Z, xp, yp):
         """The complete Miller doubling-step body on Fp2 row pairs —
@@ -1528,15 +1573,20 @@ class PallasField:
         return ((X2, Y2, Z2),
                 (a_l, (un(sc, 0), un(sc, 1)), (un(sc, 2), un(sc, 3))))
 
-    def _g2_add_line_kernel(self, off, a_ref, o_ref):
-        c = self._read_coords(a_ref, 12)
+    def _g2_add_line_kernel(self, off, t_ref, q_ref, p_ref, m_ref, to_ref,
+                            lo_ref):
+        c = self._read_coords(t_ref, 6)
+        q = self._read_coords(q_ref, 4)
+        xp, yp = self._read_coords(p_ref, 2)
         T3, line = self._g2_add_line_rows(
             off, (c[0], c[1]), (c[2], c[3]), (c[4], c[5]),
-            (c[6], c[7]), (c[8], c[9]), c[10], c[11])
-        (X3, Y3, Z3), (a_l, b_l, c_l) = T3, line
-        self._write_coords(o_ref, [
-            X3[0], X3[1], Y3[0], Y3[1], Z3[0], Z3[1],
-            a_l[0], a_l[1], b_l[0], b_l[1], c_l[0], c_l[1]])
+            (q[0], q[1]), (q[2], q[3]), xp, yp)
+        mask = m_ref[0, 0] != 0
+        # an inactive row keeps its T (the XLA path's fp2_select)
+        self._write_coords(to_ref, [
+            _select_rows(mask, new, old)
+            for new, old in zip((r for coord in T3 for r in coord), c)])
+        self._write_masked_line(lo_ref, mask, line)
 
     def _g2_add_line_rows(self, off, X, Y, Z, xq, yq, xp, yp):
         """Miller mixed-addition step body on Fp2 row pairs (shared by
@@ -1616,32 +1666,29 @@ class PallasField:
         out = self._call(kernel, n_out * N_LIMBS, at.tiles)
         return self.unpack_coords(TileForm(out, at.shape, at.b), n_out)
 
-    def g2_dbl_line(self, Tj, xp, yp):
-        """Fused Miller doubling step: Jacobian T (Fp2) + P affine Fp ->
-        (T', line) exactly as pairing._dbl_step."""
+    def _line_step(self, kernel, mask, T, *fixed):
+        """One Miller step kernel on packed state: T (6 coords) and the
+        ladder's fixed points as separate tile operands, `mask` the
+        rows' `active` as int32 [tiles, 1, 8, 128]; two outputs, T' and
+        the masked line in `flat_mul`'s sparse packed layout."""
         from drand_tpu.ops.towers import _WIDE_NEG_OFF
-        X, Y, Z = Tj
-        kernel = functools.partial(
-            self._g2_dbl_line_kernel, tuple(int(v) for v in _WIDE_NEG_OFF))
-        o = self._coords_call(
-            kernel, [X[0], X[1], Y[0], Y[1], Z[0], Z[1], xp, yp], 12)
-        T2 = ((o[0], o[1]), (o[2], o[3]), (o[4], o[5]))
-        line = ((o[6], o[7]), (o[8], o[9]), (o[10], o[11]))
-        return T2, line
+        t_out, line = self._call(
+            functools.partial(kernel, tuple(int(v) for v in _WIDE_NEG_OFF)),
+            [6 * N_LIMBS] * 2, T.tiles, *(x.tiles for x in fixed), mask)
+        return TileForm(t_out, T.shape, T.b), TileForm(line, T.shape, T.b)
 
-    def g2_add_line(self, Tj, Q, xp, yp):
-        """Fused Miller mixed-addition step (pairing._add_step)."""
-        from drand_tpu.ops.towers import _WIDE_NEG_OFF
-        X, Y, Z = Tj
-        xq, yq = Q
-        kernel = functools.partial(
-            self._g2_add_line_kernel, tuple(int(v) for v in _WIDE_NEG_OFF))
-        o = self._coords_call(
-            kernel, [X[0], X[1], Y[0], Y[1], Z[0], Z[1],
-                     xq[0], xq[1], yq[0], yq[1], xp, yp], 12)
-        T2 = ((o[0], o[1]), (o[2], o[3]), (o[4], o[5]))
-        line = ((o[6], o[7]), (o[8], o[9]), (o[10], o[11]))
-        return T2, line
+    def g2_dbl_line(self, T: TileForm, P: TileForm, mask):
+        """Fused Miller doubling step on packed state: Jacobian T (Fp2,
+        6 coords) and P (xp, yp) -> (T', line), `pairing._dbl_step` with
+        `line_to_flat` and the neutral line of an inactive row done in
+        the kernel."""
+        return self._line_step(self._g2_dbl_line_kernel, mask, T, P)
+
+    def g2_add_line(self, T: TileForm, Q: TileForm, P: TileForm, mask):
+        """Fused Miller mixed-addition step on packed state
+        (`pairing._add_step`; Q = (xq, yq), 4 coords): as `g2_dbl_line`,
+        and an inactive row keeps its T."""
+        return self._line_step(self._g2_add_line_kernel, mask, T, Q, P)
 
     # -- fused G2 Jacobian point kernels (ladder bodies) -------------------
     #
@@ -2042,13 +2089,7 @@ class PallasField:
         layout: [a0-a1, b0-b1, c0-c1, a1, b1, c1]), select the neutral
         line (1, 0, ..., 0) where the pair is inactive, and stage line p
         at lbuf groups [p*6, p*6+6)."""
-        a_l, b_l, c_l = line
-        st = self._stack3
-        un = self._unstk
-        los = self._sub_rows(st(a_l[0], b_l[0], c_l[0]),
-                             st(a_l[1], b_l[1], c_l[1]))
-        groups = [un(los, 0), un(los, 1), un(los, 2),
-                  a_l[1], b_l[1], c_l[1]]
+        groups = self._line_flat_rows(line)
         for p in range(2):
             mask = m_ref[0, p] != 0
             for gi, rows in enumerate(groups):

@@ -103,3 +103,39 @@ def sim_kernels(tile=8, row=(1, 8)):
         PFm._jit = orig_jit
         PFm.TILE, PFm._ROW = orig_tile, orig_row
         PFm._CACHE.clear()
+
+
+def assert_line_steps_match_xla(pf, Tj, Q, xp, yp, active):
+    """`pf.g2_dbl_line`/`pf.g2_add_line` on packed state against the XLA
+    `_dbl_step`/`_add_step` (the oracle: on the CPU `use_pallas()` is
+    False): T' coordinate for coordinate, the line as `line_to_flat`
+    lays it out, and where `active` is false the neutral line and, on an
+    addition, the old T."""
+    from drand_tpu.ops import pairing as DP
+    from drand_tpu.ops import pallas_field as PFm
+    on = np.asarray(active)
+    Tt = pf.g2_pack_point(Tj)
+    Pt = pf.pack_coords([xp, yp])
+    Qt = pf.pack_coords([Q[0][0], Q[0][1], Q[1][0], Q[1][1]])
+    mask = PFm.TileForm.wrap(jnp.asarray(on)[:, None], 1).tiles
+    before = PFm.layout_conversion_counts()
+    steps = {"dbl": pf.g2_dbl_line(Tt, Pt, mask),
+             "add": pf.g2_add_line(Tt, Qt, Pt, mask)}
+    assert PFm.layout_conversion_counts() == before    # kernels only
+    want = {"dbl": DP._dbl_step(Tj, xp, yp),
+            "add": DP._add_step(Tj, Q, xp, yp)}
+    for name, (t_new, line) in steps.items():
+        t_want, line_want = want[name]
+        if name == "add":
+            t_want = jax.tree_util.tree_map(
+                lambda new, old: jnp.where(on[:, None], new, old),
+                t_want, Tj)
+        for got, ref in zip(jax.tree_util.tree_leaves(
+                pf.g2_unpack_point(t_new)),
+                jax.tree_util.tree_leaves(t_want)):
+            assert (np.asarray(got) == np.asarray(ref)).all(), name
+        flat = np.where(on[:, None, None],
+                        np.asarray(DP.line_to_flat(line_want)),
+                        np.asarray(DP._LINE_ONE_FLAT))
+        got = np.asarray(line.unwrap()).reshape(flat.shape)
+        assert (got == flat).all(), name

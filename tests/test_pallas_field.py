@@ -193,13 +193,15 @@ def test_pallas_cyclo_sqr_matches_golden(sim):
 
 
 def test_pallas_miller_step_kernels_match_xla(sim):
-    """Fused g2_dbl_line/g2_add_line vs the XLA _dbl_step/_add_step
-    (identical formulas; the CPU suite keeps use_pallas() False so the
-    XLA path is the oracle)."""
+    """Fused g2_dbl_line/g2_add_line on packed state vs the XLA
+    _dbl_step/_add_step (identical formulas; the CPU suite keeps
+    use_pallas() False so the XLA path is the oracle), with one row of
+    the two inactive: its line the neutral one, its T kept on the
+    addition."""
     from drand_tpu.crypto.bls12381 import curve as GC
     from drand_tpu.crypto.bls12381.constants import R
-    from drand_tpu.ops import pairing as DP
     from drand_tpu.ops import towers as T
+    from pallas_sim import assert_line_steps_match_xla
     pf = PFm.PallasField(P)
     ts = [GC.g2_mul(GC.G2_GEN, rng.randrange(1, R)) for _ in range(2)]
     qs = [GC.g2_affine(GC.g2_mul(GC.G2_GEN, rng.randrange(1, R)))
@@ -210,20 +212,7 @@ def test_pallas_miller_step_kernels_match_xla(sim):
     Q = tuple(T.fp2_encode([q[k] for q in qs]) for k in range(2))
     xp = jnp.asarray(FP.encode([p[0] for p in ps]))
     yp = jnp.asarray(FP.encode([p[1] for p in ps]))
-
-    def assert_same(a, b):
-        for x, y in zip(jax.tree_util.tree_leaves(a),
-                        jax.tree_util.tree_leaves(b)):
-            assert (np.asarray(x) == np.asarray(y)).all()
-
-    T2x, linex = DP._dbl_step(Tj, xp, yp)       # XLA oracle (pallas off)
-    T2k, linek = pf.g2_dbl_line(Tj, xp, yp)
-    assert_same(T2x, T2k)
-    assert_same(linex, linek)
-    A2x, alinex = DP._add_step(Tj, Q, xp, yp)
-    A2k, alinek = pf.g2_add_line(Tj, Q, xp, yp)
-    assert_same(A2x, A2k)
-    assert_same(alinex, alinek)
+    assert_line_steps_match_xla(pf, Tj, Q, xp, yp, [True, False])
 
 
 def test_pallas_point_kernels_match_xla(sim):

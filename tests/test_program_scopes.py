@@ -102,11 +102,39 @@ def test_each_programs_miller_stage_names_its_kernels(loop, kernels):
             return getattr(DP, loop)(*a)
 
     with mock.patch.object(PFm, "use_pallas", return_value=True):
-        text = jax.jit(run).trace(*args).lower(
+        traced = jax.jit(run).trace(*args)
+        text = traced.lower(
             lowering_platforms=("tpu",)).as_text(debug_info=True)
     assert set(re.findall(r'kernel_name = "([^"]*)"', text)) == kernels
     sites = re.findall(r'loc\("jit\(run\)/([^"]*)/jit\(wrapped\)"', text)
     assert sites and all(s.split("/")[0] == ops.MILLER for s in sites)
+    if loop == "miller_loop_pairs":
+        # ISSUE 39: the ladder's body is kernels on tile-layout state.
+        # Between them no limb array is joined or cut, and nothing is
+        # selected in [..., 32] layout: T, P, Q and the masks crossed
+        # into tile layout before the `while`, the line never leaves it
+        (scan,) = [e for e in _equations(traced.jaxpr.jaxpr)
+                   if e.primitive.name == "scan"]
+        body = list(_equations(scan.params["jaxpr"].jaxpr))
+        names = [e.primitive.name for e in body]
+        assert names.count("pallas_call") == 7      # 4 + 3 on a set bit
+        assert "cond" in names
+        assert not {"concatenate", "slice", "dynamic_slice", "gather",
+                    "transpose", "reshape"} & set(names), names
+        assert not [e for e in body if e.primitive.name == "select_n"
+                    and e.outvars[0].aval.shape[-1:] == (32,)]
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (a
+    `jit`'s, a `scan`'s body, a `cond`'s branches), a Pallas kernel's own
+    body left out: what XLA, not Mosaic, is handed."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
 
 
 def _two_stage_program(pf, scoped: bool):

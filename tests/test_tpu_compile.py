@@ -15,7 +15,7 @@ a chip run.
 Left out for time (each was compiled once by hand for PR 22, both at one
 tile and at the 16 tiles of the 16,384 bucket; seconds in CHANGES.md):
 `mont_reduce`, `flat_mul` (dense and sparse), `fp2_sqr5_mul`/`sqr4_mul`
-and the `sqr_chain_mul` family, `g2_add_line`, `g2_point_dbl`/
+and the `sqr_chain_mul` family, `g2_point_dbl`/
 `g2_point_add`, `line_merge`, `flat_conj`/`flat_frob`, and the wider
 `fp2_products`/`fp2_sqrs` stackings.  The two Miller kernels stay in
 although each takes minutes: they are the ones the compiler refused.
@@ -70,18 +70,6 @@ def _tf(tiles):
     return PFm.TileForm(tiles, (NT * PFm.TILE,), NT * PFm.TILE)
 
 
-def _coords(tiles, n):
-    """n plain [..., 32] coordinate arrays out of one packed operand."""
-    return PFm.pallas_field(P).unpack_coords(_tf(tiles), n)
-
-
-def _g2_dbl_line(pf, t):
-    c = _coords(t, 8)
-    T2, line = pf.g2_dbl_line(((c[0], c[1]), (c[2], c[3]), (c[4], c[5])),
-                              c[6], c[7])
-    return T2, line
-
-
 # name -> (function of the PallasField and tile operands, limb rows of
 # each operand)
 KERNELS = {
@@ -94,7 +82,14 @@ KERNELS = {
     "fp2_sqrs": (lambda pf, a: pf.fp2_sqrs([_tf(a)])[0].tiles, (64,)),
     "flat_sqr": (lambda pf, a: pf.flat_sqr(_tf(a)).tiles, (384,)),
     "cyclo_sqr": (lambda pf, a: pf.cyclo_sqr(_tf(a)).tiles, (384,)),
-    "g2_dbl_line": (_g2_dbl_line, (256,)),
+    "g2_dbl_line": (
+        lambda pf, t, p, m: [o.tiles for o in pf.g2_dbl_line(
+            _tf(t), _tf(p), m)],
+        (192, 64, 1)),
+    "g2_add_line": (
+        lambda pf, t, q, p, m: [o.tiles for o in pf.g2_add_line(
+            _tf(t), _tf(q), _tf(p), m)],
+        (192, 128, 64, 1)),
     "miller_dbl_iter": (
         lambda pf, f, t, p, m: [o.tiles for o in pf.miller_dbl_iter(
             _tf(f), _tf(t), _tf(p), _tf(m))],
@@ -174,3 +169,46 @@ def test_the_fixed_q_miller_loop_compiles_for_v5e(one_chip):
     names = re.findall(r"/(\w+)/pallas_call", text)
     assert set(names) == {"flat_sqr", "mont_mul", "flat_mul", "flat_conj"}
     assert f"s32[{8 * NT},32,8,128]" in text      # the step's one launch
+
+
+def test_the_tiled_g2_miller_loop_compiles_for_v5e(one_chip):
+    """ISSUE 39's loop as the served G2 programs trace it: one `while`
+    whose body is `flat_sqr`, the two-output `g2_dbl_line` over both
+    pairs' tiles, and two `flat_mul`, the second reading its line at an
+    offset of the first's tile count; `g2_add_line` and its two
+    `flat_mul` under one `conditional`.  In the body the compiler keeps
+    nothing in limb layout: what is not a kernel is whole-buffer copies
+    of its own placing."""
+    from unittest import mock
+
+    from drand_tpu.ops import pairing as DP
+    from drand_tpu.ops.field import compact_scope
+
+    def miller(p1, q1, p2, q2, m1, m2):
+        with compact_scope():
+            return DP.miller_loop_pairs([(p1, q1), (p2, q2)], [m1, m2],
+                                        _keep_tiled=True).tiles
+
+    rows = NT * PFm.TILE
+    fp = jax.ShapeDtypeStruct((rows, 32), jnp.int32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
+    g1, g2 = (fp, fp), ((fp, fp), (fp, fp))
+    with mock.patch.object(PFm, "use_pallas", return_value=True):
+        text = jax.jit(miller).lower(g1, g2, g1, g2, mask,
+                                     mask).compile().as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r" conditional\(", text)) == 1
+    names = re.findall(r"/(\w+)/pallas_call", text)
+    assert sorted(names) == ["flat_conj"] + ["flat_mul"] * 4 + \
+        ["flat_sqr", "g2_add_line", "g2_dbl_line"]
+    # both pairs in one launch, T' and the line its two outputs
+    step = re.escape(f"s32[{2 * NT},192,8,128]") + r"\{[^}]*\}"
+    assert re.search(rf"\({step}, {step}\) custom-call\(", text)
+    (body,) = re.findall(r" while\(.*body=%?([\w.\-]+)", text)
+    in_body = text[text.index(f"{body} ("):]
+    in_body = in_body[:in_body.index("\n}")]
+    assert "custom-call(" in in_body
+    for op in ("concatenate(", " slice(", "select(", "transpose(", "pad("):
+        assert op not in in_body, op
+    assert ",32]" not in in_body              # no [..., 32] array at all
+
