@@ -7,9 +7,13 @@ beacon one at a time (`sync_manager.go:397-399`, the serial loop SURVEY.md
 whole contiguous segments in ONE batched device call
 (`ChainVerifier.verify_chain_segment`) before appending.
 
-Also implements the local-chain validation/repair pair:
-`check_past_beacons` (`:171-232`) batch-verifies the whole local store and
-`correct_past_beacons` (`:234-265`) re-fetches the faulty rounds.
+Also implements the local-chain validation/repair pair behind `drand
+util check` (`SyncManager.check_chain`): the check (`check_past_beacons`,
+`:171-232`) is the start-up scan's `recovery.scan_store` over the
+insecure store, the repair (`correct_past_beacons`, `:234-265`) fetches
+the flagged rounds in contiguous runs, verifies every replacement of a
+check in one batch over the consumer's own links, and overwrites what
+verified in one transaction.
 """
 
 from __future__ import annotations
@@ -17,16 +21,19 @@ from __future__ import annotations
 import asyncio
 import contextlib
 import gc
+import itertools
 import os
 import random
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import numpy as np
 
 from drand_tpu import log as dlog
 from drand_tpu import tracing
+from drand_tpu.chain import codec as row_codec
+from drand_tpu.chain import recovery
 from drand_tpu.chain.beacon import Beacon
 from drand_tpu.chain.segment import PackedBeacons, pack_rows
 from drand_tpu.chain.store import BeaconNotFound, StoreError
@@ -112,41 +119,6 @@ def _item_tail_sig(item) -> bytes:
 class SyncRequest:
     from_round: int
     up_to: int = 0            # 0 = follow forever / to head
-
-
-class _SegmentPipeline:
-    """Depth-1 dispatch/settle pipeline for batched segment verification.
-
-    Holds ONE in-flight (segment, resolver) pair: `record` settles the
-    previous segment before recording the new one (the caller dispatches
-    the device work FIRST, so segment k+1's transfer/dispatch overlaps
-    segment k's compute), `settle` resolves whatever is in flight.
-    `on_settled(segment, ok_array) -> bool` owns what "settled" means —
-    commit-to-store for sync, extend-faulty for check — and its False
-    aborts the caller's loop."""
-
-    def __init__(self, on_settled):
-        self._on_settled = on_settled
-        self._pending = None
-
-    def record(self, segment, resolver) -> bool:
-        if not self.settle():
-            # Drop the new segment: settling it later would commit rounds
-            # PAST the failed one, gapping the chain.  The freshly
-            # dispatched resolver is deliberately abandoned unresolved —
-            # JAX async dispatch tolerates never-fetched results (the
-            # device work completes and is garbage-collected); nothing
-            # here holds a resource that needs explicit release.
-            return False
-        self._pending = (segment, resolver)
-        return True
-
-    def settle(self) -> bool:
-        if self._pending is None:
-            return True
-        seg, resolve = self._pending
-        self._pending = None
-        return self._on_settled(seg, np.asarray(resolve()))
 
 
 class _CatchupPipeline:
@@ -782,85 +754,314 @@ class SyncManager:
 
     # -- local validation & repair (sync_manager.go:171-265) ----------------
 
+    def _scannable(self):
+        """The store the check reads: the insecure one, whose `raw_rows`
+        show damaged rows instead of dying on them."""
+        store = self._repair_store()
+        return store if hasattr(store, "raw_rows") else _RawRows(store)
+
+    async def _scan(self, up_to: int | None, on_progress=None):
+        target = up_to or 0
+        if on_progress is not None and not target:
+            with contextlib.suppress(StoreError):   # empty, or a torn tip
+                target = self.store.last().round
+        return await recovery.scan_store(
+            self._scannable(), self.verifier, beacon_id=self.beacon_id,
+            up_to=up_to or None,
+            on_progress=None if on_progress is None
+            else lambda current: on_progress(current, max(target, current)))
+
     def check_past_beacons(self, up_to: int | None = None,
                            on_progress=None) -> list[int]:
-        """Batch-verify the whole local chain; returns faulty rounds.
-
-        Pipelined like the sync loop: chunk k+1 is read from the store and
-        dispatched while chunk k's batched verify runs on the device."""
-        faulty: list[int] = []
-        try:
-            last = self.store.last()
-        except BeaconNotFound:
-            return faulty
-        top = min(up_to or last.round, last.round)
-        prev = None
-        chunk: list[Beacon] = []
-
-        def note_faulty(seg, ok) -> bool:
-            faulty.extend(seg[i].round for i in np.nonzero(~ok)[0])
-            return True                      # keep scanning past bad rounds
-
-        pipeline = _SegmentPipeline(note_faulty)
-
-        def dispatch(seg, anchor):
-            anchor_sig = anchor.signature if anchor is not None else b""
-            pipeline.record(seg, self.verifier.verify_chain_segment_async(
-                seg, anchor_sig))
-
-        for beacon in self.store.iter_range(0):
-            if beacon.round == 0:
-                prev = beacon
-                continue
-            if beacon.round > top:
-                break
-            chunk.append(beacon)
-            if len(chunk) >= SYNC_CHUNK:
-                dispatch(chunk, prev)
-                prev = chunk[-1]
-                chunk = []
-        if chunk:
-            dispatch(chunk, prev)
-        pipeline.settle()
-        if on_progress:
-            on_progress(top, top)
-        return faulty
+        """Verify the whole local chain; returns the damaged rounds.  For
+        callers outside an event loop: the check is `check_chain`'s, the
+        start-up scan's one scanner (`recovery.scan_store`), and what it
+        files under `missing` is not in a bare list of rounds."""
+        return asyncio.run(self._scan(up_to, on_progress)).damaged_rounds
 
     async def correct_past_beacons(self, faulty: list[int]) -> int:
-        """Re-fetch invalid rounds from peers and overwrite them
-        (sync_manager.go:234-265)."""
-        fixed = 0
-        if not faulty:
-            return 0
-        peers = [n for n in self.nodes]
+        """Re-fetch the given rounds from peers and overwrite them
+        (sync_manager.go:234-265); how many were mended.  The rounds
+        before and after each run of them are the caller's word: they
+        anchor the replacements."""
+        return len((await self._mend(faulty))["fixed"])
+
+    async def check_chain(self, up_to: int | None = None,
+                          on_progress=None) -> "CheckResult":
+        """`drand util check`: scan the stored chain (to `up_to`, where
+        one is given) and mend in place what the scan files.
+
+        The check is `recovery.scan_store` over the insecure store; its
+        `on_progress(current, target)` is the scan's.  To mend are the
+        rounds of the report's four lists, every missing round among
+        them.  They are fetched in contiguous runs (`_fetch_runs`), all
+        replacements of a check are verified in as few dispatches as the
+        verifier's `rows_charged` allows, each over the signature the
+        CONSUMER holds before it (the stored signature of the sound row
+        before a run, inside a run the replacement before: a served
+        `previous_sig` is never an input), and those that verified, and
+        only those, are written in one transaction through the insecure
+        store.  A replacement that fails, or a run's last that the sound
+        row after it does not link to, leaves its row as it was; what one
+        peer could not mend the next is asked for, and what no peer
+        could is `unfixed`.  Nothing is deleted, and a row the scan
+        found sound is never written.
+
+        One trace a check: the root `check.chain` (scanned, flagged,
+        runs, streams, fixed, unfixed; the event loop's lag while it is
+        open) over the scan's `store.scan` tree, then for every peer
+        asked `check.fetch` (runs, streams, rounds, messages, bytes,
+        `wait_s`, and the network layer's `recv_s`, `decode_s`; the
+        peer's `sync.serve` joins it through the request's metadata),
+        `check.edges` (the stored rows around the runs), `check.verify_wait`
+        over the verifier's `verify.dispatch` and `verify.resolve`, and
+        `check.overwrite` (rows; `encode_s`, `insert_s`, `flush_s` from
+        `SqliteStore.put_many`)."""
+        with tracing.span("check.chain", beacon_id=self.beacon_id,
+                          up_to=up_to or 0) as root, \
+                tracing.loop_watched(root):
+            report = await self._scan(up_to, on_progress)
+            want = report.damaged_rounds
+            for first, last in report.missing:
+                want.extend(range(first, last + 1))
+            mended = await self._mend(want)
+            result = CheckResult(report=report, **mended)
+            root.set(scanned=report.scanned, flagged=len(want),
+                     runs=result.runs, streams=result.streams,
+                     fixed=len(result.fixed), unfixed=len(result.unfixed))
+        if result.unfixed:
+            log.warning("check chain: %d rounds flagged, %d left unmended "
+                        "(first %s)", len(want), len(result.unfixed),
+                        result.unfixed[:8])
+        elif want:
+            log.info("check chain: %d rounds flagged, all mended", len(want))
+        return result
+
+    async def _mend(self, rounds) -> dict:
+        """Mend `rounds` in place, peer by peer -> what `CheckResult`
+        says of the repair."""
+        want = sorted(set(rounds))
+        out = {"fixed": [], "unfixed": want, "runs": len(_runs(want)),
+               "streams": 0, "dispatches": 0}
+        peers = list(self.nodes)
         random.shuffle(peers)
-        want = set(faulty)
         for peer in peers:
-            if not want:
+            if not out["unfixed"]:
                 break
-            try:
-                done = False
-                async for item in self.net.sync_chain(peer, min(want)):
-                    # a chunk-capable wire may hand back PackedBeacons;
-                    # repair works per round, so materialize (linkage
-                    # from the server's advisory prev — verify_beacons
-                    # rejects a lie before anything is overwritten)
-                    beacons = item.beacons() \
-                        if isinstance(item, PackedBeacons) else [item]
-                    for beacon in beacons:
-                        if beacon.round in want:
-                            if self.verifier.verify_beacons([beacon])[0]:
-                                self._repair_store().put(beacon)
-                                want.discard(beacon.round)
-                                fixed += 1
-                        if beacon.round >= max(faulty):
-                            done = True
-                            break
-                    if done:
-                        break
-            except Exception:
+            runs = _runs(out["unfixed"])
+            got: dict[int, bytes] = {}
+            with tracing.span(
+                    "check.fetch", runs=len(runs), rounds=len(out["unfixed"]),
+                    peer=getattr(peer, "address", "") or str(peer)) as sp:
+                try:
+                    await self._fetch_runs(peer, runs, got, sp)
+                except Exception as exc:
+                    # what came before the failure is still verified
+                    log.warning(
+                        "check chain: peer %s failed after %d of %d rounds "
+                        "(runs %s...): %s", sp.attrs["peer"], len(got),
+                        len(out["unfixed"]), runs[:4], exc)
+                    sp.set(error=f"{type(exc).__name__}: {exc}"[:200])
+                sp.set(wall_s=time.perf_counter() - sp.start_mono)
+                out["streams"] += sp.attrs.get("streams", 0)
+            if not got:
                 continue
-        return fixed
+            fixed, dispatches = await self._overwrite_verified(runs, got)
+            out["dispatches"] += dispatches
+            out["fixed"] = sorted(out["fixed"] + fixed)
+            out["unfixed"] = sorted(set(out["unfixed"]) - set(fixed))
+        return out
+
+    async def _fetch_runs(self, peer, runs, got: dict, sp) -> None:
+        """The peer's signatures of the rounds of `runs` (ascending
+        (first, last)) into `got`.  A stream is opened at a run's first
+        round and closed when the run is in hand, unless the next run
+        begins within REPAIR_STREAM_REACH rounds of what the stream has
+        delivered: then the stream is read on to it (a message in hand
+        is cheaper than a stream opened).  The counters go on `sp`."""
+        sp.set(streams=0, messages=0, wait_s=0.0)
+        i = 0
+        while i < len(runs):
+            gen = self.net.sync_chain(peer, runs[i][0])
+            sp.add(streams=1)
+            delivered = runs[i][0] - 1
+            try:
+                stream = gen.__aiter__()
+                while i < len(runs) \
+                        and runs[i][0] <= delivered + 1 + REPAIR_STREAM_REACH:
+                    t0 = time.perf_counter()
+                    try:
+                        item = await stream.__anext__()
+                    except StopAsyncIteration:
+                        # the peer's chain ends here: it has no more of
+                        # this run, nor of any after it
+                        return
+                    finally:
+                        sp.add(wait_s=time.perf_counter() - t0)
+                    sp.add(messages=1)
+                    first, last, _n = _item_span(item)
+                    if last <= delivered:
+                        raise StoreError(
+                            f"the stream went back to round {first}")
+                    delivered = last
+                    while i < len(runs) and runs[i][0] <= last:
+                        lo, hi = max(runs[i][0], first), min(runs[i][1], last)
+                        if isinstance(item, PackedBeacons):
+                            for r in range(lo, hi + 1):
+                                got[r] = item.sigs[r - first].tobytes()
+                        elif lo <= hi:
+                            got[item.round] = item.signature
+                        if runs[i][1] > last:
+                            break       # the rest is in a later message
+                        i += 1
+            finally:
+                await gen.aclose()
+
+    def _edges(self, runs) -> dict:
+        """{run: (the stored signature before it or None, the stored
+        `previous_sig` after it or None)}: worker thread."""
+        store = self._scannable()
+
+        def fields(round_: int):
+            rows = store.raw_rows(round_, 1)
+            if not rows or rows[0][0] != round_:
+                return None
+            try:
+                return row_codec.decode_fields(rows[0][1])
+            except row_codec.CodecError:
+                return None
+
+        out = {}
+        for first, last in runs:
+            before, after = fields(first - 1), fields(last + 1)
+            out[first, last] = (before and before[1], after and after[2])
+        return out
+
+    async def _overwrite_verified(self, runs, got: dict):
+        """Verify the replacements in hand and overwrite what verified
+        -> (the rounds mended, the dispatches it took)."""
+        chained = not self.verifier.scheme.decouple_prev_sig
+        with tracing.span("check.edges", runs=len(runs)):
+            edges = await asyncio.to_thread(self._edges, runs)
+        beacons: list[Beacon] = []
+        unlinked_last = set()      # a run's last that the row after denies
+        for run in runs:
+            first, last = run
+            before, after = edges[run]
+            if first == 1 and before is None:
+                before = getattr(self.group, "genesis_seed", None)
+            prev = before
+            for r in range(first, last + 1):
+                sig = got.get(r)
+                if sig is None or (chained and prev is None):
+                    # nothing to verify it over: the rest of the run waits
+                    # for a peer that serves the round before it
+                    break
+                beacons.append(Beacon(round=r, signature=sig,
+                                      previous_sig=prev if chained else b""))
+                prev = sig
+            else:
+                if chained and after and after != prev:
+                    unlinked_last.add(last)
+        if not beacons:
+            return [], 0
+        cap = min(len(beacons), SYNC_CHUNK_MAX)
+        rows_charged = getattr(self.verifier, "rows_charged", None)
+        if rows_charged is not None:
+            cap = max(cap, rows_charged(cap))
+        with tracing.span("check.verify_wait", rows=len(beacons)) as sp:
+            batches = [beacons[i:i + cap]
+                       for i in range(0, len(beacons), cap)]
+            ok = await recovery._in_worker(self._verify_batches, batches)
+            sp.set(dispatches=len(batches),
+                   wall_s=time.perf_counter() - sp.start_mono)
+        verified = [b for b, fine in zip(beacons, ok) if fine]
+        good = [b for b in verified if b.round not in unlinked_last]
+        denied = [b.round for b in verified if b.round in unlinked_last]
+        if denied:
+            log.warning("check chain: replacements of %s verified but the "
+                        "stored rows after them do not link to them; left "
+                        "as they were", denied)
+        if good:
+            with tracing.span("check.overwrite", rows=len(good)) as sp, \
+                    _collector_paused():
+                await asyncio.to_thread(self._repair_store().put_many, good)
+                sp.set(wall_s=time.perf_counter() - sp.start_mono)
+        return [b.round for b in good], len(batches)
+
+    def _verify_batches(self, batches) -> list[bool]:
+        """Worker thread: every batch dispatched, then every one
+        awaited."""
+        resolvers = [recovery.dispatch_rows(self.verifier, batch)
+                     for batch in batches]
+        return [bool(v) for resolve in resolvers for v in resolve()]
+
+
+# a repair's stream is read on to the next run where that begins within
+# this many rounds of what it has delivered, two wire messages: opening a
+# stream costs about as much as the peer's read and send of a message
+# (PERF.md, PR 41)
+REPAIR_STREAM_REACH = 1024
+
+
+def _runs(rounds) -> list[tuple[int, int]]:
+    """(first, last) of the contiguous runs of ascending `rounds`."""
+    out: list[tuple[int, int]] = []
+    for r in rounds:
+        if out and r == out[-1][1] + 1:
+            out[-1] = (out[-1][0], r)
+        else:
+            out.append((r, r))
+    return out
+
+
+class _RawRows:
+    """A store that has no `raw_rows` (an in-memory fake) as the scan
+    reads one: its beacons, encoded as sqlite would hold them."""
+
+    path = ""
+
+    def __init__(self, store):
+        self._store = store
+        self._encode = row_codec.make_encoder(None)
+
+    def raw_rows(self, start_round: int, limit: int):
+        return [(b.round, self._encode(b)) for b in itertools.islice(
+            self._store.iter_range(start_round), limit)]
+
+
+@dataclass
+class CheckResult:
+    """What `SyncManager.check_chain` found and what it did about it."""
+
+    report: "recovery.IntegrityReport"
+    fixed: list[int] = field(default_factory=list)      # rounds mended
+    unfixed: list[int] = field(default_factory=list)    # flagged, left
+    runs: int = 0           # contiguous runs of the flagged rounds
+    streams: int = 0        # SyncChain streams opened for them
+    dispatches: int = 0     # verifier dispatches of the replacements
+
+    @property
+    def scanned(self) -> int:
+        return self.report.scanned
+
+    @property
+    def flagged(self) -> int:
+        return len(self.fixed) + len(self.unfixed)
+
+    def counts(self) -> dict[str, int]:
+        """What `util check` prints, in its order."""
+        return {"scanned": self.scanned, "flagged": self.flagged,
+                "fixed": len(self.fixed), "unfixed": len(self.unfixed)}
+
+    def to_dict(self) -> dict:
+        rep = self.report.to_dict()
+        return {"scanned": self.scanned, "flagged": self.flagged,
+                **{k: rep[k] for k in ("corrupt", "unlinked", "bad_sigs",
+                                       "missing", "tip_round")},
+                "fixed": list(self.fixed), "unfixed": list(self.unfixed),
+                "runs": self.runs, "streams": self.streams,
+                "dispatches": self.dispatches}
 
 
 def _payload_bytes(item) -> int:

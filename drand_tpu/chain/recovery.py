@@ -42,7 +42,7 @@ import numpy as np
 
 from drand_tpu import log as dlog
 from drand_tpu.chain import codec as row_codec
-from drand_tpu.chain.beacon import GENESIS_ROUND
+from drand_tpu.chain.beacon import GENESIS_ROUND, Beacon
 from drand_tpu.chain.segment import PackedBeacons, pack_rows
 
 log = dlog.get("chain.recovery")
@@ -123,11 +123,28 @@ def _flush_rounds(verifier) -> int:
     return rows_charged(SCAN_SEGMENT_ROUNDS)
 
 
+def dispatch_rows(verifier, beacons: list[Beacon]):
+    """Rows that are no contiguous run (what damage leaves of a flush,
+    the replacements of a repair) as ONE batch, each row over its own
+    `previous_sig`: a zero-arg resolver of bool[B].  Dispatched here
+    where the verifier can (`ChainVerifier.verify_beacons_async`: one
+    program for all of them), else verified when the resolver is
+    called."""
+    dispatch = getattr(verifier, "verify_beacons_async", None)
+    if dispatch is not None:
+        return dispatch(beacons)
+    return lambda: verifier.verify_beacons(beacons)
+
+
 async def scan_store(store, verifier=None, *, beacon_id: str = "",
                      segment_rounds: int | None = None,
                      read_batch: int = SCAN_READ_BATCH,
-                     on_progress=None) -> IntegrityReport:
+                     on_progress=None,
+                     up_to: int | None = None) -> IntegrityReport:
     """One streaming pass over the stored chain -> IntegrityReport.
+    Rows above `up_to`, where one is given, are neither read nor judged
+    (`drand util check --up-to`): the report's tip is the last row at
+    or below it.
 
     `store` is the UNDECORATED SqliteStore (its `raw_rows` feed sees
     damaged blobs instead of dying on them).  With `verifier=None` only
@@ -174,7 +191,7 @@ async def scan_store(store, verifier=None, *, beacon_id: str = "",
         try:
             report = await _scan_store(store, ahead, beacon_id,
                                        segment_rounds, read_batch,
-                                       on_progress)
+                                       on_progress, up_to)
         except BaseException:
             await ahead.abandon()
             raise
@@ -199,61 +216,64 @@ async def _in_worker(fn, *args):
 
 class _DispatchedAhead:
     """The flushes a scan has dispatched and not settled, oldest first.
-    A flush is its packed segments' (start_round, resolver) in dispatch
-    order, each taken off as it is called, and the rows that verify one
-    by one."""
+    A flush is ONE dispatch: its (rounds, resolver), taken off as the
+    resolver is called."""
 
     def __init__(self, verifier):
         self.verifier = verifier
-        self.flushes: deque[tuple[deque, list]] = deque()
+        self.flushes: deque[deque] = deque()
         self.overlapped = 0     # flushes dispatched with another out
 
     def __len__(self) -> int:
         return len(self.flushes)
 
-    async def dispatch(self, items) -> None:
-        """Enqueue a flush's packed segments behind what is out."""
+    async def dispatch(self, items, rows) -> None:
+        """Enqueue a flush behind what is out: `rows` are its (round,
+        sig, prev), `items` what `pack_rows` made of them."""
         self.overlapped += bool(self.flushes)
-        # on record before its first dispatch: a resolver handed out is
-        # then found by whatever ends the scan
-        self.flushes.append((deque(), []))
-        await _in_worker(self._dispatch, items, *self.flushes[-1])
+        # on record before its dispatch: a resolver handed out is then
+        # found by whatever ends the scan
+        self.flushes.append(deque())
+        await _in_worker(self._dispatch, items, rows, self.flushes[-1])
 
-    def _dispatch(self, items, resolvers: deque, singles: list) -> None:
-        for item in items:
-            if isinstance(item, PackedBeacons):
-                # anchor = the row's own STORED prev: linkage against the
-                # actual predecessor sig was already judged structurally,
-                # so here the batch checks pure signature validity over
-                # exactly the bytes on disk
-                resolvers.append((
-                    item.start_round,
-                    self.verifier.verify_packed_segment_async(
-                        item, item.first_prev)))
-            else:
-                singles.append(item)
+    def _dispatch(self, items, rows, resolvers: deque) -> None:
+        """Either way the batch checks pure signature validity over
+        exactly the bytes on disk, each row over its own STORED prev:
+        linkage against the actual predecessor sig was already judged
+        structurally."""
+        if len(items) == 1 and isinstance(items[0], PackedBeacons):
+            # the rows are one run that links from its first stored
+            # prev on: they stay in their matrix
+            item = items[0]
+            resolvers.append((item.rounds(),
+                              self.verifier.verify_packed_segment_async(
+                                  item, item.first_prev)))
+            return
+        # damage has taken rows out of the flush: what is left is
+        # several runs, and goes as one batch all the same (the device
+        # is charged by the program, not by the run)
+        resolvers.append((
+            np.array([r for r, _sig, _prev in rows], dtype=np.uint64),
+            dispatch_rows(self.verifier,
+                          [Beacon(round=r, signature=sig, previous_sig=prev)
+                           for r, sig, prev in rows])))
 
     async def settle_oldest(self) -> list[int]:
-        """Wait for the oldest flush's verdicts -> its bad rounds, the
-        packed segments' first, then those of the rows verified one by
-        one.  `scan.verify_wait` is opened here, where the scan blocks,
-        and not in the worker."""
+        """Wait for the oldest flush's verdicts -> its bad rounds.
+        `scan.verify_wait` is opened here, where the scan blocks, and
+        not in the worker."""
         from drand_tpu import tracing
         with tracing.span("scan.verify_wait"):
-            bad = await _in_worker(self._settle, *self.flushes[0])
+            bad = await _in_worker(self._settle, self.flushes[0])
         self.flushes.popleft()
         return bad
 
-    def _settle(self, resolvers: deque, singles: list) -> list[int]:
+    def _settle(self, resolvers: deque) -> list[int]:
         bad: list[int] = []
         while resolvers:
-            start_round, resolver = resolvers.popleft()
-            ok = np.asarray(resolver())
-            bad.extend(int(start_round + int(i)) for i in np.nonzero(~ok)[0])
-        if singles:
-            ok = np.asarray(self.verifier.verify_beacons(singles))
-            bad.extend(b.round for b, good in zip(singles, ok)
-                       if not bool(good))
+            rounds, resolver = resolvers.popleft()
+            ok = np.asarray(resolver(), dtype=bool)
+            bad.extend(int(r) for r in rounds[~ok])
         return bad
 
     async def abandon(self) -> None:
@@ -264,7 +284,7 @@ class _DispatchedAhead:
             await _in_worker(self._abandon)
 
     def _abandon(self) -> None:
-        for resolvers, _singles in self.flushes:
+        for resolvers in self.flushes:
             while resolvers:
                 _, resolver = resolvers.popleft()
                 try:
@@ -276,7 +296,7 @@ class _DispatchedAhead:
 
 async def _scan_store(store, ahead: _DispatchedAhead, beacon_id: str,
                       segment_rounds: int | None, read_batch: int,
-                      on_progress) -> IntegrityReport:
+                      on_progress, up_to: int | None) -> IntegrityReport:
     from drand_tpu import tracing
     began = time.perf_counter()
     verifier = ahead.verifier
@@ -293,21 +313,26 @@ async def _scan_store(store, ahead: _DispatchedAhead, beacon_id: str,
         with tracing.span("scan.flush", rows=len(pending),
                           in_flight=len(ahead)):
             t0 = time.perf_counter()
+            rows = pending[:]
             items = list(pack_rows(
-                pending, max_chunk=segment_rounds or len(pending)))
+                rows, max_chunk=segment_rounds or len(rows)))
             tracing.record_span("scan.pack", t0, time.perf_counter(),
                                 items=len(items))
             pending.clear()
-            await ahead.dispatch(items)
+            await ahead.dispatch(items, rows)
             if len(ahead) > SCAN_DISPATCH_AHEAD:
                 report.bad_sigs.extend(await ahead.settle_oldest())
 
     next_round = GENESIS_ROUND
-    while True:
+    last_read = False           # this batch reached `up_to`
+    while not last_read:
         t0 = time.perf_counter()
         rows = await asyncio.to_thread(store.raw_rows, next_round, read_batch)
         tracing.record_span("scan.read", t0, time.perf_counter(),
                             from_round=next_round, rows=len(rows))
+        if up_to is not None and rows and rows[-1][0] >= up_to:
+            rows = [row for row in rows if row[0] <= up_to]
+            last_read = True
         if not rows:
             break
         flagged = len(report.corrupt), len(report.unlinked)

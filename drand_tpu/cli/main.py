@@ -164,6 +164,9 @@ def build_parser() -> argparse.ArgumentParser:
                     "sidecar, nothing deleted)")
     sp.add_argument("--json", action="store_true", dest="json_out",
                     help="util fsck: machine-readable report on stdout")
+    sp.add_argument("--up-to", type=int, default=0,
+                    help="util check: rows above this round are neither "
+                    "read nor judged (0 = the whole stored chain)")
 
     sp = sub.add_parser("relay", help="run an HTTP relay over upstreams")
     sp.add_argument("--url", action="append", required=True,
@@ -1006,10 +1009,28 @@ async def cmd_util(args):
             drand_pb2.ListBeaconIDsRequest(metadata=md), timeout=10)
         print("\n".join(r.ids))
     elif args.what == "check":
-        async for p in cc.stub.StartCheckChain(
-                drand_pb2.StartSyncRequest(metadata=md)):
-            print(f"\rcheck {p.current}/{p.target}", end="", flush=True)
+        # scan the stored chain on the daemon's verifier and mend in
+        # place what it flags (`SyncManager.check_chain`)
+        import grpc
+        from drand_tpu.core.control import CHECK_COUNT_PREFIX
+        call = cc.stub.StartCheckChain(drand_pb2.StartSyncRequest(
+            up_to=args.up_to, metadata=md))
+        left = ""
+        try:
+            async for p in call:
+                print(f"\rcheck {p.current}/{p.target}", end="", flush=True)
+        except grpc.aio.AioRpcError as exc:
+            if exc.code() != grpc.StatusCode.DATA_LOSS:
+                raise
+            left = exc.details()
         print()
+        print(" / ".join(
+            f"{key[len(CHECK_COUNT_PREFIX):]} {value}"
+            for key, value in await call.trailing_metadata() or ()
+            if key.startswith(CHECK_COUNT_PREFIX)))
+        if left:
+            await cc.close()
+            raise SystemExit(f"check: {left}")
     elif args.what == "backup":
         if not args.target:
             raise SystemExit("util backup needs an output path")

@@ -19,6 +19,11 @@ from drand_tpu.protogen import drand_pb2
 
 log = dlog.get("core")
 
+# `StartCheckChain` says a check's counts in its trailing metadata, as
+# `drand-check-<name>` (`CheckResult.counts`: rows scanned, rounds
+# flagged, mended and left)
+CHECK_COUNT_PREFIX = "drand-check-"
+
 
 class ControlService(_Demux):
     async def PingPong(self, request, context):
@@ -125,21 +130,35 @@ class ControlService(_Demux):
 
     async def StartCheckChain(self, request, context):
         """Validate + repair the local chain
-        (core/drand_beacon_control.go:1168-1257)."""
+        (core/drand_beacon_control.go:1168-1257): all of it is
+        `SyncManager.check_chain`.  The scan's progress streams as it is
+        made; the counts ride the trailing metadata (`CHECK_COUNT_PREFIX`), and
+        a check that leaves rounds unmended ends DATA_LOSS, naming how
+        many."""
         bp = await self._process(request, context)
         if bp.sync_manager is None:
             await context.abort(grpc.StatusCode.FAILED_PRECONDITION,
                           "beacon not loaded")
-        loop = asyncio.get_running_loop()
-        up_to = request.up_to or None
-        faulty = await loop.run_in_executor(
-            None, lambda: bp.sync_manager.check_past_beacons(up_to))
-        target = request.up_to or bp.status()["last_round"]
-        yield drand_pb2.SyncProgress(current=0, target=target)
-        if faulty:
-            fixed = await bp.sync_manager.correct_past_beacons(faulty)
-            log.info("check chain: %d faulty, %d fixed", len(faulty), fixed)
-        yield drand_pb2.SyncProgress(current=target, target=target)
+        progress: asyncio.Queue = asyncio.Queue()
+        check = asyncio.ensure_future(bp.sync_manager.check_chain(
+            request.up_to or None,
+            on_progress=lambda current, target:
+                progress.put_nowait((current, target))))
+        check.add_done_callback(lambda _: progress.put_nowait(None))
+        try:
+            while (step := await progress.get()) is not None:
+                yield drand_pb2.SyncProgress(current=step[0], target=step[1])
+            result = await check
+        finally:
+            check.cancel()
+        counts = tuple((CHECK_COUNT_PREFIX + name, str(n))
+                       for name, n in result.counts().items())
+        if result.unfixed:
+            await context.abort(
+                grpc.StatusCode.DATA_LOSS,
+                f"{len(result.unfixed)} rounds left unmended (first "
+                f"{result.unfixed[:8]})", trailing_metadata=counts)
+        context.set_trailing_metadata(counts)
 
     async def BackupDatabase(self, request, context):
         bp = await self._process(request, context)

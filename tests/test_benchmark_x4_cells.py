@@ -10,14 +10,17 @@ configuration, two cells and four metrics and may edit no file under
 `benchmark/`.  Those cases are held here in the form that stays true
 under appending, under their own names, in the others' place, as
 `test_benchmark_new_cells.py` holds PR 29's; the repair of the file
-beside the benchmark is a `benchmark` PR's (PERF.md, section 7)."""
+beside the benchmark is a `benchmark` PR's (PERF.md, section 7).  PR 41
+added the scan's four-chip cell, `restart-scan.quicknet-g1-x4`."""
 
 from benchmark.tests.test_x4_cells import *  # noqa: F401,F403
 from benchmark.tests.test_x4_cells import ONE, X4
 
 
 def test_four_chips_are_asked_for_once(bench):  # noqa: F811
-    assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] == [X4]
+    # once a traffic mix: the catch-up's, and since PR 41 the scan's
+    assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] == [
+        X4, "restart-scan.quicknet-g1-x4"]
     cells = [w["name"] for w in bench["workloads"]]
     assert cells[cells.index(X4):][:2] == [X4, ONE]      # appended, in order
     configs = [c["name"] for c in bench["configs"]]

@@ -157,10 +157,12 @@ class _RecordingVerifier:
     is refused at its dispatch, one that starts at `resolver_raises` by
     its resolver."""
 
-    def __init__(self, bad=(), dispatch_raises=None, resolver_raises=None):
+    def __init__(self, bad=(), dispatch_raises=None, resolver_raises=None,
+                 rows_raise=None):
         self.bad = set(bad)
         self.dispatch_raises = dispatch_raises
         self.resolver_raises = resolver_raises
+        self.rows_raise = rows_raise    # a batch of rows that begins here
         self.calls: list[tuple[str, int]] = []
         self.out: set[int] = set()          # dispatched, not resolved
         self.most_out = 0
@@ -185,8 +187,28 @@ class _RecordingVerifier:
 
     def verify_beacons(self, beacons):
         self.calls.append(("singles", beacons[0].round))
+        if beacons[0].round == self.rows_raise:
+            raise RuntimeError(f"no verdicts of the rows at {self.rows_raise}")
         return np.array([b.round not in self.bad for b in beacons],
                         dtype=bool)
+
+
+class _AsyncRowsVerifier(_RecordingVerifier):
+    """One that can dispatch a batch of rows ahead, as `ChainVerifier`
+    does (`verify_beacons_async`)."""
+
+    def verify_beacons_async(self, beacons):
+        start = beacons[0].round
+        self.calls.append(("dispatch_rows", start))
+        self.out.add(start)
+        self.most_out = max(self.most_out, len(self.out))
+        ok = np.array([b.round not in self.bad for b in beacons], dtype=bool)
+
+        def resolve():
+            self.calls.append(("resolve", start))
+            self.out.discard(start)
+            return ok
+        return resolve
 
 
 def test_scan_bls_stage_flags_bad_signature(tmp_path):
@@ -455,22 +477,21 @@ def _sequential_report(store, verifier, segment_rounds) -> dict:
             report.unlinked.append(r)
         elif r != 0:
             good.append((r, sig, prev))
+    from drand_tpu.chain.beacon import Beacon
     from drand_tpu.chain.segment import PackedBeacons, pack_rows
     for at in range(0, len(good), segment_rounds):
-        singles = []
-        for item in pack_rows(good[at:at + segment_rounds],
-                              max_chunk=segment_rounds):
-            if isinstance(item, PackedBeacons):
-                ok = verifier.verify_packed_segment_async(
-                    item, item.first_prev)()
-                report.bad_sigs += [int(item.start_round + i)
-                                    for i in np.nonzero(~ok)[0]]
-            else:
-                singles.append(item)
-        if singles:
-            ok = verifier.verify_beacons(singles)
-            report.bad_sigs += [b.round for b, good_ in zip(singles, ok)
-                                if not good_]
+        flush = good[at:at + segment_rounds]
+        items = list(pack_rows(flush, max_chunk=segment_rounds))
+        if len(items) == 1 and isinstance(items[0], PackedBeacons):
+            ok = verifier.verify_packed_segment_async(
+                items[0], items[0].first_prev)()
+        else:
+            # what damage leaves of a flush: one batch of rows, in order
+            ok = verifier.verify_beacons(
+                [Beacon(round=r, signature=sig, previous_sig=prev)
+                 for r, sig, prev in flush])
+        report.bad_sigs += [r for (r, _s, _p), good_ in zip(flush, ok)
+                            if not good_]
     problems = (report.corrupt + report.unlinked + report.bad_sigs
                 + [a for a, _ in report.missing])
     report.verified_tip = -1 if not rows else (
@@ -488,9 +509,9 @@ _DAMAGE = {
     "bad_sig_last_segment_first_row": dict(bad=[65]),
     "bad_sig_last_segment_last_row": dict(bad=[72]),
     "bad_sigs_in_every_segment": dict(bad=[72, 5, 49, 17, 32, 64]),
-    # a corrupt row cuts its segment in two packed items; two of them two
-    # rows apart leave the row between to be verified alone, after the
-    # packed items of its flush
+    # a corrupt row cuts its flush in two runs, two of them two rows
+    # apart leave a row alone between them: the flush goes as ONE batch
+    # of rows, and its bad rounds are filed in round order
     "corrupt_rows_split_a_segment": dict(torn=[20, 22, 40], bad=[21, 23, 41]),
     # an unlinked row is not verified and leaves a hole in its segment
     "an_unlinked_row_splits_a_segment": dict(rot=[37], bad=[36, 38]),
@@ -555,19 +576,36 @@ def test_a_scan_that_fails_leaves_no_resolver_behind(tmp_path, fault):
         == len(dispatched) + ("dispatch_raises" in fault)
 
 
-def test_a_split_flush_that_fails_midway_resolves_what_it_dispatched(
-        tmp_path):
-    # the second flush holds two packed items (a corrupt row between
-    # them); its second dispatch raises with its first already out
+def test_a_split_flush_that_fails_resolves_what_is_dispatched(tmp_path):
+    # the second flush is two runs (a corrupt row between them) and goes
+    # as one batch of rows, which this verifier judges when the flush is
+    # settled; that fails with the third flush already out
     s, path = _chain_db(tmp_path, 72)
     faults.torn_write(path, 24)
-    v = _RecordingVerifier(dispatch_raises=25)
-    with pytest.raises(RuntimeError, match="no dispatch at 25"):
+    v = _RecordingVerifier(rows_raise=17)
+    with pytest.raises(RuntimeError, match="no verdicts of the rows at 17"):
         _scan(s, v, segment_rounds=16, read_batch=10)
     s.close()
-    assert v.calls == [("dispatch", 1), ("dispatch", 17),
-                       ("resolve", 1), ("resolve", 17)]
+    assert v.calls == [("dispatch", 1), ("resolve", 1), ("dispatch", 34),
+                       ("singles", 17), ("resolve", 34)]
     assert not v.out
+
+
+def test_a_split_flush_is_one_dispatch_of_rows_one_ahead(tmp_path):
+    # a verifier that can dispatch rows ahead gets the split flush as ONE
+    # dispatch, before the flush before it is awaited
+    s, path = _chain_db(tmp_path, 72)
+    faults.torn_write(path, 24)
+    faults.torn_write(path, 26)
+    v = _AsyncRowsVerifier(bad=[25, 30])
+    rep = _scan(s, v, segment_rounds=16, read_batch=10)
+    s.close()
+    assert rep.corrupt == [24, 26] and rep.bad_sigs == [25, 30]
+    assert v.calls[:4] == [("dispatch", 1), ("dispatch_rows", 17),
+                           ("resolve", 1), ("dispatch", 35)]
+    assert [c for c in v.calls if c[0] == "dispatch_rows"] \
+        == [("dispatch_rows", 17)]
+    assert not v.out and v.most_out == 2
 
 
 def test_a_cancelled_scan_leaves_no_resolver_behind(tmp_path):
