@@ -363,7 +363,14 @@ def flat_cyclo_sqr(a):
     On TPU the whole square runs as ONE fused Pallas kernel
     (PallasField.cyclo_sqr): the round-3 profile showed this XLA form at
     ~85% carry/select glue around a single products call, and the x-power
-    chains execute it 63 times per chain, 5+ chains per verify.
+    chains execute it 63 times per chain, 5+ chains per verify.  The
+    kernel computes the same formulas in another order (ISSUE 42): the
+    nine squares' convolutions stay un-reduced, the Fp4 recombination,
+    the 3t +- 2g folds and the flat encoding below are summed in the
+    wide domain, and each of the 12 output coordinates is Montgomery-
+    reduced once.  This form reduces the nine squares first (18
+    coordinates) and recombines canonical values: it is the oracle the
+    kernel is held to, limb for limb.
     """
     pf = FP._pallas()
     if pf is not None:

@@ -68,6 +68,20 @@ def _count_crossing(kind: str) -> None:
         pass
 
 
+# A Montgomery reduction is ~1.5 convolutions of VPU work, so how many a
+# kernel body runs says what its recombination costs: one a coordinate
+# that leaves the wide domain.  Counted where the bodies are traced, as
+# the crossings above are: read it around a trace.
+
+_MONT_REDUCTIONS = {"coords": 0}
+
+
+def mont_reductions_traced() -> int:
+    """Fp coordinates Montgomery-reduced by the kernel bodies traced so
+    far (a stacked reduce counts its stack)."""
+    return _MONT_REDUCTIONS["coords"]
+
+
 @jax.tree_util.register_pytree_node_class
 class TileForm:
     """A batched limb tensor ALREADY in the kernel tile layout
@@ -204,6 +218,7 @@ _I32_BITS = np.int32(LIMB_BITS)
 _mul, _add = jax.lax.mul, jax.lax.add
 _and, _shr = jax.lax.bitwise_and, jax.lax.shift_right_arithmetic
 _or, _eq, _gt = jax.lax.bitwise_or, jax.lax.eq, jax.lax.gt
+_sub = jax.lax.sub
 
 
 def _carry_cheap_rows(rows, passes=2):
@@ -401,6 +416,58 @@ def _line_merge_tables():
     return (tuple(tuple(p) for p in pairs_by_k), tuple(scatter), counts)
 
 
+# The Granger-Scott square's twelve outputs as linear forms of its 27
+# un-reduced convolutions: the kernel's recombination and the bound
+# builder (PallasField._cyclo_sqr_plan) both read THIS table, so what is
+# asserted is what runs.  For an fp4 group (a, b) of tower cells with
+# s = a + b, and for x = (x0, x1) in {a, b, s}: sq = x0^2, iq = x1^2,
+# cr = 2 x0 x1, so x^2 = (sq - iq, cr) and
+#   re = a^2 + xi b^2 = (a.sq - a.iq + b.sq - b.iq - b.cr,
+#                        a.cr + b.sq - b.iq + b.cr)
+#   im = s^2 - a^2 - b^2.
+# An even slot is 3 re - 2 g, an odd one 3 t + 2 g with t = im, or
+# xi im = (im_x - im_y, im_x + im_y) for slot 1; the flat encoding
+# stores lo = x - y and hi = y, and the input's own lo and hi are g's.
+# A row: (half, groups, slots, the input's coefficient, terms); every
+# term is (coefficient, cell, convolution) and the whole sum is taken
+# times three.
+_CY_A, _CY_B, _CY_S = 0, 1, 2            # cell of the group: a, b, a + b
+_CY_SQ, _CY_IQ, _CY_CR = 0, 1, 2         # x0^2, x1^2, 2 x0 x1
+_CY_IM_X = ((1, _CY_S, _CY_SQ), (1, _CY_A, _CY_IQ), (1, _CY_B, _CY_IQ),
+            (-1, _CY_S, _CY_IQ), (-1, _CY_A, _CY_SQ), (-1, _CY_B, _CY_SQ))
+_CY_IM_Y = ((1, _CY_S, _CY_CR), (-1, _CY_A, _CY_CR), (-1, _CY_B, _CY_CR))
+
+
+def _cy_scaled(terms, k):
+    return tuple((k * c, cell, conv) for c, cell, conv in terms)
+
+
+_CYCLO_SQR_OUTPUTS = (
+    # slots 0, 2, 4 = 3 re - 2 g of groups A, B, C
+    ("lo", (0, 1, 2), (0, 2, 4), -2,
+     ((1, _CY_A, _CY_SQ), (-1, _CY_A, _CY_IQ), (-1, _CY_A, _CY_CR),
+      (-2, _CY_B, _CY_CR))),
+    ("hi", (0, 1, 2), (0, 2, 4), -2,
+     ((1, _CY_A, _CY_CR), (1, _CY_B, _CY_SQ), (1, _CY_B, _CY_CR),
+      (-1, _CY_B, _CY_IQ))),
+    # slots 3, 5 = 3 im + 2 g of groups A, B
+    ("lo", (0, 1), (3, 5), 2, _CY_IM_X + _cy_scaled(_CY_IM_Y, -1)),
+    ("hi", (0, 1), (3, 5), 2, _CY_IM_Y),
+    # slot 1 = 3 xi im + 2 g of group C
+    ("lo", (2,), (1,), 2, _cy_scaled(_CY_IM_Y, -2)),
+    ("hi", (2,), (1,), 2, _CY_IM_X + _CY_IM_Y),
+)
+
+
+def _carried_maxes(maxes, passes):
+    """Row-wise upper bounds of `_carry_cheap_rows` over non-negative
+    rows bounded by `maxes` (exact Python integers)."""
+    for _ in range(passes):
+        maxes = [min(m, MASK) + (maxes[i - 1] >> LIMB_BITS if i else 0)
+                 for i, m in enumerate(maxes)]
+    return maxes
+
+
 # ---------------------------------------------------------------------------
 # Kernel factory: mont_mul / mont_reduce for one modulus
 # ---------------------------------------------------------------------------
@@ -437,6 +504,8 @@ class PallasField:
         regardless) and some later canonical reduce/cond-sub restores
         [0, m) — the Fermat/x-power chains run all intermediate squarings
         lazy and the final table multiply canonical."""
+        _MONT_REDUCTIONS["coords"] += int(
+            np.prod(t_rows[0].shape[:-len(_ROW)]))
         m_cols = _mul_const_rows(t_rows[:N_LIMBS], self.PPRIME, N_LIMBS)
         m_rows = _carry_cheap_rows(m_cols, 2)
         u_cols = _mul_const_rows(m_rows, self.MOD, 2 * N_LIMBS - 1)
@@ -540,10 +609,6 @@ class PallasField:
     def _fp2_sub_rows(self, a, b):
         return (self._sub_rows(a[0], b[0]), self._sub_rows(a[1], b[1]))
 
-    def _fp2_mul_xi_rows(self, a):
-        """xi = 1 + u: (c0 - c1, c0 + c1)."""
-        return (self._sub_rows(a[0], a[1]), self._add_rows(a[0], a[1]))
-
     def _neg_rows(self, a_rows):
         """(-a) mod m, canonical in/out (0 -> 0 via the cond-sub)."""
         zeros = [jnp.zeros_like(r) for r in a_rows]
@@ -596,14 +661,135 @@ class PallasField:
     # The x-power chains run flat_cyclo_sqr 63 times per chain; profiling
     # (round 3) showed its XLA form at ~85% carry/select glue around one
     # fused products call.  This kernel keeps the whole Granger-Scott
-    # square — cell extraction, 9 Fp2 squarings, Fp4 recombination, the
-    # 3t +- 2g folds, and the flat re-encoding — in VMEM.
+    # square in VMEM, and since ISSUE 42 its linear tail (the Fp4
+    # recombination, the 3t +- 2g folds and the flat re-encoding) runs in
+    # the WIDE domain, in front of the Montgomery reduction: the 27
+    # convolutions of the nine Fp2 squares are summed into the 12 output
+    # coordinates as 64-row int32 values (_CYCLO_SQR_OUTPUTS), the input
+    # enters as g*R (its limbs at rows 32..63), and each output is
+    # reduced once: 12 reductions a launch where reducing the squares
+    # first took 18, and about 86 canonical add/sub passes gone.
 
-    def _cyclo_sqr_kernel(self, off_limbs, a_ref, o_ref):
+    @functools.lru_cache(maxsize=None)
+    def _cyclo_sqr_plan(self):
+        """(offsets, subs) of `_cyclo_sqr_kernel`: a 64-limb K*p^2
+        constant for each row of _CYCLO_SQR_OUTPUTS, sized to what that
+        row subtracts, and the conditional-subtract chain of the one
+        stacked reduce; `_cyclo_sqr_check` asserts every bound."""
+        from drand_tpu.ops.towers import wide_neg_offset
+        offs = []
+        for out in _CYCLO_SQR_OUTPUTS:
+            sub_value, _, sub_limbs, _ = self._cyclo_sqr_ranges(out)
+            # wide_neg_offset's limbs are 4300 * scale below the top one;
+            # 2 * scale << 756 above the subtracted value keeps its top
+            # limb over the subtracted top limbs too
+            scale = -(-max(sub_limbs[:-1]) // 4300)
+            off, _ = wide_neg_offset(
+                scale, min_value=sub_value + ((2 * scale) << 756))
+            offs.append(tuple(int(v) for v in off))
+        offs = tuple(offs)
+        return offs, self._cyclo_sqr_check(offs)
+
+    def _cyclo_sqr_ranges(self, out):
+        """What one row of _CYCLO_SQR_OUTPUTS subtracts and adds, at most,
+        over canonical inputs: (subtracted value, added value, subtracted
+        limbs, added limbs), the limbs row by row of the 64."""
+        m = self.modulus
+        _half, _groups, _slots, g_coeff, terms = out
+        products = [min(k, 2 * N_LIMBS - 2 - k) + 1
+                    for k in range(2 * N_LIMBS - 1)]
+
+        def conv(twice):
+            # a convolution of canonical operands (cr: doubled), cheap-
+            # carried in 64 rows: its value, and its rows under both
+            value = twice * (m - 1) ** 2
+            rows = _carried_maxes(
+                [twice * n * MASK * MASK for n in products] + [0], 2)
+            return value, [min(v, value >> (LIMB_BITS * l))
+                           for l, v in enumerate(rows)]
+
+        # every summand: (signed multiple, value, rows)
+        parts = [(3 * coeff, *conv(2 if kind == _CY_CR else 1))
+                 for coeff, _cell, kind in terms]
+        # the input's coordinate times R: its limbs at rows 32..63
+        parts.append((g_coeff, (m - 1) << (LIMB_BITS * N_LIMBS),
+                      [0] * N_LIMBS + [min(MASK, (m - 1) >> (LIMB_BITS * l))
+                                       for l in range(N_LIMBS)]))
+        values, limbs = [0, 0], [[0] * (2 * N_LIMBS) for _ in range(2)]
+        for k, value, rows in parts:
+            side = int(k > 0)                    # 0: subtracted, 1: added
+            values[side] += abs(k) * value
+            limbs[side] = [a + abs(k) * v for a, v in zip(limbs[side], rows)]
+        sub_value, add_value = values
+        sub_limbs, add_limbs = limbs
+        return sub_value, add_value, sub_limbs, add_limbs
+
+    def _cyclo_sqr_check(self, offs):
+        """Assert, on exact integers, that with `offs` every output of
+        the wide recombination is a sound input of `_mont_reduce_rows`,
+        and return the shortest conditional-subtract chain that brings
+        all of them to [0, m).  A bound that fails fails the BUILD: an
+        under-covered subtraction would wrap mod 2^768 at the reduce and
+        surface as an off-by-one result (see towers.wide_neg_offset)."""
+        m = self.modulus
+        W = 2 * N_LIMBS
+        R = 1 << (LIMB_BITS * N_LIMBS)
+        assert len(offs) == len(_CYCLO_SQR_OUTPUTS)
+        r_max = 0
+        for out, off in zip(_CYCLO_SQR_OUTPUTS, offs):
+            sub_value, add_value, sub_limbs, add_limbs = \
+                self._cyclo_sqr_ranges(out)
+            assert len(off) == W, out
+            off_value = sum(int(v) << (LIMB_BITS * l)
+                            for l, v in enumerate(off))
+            # a multiple of the modulus: the residue is the formula's
+            assert off_value % m == 0, out
+            # never negative: in value, and limb by limb, so that every
+            # row stays a non-negative int32 through the carries and
+            # the reduction's m = t * p' is no negative number either
+            assert off_value >= sub_value, (out, off_value, sub_value)
+            assert all(o >= s for o, s in zip(off, sub_limbs)), (out, off)
+            rows = [o + a for o, a in zip(off, add_limbs)]
+            assert max(o + s + a for o, s, a in zip(
+                off, sub_limbs, add_limbs)) < 1 << 31, out
+            # one cheap pass brings the rows to what every caller of
+            # _mont_reduce_rows hands it (limbs <= 4224)
+            t_value = off_value + add_value
+            t_rows = _carried_maxes(rows, 1)
+            assert max(t_rows) <= 4224, (out, max(t_rows))
+            # the reduction's own column sums, and u = t + m_val * m
+            # inside the 64-limb window (rows non-negative: the top
+            # row then holds the top of the value, nothing is dropped)
+            m_cols = [sum(t_rows[i] * self.PPRIME[k - i]
+                          for i in range(k + 1)) for k in range(N_LIMBS)]
+            assert max(m_cols) < 1 << 31, out
+            m_rows = _carried_maxes(m_cols, 2)
+            u_cols = [sum(m_rows[i] * self.MOD[k - i]
+                          for i in range(N_LIMBS) if 0 <= k - i < N_LIMBS)
+                      + t_rows[k] for k in range(W)]
+            assert max(u_cols) < 1 << 31, out
+            m_value = sum(v << (LIMB_BITS * l) for l, v in enumerate(m_rows))
+            assert t_value + m_value * m < 1 << (LIMB_BITS * W), out
+            r_max = max(r_max, (t_value + m_value * m) // R)
+        # the twelve reduce as ONE stack, so the worst of them decides
+        # (with these offsets every one lies between 4m and 8m)
+        for subs in ((1,), (2, 1), (4, 2, 1), (8, 4, 2, 1)):
+            if r_max < 2 * subs[0] * m:
+                assert all(k in self.K for k in subs), subs
+                return subs
+        raise AssertionError(("no chain reduces", r_max // m))
+
+    def _cyclo_sqr_kernel(self, plan, a_ref, o_ref):
         """Every stage operates on STACKED rows ([k, 8, 128] per limb):
         the whole square is one traced conv/carry body per stage, not an
         unrolled per-cell program — ~6x fewer Mosaic instructions, same
-        vector work."""
+        vector work.  Front: cell extraction, s = a + b a group, the 27
+        convolutions of the nine Fp2 squares, cheap-carried and NOT
+        reduced.  Tail: the 12 outputs of _CYCLO_SQR_OUTPUTS summed wide
+        over the offsets of `plan`, one stacked Montgomery reduction,
+        canonical rows out."""
+        offs, subs = plan
+
         def stk(slots, base=0):
             return [jnp.stack([a_ref[0, (base + s) * N_LIMBS + l]
                                for s in slots], 0) for l in range(N_LIMBS)]
@@ -623,55 +809,60 @@ class PallasField:
         # s = a + b per group (three Fp2 adds, one stacked call per coord)
         sx = self._add_rows(ax, bx)
         sy = self._add_rows(ay, by)
-        # nine squares in one stacked pass: [a(3), b(3), s(3)]
+        # nine squares in one stacked pass: [a(3), b(3), s(3)], every
+        # operand canonical, so a convolution is below p^2
         x0s = [jnp.concatenate([a, b, s], 0) for a, b, s in zip(ax, bx, sx)]
         x1s = [jnp.concatenate([a, b, s], 0) for a, b, s in zip(ay, by, sy)]
-        r0, r1 = self._fp2_sqr_rows((x0s, x1s), off_limbs)
-        a2 = ([r[0:3] for r in r0], [r[0:3] for r in r1])
-        b2 = ([r[3:6] for r in r0], [r[3:6] for r in r1])
-        s2 = ([r[6:9] for r in r0], [r[6:9] for r in r1])
+        z = jnp.zeros_like(x0s[0])
+        wide = {
+            _CY_SQ: _carry_cheap_rows(_sqr_conv_rows(x0s) + [z], 2),
+            _CY_IQ: _carry_cheap_rows(_sqr_conv_rows(x1s) + [z], 2),
+            _CY_CR: _carry_cheap_rows(
+                [_add(c, c) for c in _conv_rows(x0s, x1s) + [z]], 2),
+        }
 
-        # fp4: re = a2 + xi*b2, im = s2 - a2 - b2   (stacks of 3)
-        re = self._fp2_add_rows(a2, self._fp2_mul_xi_rows(b2))
-        im = self._fp2_sub_rows(self._fp2_sub_rows(s2, a2), b2)
+        # the recombination, row by row of the 64: each output kind a
+        # stack over its groups, the kinds joined into one stack of 12
+        t_rows = [[] for _ in range(2 * N_LIMBS)]
+        for (half, groups, slots, g_coeff, terms), off in zip(
+                _CYCLO_SQR_OUTPUTS, offs):
+            g_in = stk(slots, base=0 if half == "lo" else 6)
+            first, n = groups[0], len(groups)
+            terms = sorted(terms, reverse=True)      # an added one first
+            assert terms[0][0] > 0, terms
+            for l in range(2 * N_LIMBS):
+                acc = None
+                for coeff, cell, conv in terms:
+                    at = 3 * cell + first
+                    x = wide[conv][l][at:at + n]
+                    if abs(coeff) == 2:
+                        x = _add(x, x)
+                    if acc is None:
+                        acc = x
+                    else:
+                        acc = _add(acc, x) if coeff > 0 else _sub(acc, x)
+                acc = _add(_mul(acc, np.int32(3)), np.int32(off[l]))
+                if l >= N_LIMBS:
+                    acc = _add(acc, _mul(g_in[l - N_LIMBS],
+                                         np.int32(g_coeff)))
+                t_rows[l].append(acc)
+        t = _carry_cheap_rows([jnp.concatenate(ks, 0) for ks in t_rows], 1)
+        r = self._mont_reduce_rows(t, subs=subs)
 
-        # out slots 0,2,4 = 3*re - 2*g[0,1,2]; slots 1,3,5 = 3*t + 2*g[3,4,5]
-        # with t = [xi*im_C, im_A, im_B] and re ordered [re_A, re_B, re_C]
-        g_even = (pick(xs6, (0, 2, 4)), pick(hi6, (0, 2, 4)))
-        g_odd = (pick(xs6, (1, 3, 5)), pick(hi6, (1, 3, 5)))
-        xi_imc = self._fp2_mul_xi_rows(
-            ([r[2:3] for r in im[0]], [r[2:3] for r in im[1]]))
-        tp_t = ([jnp.concatenate([xi_imc[0][l], im[0][l][0:2]], 0)
-                 for l in range(N_LIMBS)],
-                [jnp.concatenate([xi_imc[1][l], im[1][l][0:2]], 0)
-                 for l in range(N_LIMBS)])
-        d_even = self._fp2_sub_rows(re, g_even)
-        out_even = self._fp2_add_rows(self._fp2_add_rows(d_even, d_even), re)
-        s_odd = self._fp2_add_rows(tp_t, g_odd)
-        out_odd = self._fp2_add_rows(self._fp2_add_rows(s_odd, s_odd), tp_t)
-
-        # interleave to slot order 0..5 and re-encode flat (lo = x - y)
-        x2 = [jnp.stack([out_even[0][l][0], out_odd[0][l][0],
-                         out_even[0][l][1], out_odd[0][l][1],
-                         out_even[0][l][2], out_odd[0][l][2]], 0)
-              for l in range(N_LIMBS)]
-        y2 = [jnp.stack([out_even[1][l][0], out_odd[1][l][0],
-                         out_even[1][l][1], out_odd[1][l][1],
-                         out_even[1][l][2], out_odd[1][l][2]], 0)
-              for l in range(N_LIMBS)]
-        lo_out = self._sub_rows(x2, y2)
-        for i in range(6):
-            for l in range(N_LIMBS):
-                o_ref[0, i * N_LIMBS + l] = lo_out[l][i]
-                o_ref[0, (i + 6) * N_LIMBS + l] = y2[l][i]
+        i = 0
+        for half, _groups, slots, _g, _terms in _CYCLO_SQR_OUTPUTS:
+            for s in slots:
+                base = (s if half == "lo" else s + 6) * N_LIMBS
+                for l in range(N_LIMBS):
+                    o_ref[0, base + l] = r[l][i]
+                i += 1
 
     def cyclo_sqr(self, a):
         """Fused Granger-Scott cyclotomic square of a flat Fp12 element
         ([..., 12, 32] canonical Montgomery limbs, or the packed
         TileForm — output kind follows the input)."""
-        from drand_tpu.ops.towers import _WIDE_NEG_OFF
-        kernel = functools.partial(
-            self._cyclo_sqr_kernel, tuple(int(v) for v in _WIDE_NEG_OFF))
+        kernel = functools.partial(self._cyclo_sqr_kernel,
+                                   self._cyclo_sqr_plan())
         if isinstance(a, TileForm):
             out = self._call(kernel, 12 * N_LIMBS, a.tiles)
             return TileForm(out, a.shape, a.b)

@@ -15,7 +15,7 @@ import pytest
 
 from drand_tpu.crypto.bls12381.constants import P, R
 from drand_tpu.ops import pallas_field as PFm
-from drand_tpu.ops.field import FP, FR
+from drand_tpu.ops.field import FP, FR, int_to_limbs
 
 pytestmark = pytest.mark.slow   # interpreter-mode kernels: ~10 min
 
@@ -173,23 +173,67 @@ def test_pallas_flat_sqr_matches_golden(sim):
         assert F.flat_decode(jnp.asarray(out), i) == G.fp12_mul(x, x)
 
 
-def test_pallas_cyclo_sqr_matches_golden(sim):
-    """Fused Granger-Scott kernel vs golden fp12_mul(z, z) on unitary
-    elements (outputs of the final-exp easy part)."""
+def _cyclotomic(n):
+    """Outputs of the final exponentiation's easy part."""
     from drand_tpu.crypto.bls12381 import fp as G
-    from drand_tpu.ops import flat12 as F
-    pf = PFm.PallasField(P)
     zs = []
-    for _ in range(2):
+    for _ in range(n):
         f = _r_fp12()
         # easy part makes it unitary: f^(p^6-1) then ^(p^2+1)
         f = G.fp12_mul(G.fp12_conj(f), G.fp12_inv(f))
-        f = G.fp12_mul(G.fp12_frob_n(f, 2), f)
-        zs.append(f)
-    a = F.flat_encode(zs)
-    out = np.asarray(pf.cyclo_sqr(jnp.asarray(a)))
-    for i, z in enumerate(zs):
-        assert F.flat_decode(jnp.asarray(out), i) == G.fp12_mul(z, z)
+        zs.append(G.fp12_mul(G.fp12_frob_n(f, 2), f))
+    return zs
+
+
+@pytest.mark.parametrize("case", ["random", "identity", "formula_at_bounds",
+                                  "pow_x_abs", "final_exp"])
+def test_pallas_cyclo_sqr_matches_golden(sim, case):
+    """Fused Granger-Scott kernel (ISSUE 42: recombined in the wide
+    domain, one Montgomery reduction an output) vs golden fp12_mul(z, z)
+    on elements of the cyclotomic subgroup (outputs of the final-exp easy
+    part; the identity, whose cells hold zeros); off that subgroup, where
+    its contract is the formula, vs the XLA flat_cyclo_sqr on -1 (the
+    formula gives 5) and on inputs at the static bounds (every stored
+    coefficient p-1, mixes of 0, 1 and p-1); and through its callers:
+    `_unitary_pow_x_abs` (63 chained squarings, 5 multiplies) and the
+    whole `final_exp`, bit for bit the golden model's."""
+    from unittest import mock
+
+    from drand_tpu.crypto.bls12381 import fp as G
+    from drand_tpu.crypto.bls12381 import pairing as GP
+    from drand_tpu.ops import flat12 as F
+    from drand_tpu.ops import pairing as DP
+    from drand_tpu.ops.field import compact_scope
+    pf = PFm.PallasField(P)
+    if case in ("random", "identity"):
+        zs = _cyclotomic(2) if case == "random" else [G.FP12_ONE]
+        out = np.asarray(pf.cyclo_sqr(jnp.asarray(F.flat_encode(zs))))
+        for i, z in enumerate(zs):
+            assert F.flat_decode(jnp.asarray(out), i) == G.fp12_mul(z, z)
+    elif case == "formula_at_bounds":
+        minus_one = G.fp12_neg(G.FP12_ONE)
+        stored = [[P - 1] * 12, [0] * 12, [P - 1] * 6 + [0] * 6,
+                  [0] * 6 + [P - 1] * 6]
+        stored += [[rng.choice([0, 1, P - 1]) for _ in range(12)]
+                   for _ in range(3)]
+        a = jnp.asarray(np.concatenate(
+            [np.asarray(F.flat_encode([minus_one]))]
+            + [np.stack([int_to_limbs(v) for v in vs])[None]
+               for vs in stored]))
+        want = np.asarray(F.flat_cyclo_sqr(a))      # CPU: the XLA form
+        assert (np.asarray(pf.cyclo_sqr(a)) == want).all()
+    else:
+        z = _cyclotomic(1)[0] if case == "pow_x_abs" else _r_fp12()
+        a = jnp.asarray(F.flat_encode([z]))
+        with mock.patch.object(PFm, "use_pallas", return_value=True), \
+                jax.disable_jit(), compact_scope(True):
+            if case == "pow_x_abs":
+                got = F.flat_untile(DP._unitary_pow_x_abs(F.flat_tile(a)))
+                want = G.fp12_pow(z, DP._X_ABS)
+            else:
+                got = F.flat_untile(DP.final_exp(F.flat_tile(a)))
+                want = GP.final_exp(z)
+        assert F.flat_decode(jnp.asarray(np.asarray(got)), 0) == want
 
 
 def test_pallas_miller_step_kernels_match_xla(sim):

@@ -23,7 +23,7 @@ from drand_tpu.crypto.bls12381.constants import P
 from drand_tpu.ops import flat12 as F
 from drand_tpu.ops import pallas_field as PFm
 from drand_tpu.ops import towers as T
-from drand_tpu.ops.field import FP
+from drand_tpu.ops.field import FP, int_to_limbs, limbs_to_int
 
 rng = random.Random(0x5EED)
 
@@ -128,13 +128,90 @@ def test_sim_flat_mul_full_and_sparse(sim):
     assert F.flat_decode(jnp.asarray(np.asarray(out)), 0) == want
 
 
-def test_sim_cyclo_sqr(sim):
+_FP12_MINUS_ONE = G.fp12_neg(G.FP12_ONE)
+
+
+def _stored_extremes(seed):
+    """A flat element whose 12 STORED coefficients are each 0, 1 or p-1."""
+    rng2 = random.Random(seed)
+    return np.stack([int_to_limbs(rng2.choice([0, 1, P - 1]))
+                     for _ in range(12)])
+
+
+@pytest.mark.parametrize("case", ["cyclotomic", "formula_at_bounds"])
+def test_sim_cyclo_sqr(sim, case):
+    """The fused Granger-Scott square (ISSUE 42: recombined wide, one
+    Montgomery reduction an output coordinate).  In the cyclotomic
+    subgroup (what the easy part leaves, the identity with its zero
+    cells included) it is the golden model's square.  Off it the
+    kernel's contract is the formula: -1 (unitary, of order 2, and so
+    outside that subgroup: the formula gives 5) and inputs built to sit
+    at the static bounds (every stored coefficient p-1, and mixes of 0,
+    1 and p-1) are held to the XLA `flat_cyclo_sqr`, limb for limb."""
     pf = PFm.pallas_field(P)
-    f = _r_fp12()
-    f = G.fp12_mul(G.fp12_conj(f), G.fp12_inv(f))     # unitary
-    f = G.fp12_mul(G.fp12_frob_n(f, 2), f)
-    out = np.asarray(pf.cyclo_sqr(jnp.asarray(F.flat_encode([f]))))
-    assert F.flat_decode(jnp.asarray(out), 0) == G.fp12_mul(f, f)
+    if case == "formula_at_bounds":
+        a = jnp.asarray(np.concatenate(
+            [np.asarray(F.flat_encode([_FP12_MINUS_ONE])), _max_flat()[None]]
+            + [_stored_extremes(s)[None] for s in range(4)]))
+        assert FP._pallas() is None                 # the XLA form
+        want = np.asarray(F.flat_cyclo_sqr(a))
+        assert (np.asarray(pf.cyclo_sqr(a)) == want).all()
+        return
+    zs = [_unitary_fp12(13), _unitary_fp12(42), G.FP12_ONE]
+    out = np.asarray(pf.cyclo_sqr(jnp.asarray(F.flat_encode(zs))))
+    assert ((0 <= out) & (out < 4096)).all()
+    for i, z in enumerate(zs):
+        assert F.flat_decode(jnp.asarray(out), i) == G.fp12_mul(z, z), i
+
+
+def test_cyclo_sqr_traces_twelve_reductions():
+    """The count that says the mechanism is in the program: a trace of
+    the real `pallas_call` (nothing runs) reduces 12 coordinates a
+    launch, one an output; reducing the nine Fp2 squares first took 18."""
+    import jax
+    pf = PFm.PallasField(P)                # its own launchers: traced here
+    before = PFm.mont_reductions_traced()
+    out = jax.eval_shape(pf.cyclo_sqr,
+                         jax.ShapeDtypeStruct((8, 12, 32), jnp.int32))
+    assert out.shape == (8, 12, 32)
+    assert PFm.mont_reductions_traced() - before == 12
+
+
+def test_cyclo_sqr_bounds_are_asserted_at_build():
+    """`_cyclo_sqr_plan` holds every bound of the wide recombination as
+    an assertion on exact integers: its own offsets pass, cover what each
+    output subtracts in value and limb by limb, and an offset one p^2
+    short of the subtracted value, or no multiple of p, fails the build."""
+    pf = PFm.PallasField(P)
+    offs, subs = pf._cyclo_sqr_plan()
+    assert subs == (4, 2, 1) and len(offs) == len(PFm._CYCLO_SQR_OUTPUTS)
+    assert sum(len(o[2]) for o in PFm._CYCLO_SQR_OUTPUTS) == 12
+    assert pf._cyclo_sqr_check(offs) == subs
+    value = limbs_to_int
+    for out, off in zip(PFm._CYCLO_SQR_OUTPUTS, offs):
+        sub_value, add_value, sub_limbs, _ = pf._cyclo_sqr_ranges(out)
+        assert value(off) % (P * P) == 0
+        assert value(off) >= sub_value > 0
+        assert all(o >= s for o, s in zip(off, sub_limbs))
+        # with the reduce's own m * p / R, about 1.04 p, on top: between
+        # 4p and 8p, so no shorter chain would do
+        assert 4 * P < ((value(off) + add_value) >> 384) + P < 8 * P
+
+    def with_offset(i, off):
+        return offs[:i] + (tuple(off),) + offs[i + 1:]
+
+    for i, out in enumerate(PFm._CYCLO_SQR_OUTPUTS):
+        sub_value = pf._cyclo_sqr_ranges(out)[0]
+        short, _ = T.wide_neg_offset(1, min_value=sub_value - P * P)
+        assert value(short) < sub_value
+        with pytest.raises(AssertionError):
+            pf._cyclo_sqr_check(with_offset(i, short))
+        bent = list(offs[i])
+        bent[0] += 1                                # value no multiple of p
+        with pytest.raises(AssertionError):
+            pf._cyclo_sqr_check(with_offset(i, bent))
+    with pytest.raises(AssertionError):
+        pf._cyclo_sqr_check(offs[:-1])
 
 
 def test_sim_sqr4_mul_lazy(sim):

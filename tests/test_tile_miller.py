@@ -129,6 +129,35 @@ def test_the_crossings_are_the_entrys_whatever_the_ladders_length(
                                  compact) == want, (compact, segments)
 
 
+@pytest.mark.parametrize("check,want", [
+    ("pairs", {"to_tiles": 24, "from_tiles": 11}),
+    ("fixed_q", {"to_tiles": 25, "from_tiles": 11}),
+])
+def test_a_whole_check_crosses_as_it_did_before_issue_42(points, check, want):
+    """`cyclo_sqr` recombines in the wide domain since ISSUE 42: its
+    callers, the TileForm threading and the crossings of a whole pairing
+    check do not change.  The counts are the parent commit's (8e90d58),
+    read around the same traces: Miller loop and x-power chains of one
+    step, the final exponentiation whole (`flat_inv`'s tower evaluation
+    is its counted interior exception), the verdict's mask out."""
+    from drand_tpu.crypto.bls12381 import curve as GCv
+    if check == "pairs":
+        fn, args = DP.pairing_check_pairs, (points,)
+    else:
+        qs = [GCv.g2_affine(q) for q in
+              (GCv.G2_GEN, GCv.g2_mul(GCv.G2_GEN, 7))]
+        fn = DP.pairing_check_fixed_q
+        args = ([p for p, _ in points], jnp.asarray(DP.fixed_q_table(qs)))
+    with mock.patch.object(PFm, "use_pallas", return_value=True), \
+            mock.patch.object(DP, "_X_SEGMENTS", ONE_STEP), \
+            mock.patch.dict("os.environ", DRAND_TPU_MILLER_MERGED="0"), \
+            compact_scope(True):
+        before = PFm.layout_conversion_counts()
+        jax.make_jaxpr(fn)(*args)
+        after = PFm.layout_conversion_counts()
+    assert {k: after[k] - before[k] for k in after} == want
+
+
 def test_a_line_is_read_from_its_pairs_run_of_tiles(points):
     """`flat_mul`'s `b_run` under the simulator: with two batches joined
     on the tile axis it multiplies by the one asked for, in place."""
