@@ -1,6 +1,7 @@
 """Chain catch-up sync (reference `chain/beacon/sync_manager.go`).
 
-Follower side: queued sync requests, shuffled peer iteration, stall
+Follower side: queued sync requests, shuffled peer iteration until the
+store holds a bounded request's target (`SyncManager.sync`), stall
 detection at 2x period — but where the reference verifies each streamed
 beacon one at a time (`sync_manager.go:397-399`, the serial loop SURVEY.md
 §5.7 calls out), this sync manager accumulates stream chunks and verifies
@@ -290,6 +291,7 @@ class _CatchupPipeline:
                 continue
             dt = time.perf_counter() - t0
             self.m.stats["pack_s"] += dt
+            self.m.stats["rows_dispatched"] += len(work.seg)
             _observe_stage("pack", dt)
             await self._enqueue(self._q_commit, work, "commit")
 
@@ -317,10 +319,13 @@ class _CatchupPipeline:
             if work is self._CLOSE:
                 return
             t0 = self._dequeued(work, "commit")
+            seg, anchor_sig = work.seg, work.anchor_sig
             if self.broken:
+                # dispatched behind the segment that broke the pipeline:
+                # the device verifies it and nobody reads the verdicts
+                self.m.stats["rows_discarded"] += len(seg)
                 work.span.end("discarded")
                 continue
-            seg, anchor_sig = work.seg, work.anchor_sig
             settle = tracing.begin_span("sync.settle", parent=work.span,
                                         at=t0)
             try:
@@ -344,6 +349,8 @@ class _CatchupPipeline:
                            for i in np.nonzero(~ok)[0][:5]]
                 log.warning("segment verify failed at rounds %s", bad)
                 self.failure = True
+                # its good rows' verdicts commit nothing either
+                self.m.stats["rows_discarded"] += len(seg)
                 work.span.end("verify_failed")
                 continue
             t0 = time.perf_counter()
@@ -399,16 +406,33 @@ class SyncManager:
         # cumulative per-stage host seconds + throughput counters of the
         # catch-up pipeline — the /debug/sync snapshot and the bench's
         # per-stage breakdown both read this
+        # `rounds_fetched`: rounds taken off the wire; `rounds_refetched`:
+        # those of them at or below the highest round this manager had
+        # taken off it before (a peer dropped with a run buffered, a
+        # segment failed: the next peer serves them again);
+        # `rows_dispatched`: rows handed to the verifier, of which
+        # `rows_discarded` are those whose verdicts committed nothing (a
+        # segment that verified false, and what was enqueued behind it)
         self.stats = {"fetch_s": 0.0, "pack_s": 0.0, "verify_s": 0.0,
-                      "commit_s": 0.0, "segments": 0, "rounds": 0}
+                      "commit_s": 0.0, "segments": 0, "rounds": 0,
+                      "rounds_fetched": 0, "rounds_refetched": 0,
+                      "rows_dispatched": 0, "rows_discarded": 0}
+        self._wire_high = 0            # highest round taken off the wire
         self._current_peer = ""
         self._chunk_target = SYNC_CHUNK
         self._backlog = 0
+        self._try = 0                  # which try of the request at hand
+        self._try_end = ""             # how the last try ended (`end`)
+        # `sync.failover`, open from the end of a try that left the
+        # request short to the next peer's first wire message
+        self._failover: tracing.Span | None = None
 
     def snapshot(self) -> dict:
         """Point-in-time sync state for /debug/sync."""
         return {
             "current_peer": self._current_peer,
+            "try": self._try,
+            "last_try_end": self._try_end,
             "chunk_target": self._chunk_target,
             "pipeline_depth": PIPELINE_DEPTH,
             "backlog_estimate": self._backlog,
@@ -446,41 +470,106 @@ class SyncManager:
                 log.warning("sync failed: %s", exc)
 
     async def sync(self, req: SyncRequest) -> bool:
-        """Try peers until one stream succeeds (sync_manager.go:296-320).
+        """One sync request against the group's peers, one after another
+        (Sync/tryNode, sync_manager.go:296-438).
 
-        Pre-resilience this was a blind shuffle; now the shuffled list is
-        re-ranked breaker-aware (closed first, open last — open peers
-        stay reachable as a last resort so a fully-tripped net keeps its
-        liveness path) and the head of the line goes to the first peer
-        answering a hedged liveness probe."""
-        peers = [n for n in self.nodes]
+        A bounded request (`up_to > 0`) is true only with the store at
+        `up_to`: a peer whose try leaves the store short of it (its
+        stream ended cleanly before the target, as a serving node with a
+        damaged row of its own ends it; it dropped; it stalled; a
+        segment of its rounds verified false) is followed by the next,
+        which resumes past `store.last()`, and the request is false only
+        when every peer was tried, with everything verified so far
+        committed.  In follow mode (`up_to == 0`) there is no height to
+        reach: the request ends with the first peer whose stream
+        committed anything, false where none did.
+
+        Who is next: the shuffled list, re-ranked breaker-aware before
+        every try where the daemon's Resilience hub is wired (closed
+        first, open last: open peers stay reachable as a last resort so
+        a fully-tripped net keeps its liveness path), the head of the
+        line going to the first peer that answers a hedged liveness
+        probe (`_hedge_probe_order`).
+
+        One trace a request: the root `sync.request` (from_round, up_to,
+        peers; at its end `tries`, `reached`, and what the request added
+        to `stats`: `rounds`, `rounds_fetched`, `rounds_refetched`,
+        `rows_dispatched`, `rows_discarded`) over a `sync.catchup` a try
+        (`_try_node`), and
+        between a try that left the request short and the next peer's
+        first wire message a `sync.failover` (from_peer, reason: that
+        try's `end`; to_peer, `wall_s`; `spent` where no peer was left
+        to deliver one) over the `sync.probe` that chose the peer."""
+        peers = list(self.nodes)
         random.shuffle(peers)
-        if self.resilience is not None and len(peers) > 1:
-            peers = self.resilience.breakers.rank(
-                peers, key=lambda n: getattr(n, "address", ""))
-            peers = await self._hedge_probe_order(peers)
+        before = dict(self.stats)
         # NOTE: sync outcomes deliberately do NOT feed the breakers —
         # only RetryPolicy-gated unary traffic does, keeping failure
         # sequences (and so trip points) deterministic in fake time for
-        # chaos replay.  Sync READS breaker state (the ranking above)
+        # chaos replay.  Sync READS breaker state (the ranking below)
         # without writing it.
-        for peer in peers:
-            addr = getattr(peer, "address", "")
+        with tracing.span("sync.request", beacon_id=self.beacon_id,
+                          from_round=req.from_round, up_to=req.up_to,
+                          peers=len(peers)) as root:
+            reached = self._reached(req.up_to)
+            self._try = 0
             try:
-                ok = await self._try_node(peer, req)
-            except Exception as exc:
-                log.debug("peer %s sync error: %s", addr or peer, exc)
-                continue
-            if ok:
-                return True
-        return False
+                while peers and not reached:
+                    with tracing.under(self._failover or root):
+                        peers = await self._ranked(peers)
+                    peer = peers.pop(0)
+                    addr = getattr(peer, "address", "") or str(peer)
+                    self._try += 1
+                    try:
+                        ok = await self._try_node(peer, req)
+                    except Exception as exc:
+                        log.debug("peer %s sync error: %s", addr, exc)
+                        ok = False
+                    reached = self._reached(req.up_to) if req.up_to else ok
+                    if not reached and self._failover is None:
+                        self._failover = tracing.begin_span(  # lint: disable=span-balance
+                            "sync.failover", parent=root, from_peer=addr,
+                            reason=self._try_end)   # ended by `_arrived`
+            finally:
+                self._arrived("", time.perf_counter(), "spent")
+                root.set(tries=self._try, reached=reached,
+                         **{k: self.stats[k] - before[k] for k in (
+                             "rounds", "rounds_fetched", "rounds_refetched",
+                             "rows_dispatched", "rows_discarded")})
+        return reached
+
+    def _reached(self, up_to: int) -> bool:
+        """The store holds a bounded request's target."""
+        if not up_to:
+            return False
+        try:
+            return self.store.last().round >= up_to
+        except StoreError:              # empty, or a torn tip
+            return False
+
+    def _arrived(self, peer: str, at: float, status: str | None = None):
+        """A peer's first wire message came (or no peer is left): the
+        end of the open `sync.failover`, if there is one."""
+        sp, self._failover = self._failover, None
+        if sp is not None:
+            sp.set(to_peer=peer, wall_s=at - sp.start_mono)
+            sp.end(status, at=at)
+
+    async def _ranked(self, peers: list) -> list:
+        """The peers not yet tried, the next one first."""
+        if self.resilience is None or len(peers) < 2:
+            return peers
+        peers = self.resilience.breakers.rank(
+            peers, key=lambda n: getattr(n, "address", ""))
+        return await self._hedge_probe_order(peers)
 
     async def _hedge_probe_order(self, peers: list) -> list:
         """Hedged segment dispatch: stagger Status probes across the top
         candidates (delayed secondary launch, first success wins, losers
         cancelled); the winner serves the stream first.  Best-effort —
         any failure falls back to the breaker-ranked order — and bounded
-        in real time so a hung probe cannot wedge a sync request."""
+        in real time so a hung probe cannot wedge a sync request.  One
+        span a race, `sync.probe` (candidates, winner, `wall_s`)."""
         from drand_tpu.resilience import hedge
         status = getattr(self.net, "status", None)
         if status is None:
@@ -491,34 +580,51 @@ class SyncManager:
             await status(p)
             return p
 
-        try:
-            winner = await asyncio.wait_for(
-                hedge.first_success(
-                    "sync.dispatch", [lambda p=p: probe(p) for p in top],
-                    delay_s=HEDGE_PROBE_DELAY_S, clock=self.clock),
-                HEDGE_PROBE_BOUND_S)
-        except Exception:
+        with tracing.span("sync.probe", candidates=len(top)) as sp:
+            try:
+                winner = await asyncio.wait_for(
+                    hedge.first_success(
+                        "sync.dispatch", [lambda p=p: probe(p) for p in top],
+                        delay_s=HEDGE_PROBE_DELAY_S, clock=self.clock),
+                    HEDGE_PROBE_BOUND_S)
+            except Exception:
+                winner = None
+            sp.set(winner=getattr(winner, "address", "") if winner else "",
+                   wall_s=time.perf_counter() - sp.start_mono)
+        if winner is None:
             return peers
         return [winner] + [p for p in peers if p is not winner]
 
     async def _try_node(self, peer, req: SyncRequest) -> bool:
         """One catch-up from one peer, as one trace: the root span
-        `sync.catchup` over `_fetch_stage`.  The wire wait stays a
+        `sync.catchup` over `_fetch_stage` (under a request's
+        `sync.request`, where `sync` made the call; `try` is its number
+        there).  The wire wait stays a
         counter on it (`fetch_s`, `messages`; from the network layer
         `recv_s`, `decode_s`, `bytes`): a catch-up makes a wait for
         every message, which is no span each; `_fetch_stage` files them
         on one `sync.fetch` a segment.  While the root is open the event
-        loop's lag is counted on it (`tracing.loop_watched`)."""
+        loop's lag is counted on it (`tracing.loop_watched`).  At its
+        end it says how the try ended (`end`): `done` (the stream served
+        what was asked: a bounded try to `up_to`), `ended_short` (the
+        stream ended, or went out of order, before that), `dropped` (it
+        raised after its first message), `unreachable` (before it),
+        `stalled` (it idled STALL_FACTOR periods), `verify_failed` (a
+        segment of its rounds verified false) or `error` (the
+        pipeline's own: a dispatch, a commit)."""
         before = dict(self.stats)
+        self._try_end = ""
         with tracing.span(
                 "sync.catchup", beacon_id=self.beacon_id,
                 from_round=req.from_round, up_to=req.up_to,
-                peer=getattr(peer, "address", "") or str(peer)) as root, \
+                peer=getattr(peer, "address", "") or str(peer),
+                **{"try": self._try}) as root, \
                 tracing.loop_watched(root):
             try:
                 return await self._fetch_stage(peer, req, root)
             finally:
-                root.set(**{k: self.stats[k] - before[k]
+                root.set(end=self._try_end or "error",
+                         **{k: self.stats[k] - before[k]
                             for k in ("rounds", "segments", "fetch_s")})
 
     async def _fetch_stage(self, peer, req: SyncRequest, root) -> bool:
@@ -537,6 +643,8 @@ class SyncManager:
             last = self.store.last()
         except BeaconNotFound:
             return False
+        # the resumption: a request's second peer begins where the
+        # first one's verified rounds end
         from_round = max(req.from_round, last.round + 1)
         # the anchor advances OPTIMISTICALLY at flush time (to the
         # flushed tail) — sound because verify failure or commit error
@@ -565,7 +673,8 @@ class SyncManager:
         # buckets on one device, the host tier).
         chunk_target = SYNC_CHUNK
         rows_charged = getattr(self.verifier, "rows_charged", None)
-        self._current_peer = getattr(peer, "address", "") or str(peer)
+        address = getattr(peer, "address", "") or str(peer)
+        self._current_peer = address
         self._backlog = max(0, req.up_to - last.round) if req.up_to else 0
 
         pipe = _CatchupPipeline(self, req.up_to)
@@ -641,6 +750,7 @@ class SyncManager:
         # first idle moment.  Keep one pending read across idle windows.
         pending: asyncio.Future | None = None
         ended_by = "stream_end"    # what cuts the run left when the loop ends
+        end = "ended_short"        # how the try ended (`_try_node`)
         try:
             while not pipe.broken:
                 cut_at, cut = segment_cut()
@@ -666,6 +776,7 @@ class SyncManager:
                         log.debug("sync stream from %s stalled (%dx period"
                                   " idle); renewing",
                                   getattr(peer, "address", peer), STALL_FACTOR)
+                        end = "stalled"
                         break
                     continue
                 try:
@@ -673,10 +784,19 @@ class SyncManager:
                 except StopAsyncIteration:
                     pending = None
                     break
+                except Exception:
+                    end = "dropped" if messages else "unreachable"
+                    raise
                 pending = None
                 messages += 1
+                if messages == 1:
+                    self._arrived(address, fill_ended)
                 stall_at = self.clock.now() + STALL_FACTOR * self.group.period
                 first_r, last_r, n = _item_span(item)
+                self.stats["rounds_fetched"] += n
+                self.stats["rounds_refetched"] += max(
+                    0, min(last_r, self._wire_high) - first_r + 1)
+                self._wire_high = max(self._wire_high, last_r)
                 expected = (_item_span(buffer[-1])[1] + 1 if buffer
                             else anchor_round + 1)
                 if first_r != expected:
@@ -708,6 +828,12 @@ class SyncManager:
                                        SYNC_CHUNK_MAX)
             if not pipe.broken:
                 await flush(ended_by)
+                if ended_by == "backlog_end":
+                    end = "done"
+        except BaseException:
+            if end not in ("dropped", "unreachable"):
+                end = "error"       # not the stream's: a failpoint, a cancel
+            raise
         finally:
             # A mid-stream exception (peer drop, RPC error) must not
             # discard in-flight segments: they were dispatched against a
@@ -729,6 +855,13 @@ class SyncManager:
                     await aclose()
                 except Exception:
                     pass
+            if pipe.error is not None:
+                end = "error"
+            elif pipe.failure:
+                end = "verify_failed"
+            elif not req.up_to and end == "ended_short" and pipe.got_any:
+                end = "done"        # follow mode asks for no height
+            self._try_end = end
         if pipe.error is not None:
             raise pipe.error
         if pipe.failure:
@@ -1144,9 +1277,12 @@ async def serve_sync_chain(store, from_round: int, live_queue=None,
                     # A damaged row on OUR disk must not error the stream:
                     # the CorruptRowError carries the offending round, so
                     # re-read the good prefix below it, serve that, and end
-                    # the stream cleanly — the client renews against
-                    # another peer while the startup scan / fsck deals with
-                    # the damage here.
+                    # the stream cleanly.  A bounded request of the client's
+                    # then goes on to its next peer for the rest
+                    # (`SyncManager.sync`: the try ends `ended_short`);
+                    # a follower at the head commits the prefix and asks
+                    # again on its next tick.  The startup scan / fsck
+                    # deals with the damage here.
                     bad = getattr(exc, "round", None)
                     rows = []
                     if bad is not None and bad > next_round:

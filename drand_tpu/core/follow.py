@@ -111,6 +111,9 @@ async def follow_chain(daemon, request):
                 continue
         while not q.empty():
             yield q.get_nowait()
+        # a bounded follow is true only with the store at `up_to`, false
+        # only once every peer was tried; an unbounded one is true where
+        # any peer's stream committed anything
         ok = task.result()
         last = store.last()
         yield last.round, target
