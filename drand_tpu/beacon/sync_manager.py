@@ -948,8 +948,8 @@ class SyncManager:
         peer's `sync.serve` joins it through the request's metadata),
         `check.edges` (the stored rows around the runs), `check.verify_wait`
         over the verifier's `verify.dispatch` and `verify.resolve`, and
-        `check.overwrite` (rows; `encode_s`, `insert_s`, `flush_s` from
-        `SqliteStore.put_many`)."""
+        `check.overwrite` (rows; `encode_s`, `insert_s`, `statements`,
+        `flush_s` from `SqliteStore.put_many`)."""
         with tracing.span("check.chain", beacon_id=self.beacon_id,
                           up_to=up_to or 0) as root, \
                 tracing.loop_watched(root):

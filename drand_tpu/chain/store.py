@@ -15,6 +15,7 @@ already in the Python stdlib (SURVEY.md §7 step 4).
 
 from __future__ import annotations
 
+import functools
 import os
 import sqlite3
 import threading
@@ -29,6 +30,17 @@ from drand_tpu.chain.beacon import Beacon
 # the per-row crossing on deep scans (iter_range over a 16384-round
 # segment) without holding more than this many decoded rows at once
 _FETCH_BATCH = 1024
+
+# Rows a statement of `put_many`: 998 bound variables, under the 999 of
+# sqlite's oldest SQLITE_MAX_VARIABLE_NUMBER.
+_ROWS_A_STATEMENT = 499
+
+
+@functools.cache
+def _insert_rows_sql(n: int) -> str:
+    return ("INSERT OR REPLACE INTO beacons (round, data) VALUES "
+            + ", ".join(["(?, ?)"] * n))
+
 
 # PRAGMA synchronous policy (DRAND_TPU_STORE_SYNC): NORMAL is the WAL
 # crash-safe default — with WAL journaling, NORMAL survives process kill
@@ -133,7 +145,7 @@ class SqliteStore(Store):
     Crash-consistency invariant (WAL + synchronous>=NORMAL + one
     transaction per commit): a partially-applied segment is NEVER
     visible after a restart.  `put_many` writes a whole verified
-    segment in one `executemany` transaction, so a kill -9 mid-catchup
+    segment in one transaction, so a kill -9 mid-catchup
     leaves the database at a segment boundary — either the segment is
     fully there or fully absent.  The startup integrity scan
     (drand_tpu/chain/recovery.py) depends on, and the chaos
@@ -198,30 +210,44 @@ class SqliteStore(Store):
 
     def put_many(self, beacons) -> None:
         """ONE transaction for a whole verified segment (one commit/fsync
-        instead of per-beacon).  Its three parts go as counters to the
+        instead of per-beacon), its rows in multi-row statements of at
+        most `_ROWS_A_STATEMENT`, in the order given (a round given
+        twice keeps the later row).  Its parts go as counters to the
         span that encloses the call (`store.commit`, in the worker that
-        commits): `encode_s` (the row tuples built in Python),
-        `insert_s` (`executemany`, which opens the transaction) and
-        `flush_s` (its COMMIT: the WAL's write, at the `synchronous`
-        level the connection has); an exception rolls it back, as the
-        connection's context manager did."""
+        commits): `encode_s` (the rows' values built in Python),
+        `insert_s` (all the statements, of which the first opens the
+        transaction), their number `statements`, and `flush_s` (its
+        COMMIT: the WAL's write, at the `synchronous` level the
+        connection has); an exception rolls the whole segment back, as
+        the connection's context manager did.
+
+        `sqlite3` gives the interpreter lock up around every step of a
+        statement, and a row of `executemany` is a step: a 16,384-row
+        segment handed the lock over 16,384 times, and beside another
+        thread that does the same (a reader's `fetchall`) each handing
+        over waited for a thread to wake (PERF.md, PR 45).  A statement
+        of 499 rows is ONE step: 33 a segment."""
         from drand_tpu import tracing
         enc = self._encode
         conn = self._conn()
         t0 = _time.perf_counter()
-        rows = [(b.round, enc(b)) for b in beacons]
+        values = [v for b in beacons for v in (b.round, enc(b))]
         t1 = _time.perf_counter()
+        step = 2 * _ROWS_A_STATEMENT
+        statements = 0
         try:
-            conn.executemany(
-                "INSERT OR REPLACE INTO beacons (round, data) VALUES (?, ?)",
-                rows)
+            for at in range(0, len(values), step):
+                part = values[at:at + step]
+                conn.execute(_insert_rows_sql(len(part) // 2), part)
+                statements += 1
             t2 = _time.perf_counter()
             conn.commit()
         except BaseException:
             conn.rollback()
             raise
         tracing.count(encode_s=t1 - t0, insert_s=t2 - t1,
-                      flush_s=_time.perf_counter() - t2)
+                      flush_s=_time.perf_counter() - t2,
+                      statements=statements)
 
     def last(self) -> Beacon:
         row = self._conn().execute(

@@ -343,39 +343,43 @@ LINE_IDX = (0, 2, 3, 6, 8, 9)
 
 @functools.cache
 def _flat_mul_tab(b_idx):
-    """Contribution table for a 12-slot x b_idx flat multiply:
-    (tab [K, 12] with tab[k, i] = b row group for power k-i or -1,
+    """Compact product table for a 12-slot x b_idx flat multiply:
+    (tab [4, max(K, n)] — rows 0 and 1, at column k, the start and the
+     count of power k's run in the flat list of the n slot products;
+     rows 2 and 3, at column t, the list's (i, jj): a_i times b row
+     group jj, which holds power b_idx[jj] = k - i; i rising in a run —
      pairs ((k, n_products), ...), K)."""
     K = 11 + max(b_idx) + 1
-    inv = [-1] * 12
-    for jj, p in enumerate(b_idx):
-        inv[p] = jj
-    tab = np.full((K, 12), -1, np.int32)
-    for k in range(K):
-        for i in range(12):
-            if 0 <= k - i <= 11:
-                tab[k, i] = inv[k - i]
-    pairs = tuple((k, int((tab[k] >= 0).sum())) for k in range(K))
-    return tab, pairs, K
+    inv = {p: jj for jj, p in enumerate(b_idx)}
+    runs = [[(i, inv[k - i]) for i in range(12) if k - i in inv]
+            for k in range(K)]
+    counts = [len(run) for run in runs]
+    flat = [prod for run in runs for prod in run]
+    tab = np.zeros((4, max(K, len(flat))), np.int32)
+    tab[0, :K] = np.cumsum([0] + counts[:-1])
+    tab[1, :K] = counts
+    tab[2:, :len(flat)] = np.asarray(flat, np.int32).T
+    return tab, tuple(enumerate(counts)), K
 
 
 @functools.cache
 def _flat_sqr_tab():
-    """Slot-symmetric squaring table: (tab [23, 7] — cols 0..5 the i of
-    pair (i, k-i) with i < k-i or -1, col 6 the diagonal slot — and the
-    per-conv product counts)."""
+    """Compact slot-symmetric squaring table: (tab [4, 66] — rows 0 and
+    1, at column k, the start and the count of power k's run in the flat
+    list of the 66 pairs (i, k - i) with i < k - i; row 2 the count of
+    its diagonal a_{k/2}^2, 1 for the 12 even k and 0 else; row 3, at
+    column t, the list's i, rising in a run — and the per-conv product
+    counts)."""
     K = 23
-    tab = np.full((K, 7), -1, np.int32)
-    for k in range(K):
-        t = 0
-        for i in range(max(0, k - 11), (k - 1) // 2 + 1):
-            tab[k, t] = i
-            t += 1
-        if k % 2 == 0:
-            tab[k, 6] = k // 2
-    pairs = tuple(
-        (k, int(2 * (tab[k, :6] >= 0).sum() + (tab[k, 6] >= 0)))
-        for k in range(K))
+    runs = [list(range(max(0, k - 11), (k - 1) // 2 + 1)) for k in range(K)]
+    counts = [len(run) for run in runs]
+    flat = [i for run in runs for i in run]
+    tab = np.zeros((4, len(flat)), np.int32)
+    tab[0, :K] = np.cumsum([0] + counts[:-1])
+    tab[1, :K] = counts
+    tab[2, :K] = [k % 2 == 0 for k in range(K)]
+    tab[3] = flat
+    pairs = tuple((k, 2 * counts[k] + int(tab[2, k])) for k in range(K))
     return tab, pairs
 
 
@@ -1094,6 +1098,14 @@ class PallasField:
         assert worst_limb + 5 * 4 * 2 * 12 * 4224 < (1 << 31) // 4
         return tuple(offs)
 
+    @staticmethod
+    def _acc_scratch():
+        """The flat kernels' accumulator scratch, 64 wide rows a group:
+        the 12 slot accumulators, the trash slot, and the sum of the
+        power in hand (`_sum_run`; `line_merge` sums statically and
+        leaves that last group unused)."""
+        return pltpu.VMEM((14 * 2 * N_LIMBS, *_ROW), jnp.int32)
+
     def _acc_init(self, acc_ref, offs):
         for j in range(12):
             acc_ref[pl.ds(j * 2 * N_LIMBS, 2 * N_LIMBS)] = jnp.stack(
@@ -1141,13 +1153,33 @@ class PallasField:
     # implementation — bit-identity between the paths is by construction,
     # not by parallel maintenance.
 
+    @staticmethod
+    def _sum_run(acc_ref, start, count, conv_at):
+        """One power's wide sum: the carried convolutions conv_at(t) of
+        its run, t in [start, start + count), added in place in the
+        scratch's last group.  The bounds are the run's own, read from
+        SMEM, so every iteration is a product and no branch stands in
+        the loop; and the loop carries its counter alone: a 64-row sum
+        carried through it is the whole vector register file, spilled
+        and filled around every product."""
+        run = pl.ds(13 * 2 * N_LIMBS, 2 * N_LIMBS)
+        acc_ref[run] = jnp.zeros((2 * N_LIMBS, *_ROW), jnp.int32)
+
+        def t_body(t, _):
+            acc_ref[run] = acc_ref[run] + conv_at(t)
+            return 0
+
+        jax.lax.fori_loop(start, start + count, t_body, 0)
+        return acc_ref[run]
+
     def _mul_phase(self, acc_ref, tab_ref, K, read_a, read_b, offs):
         """Generic flat-multiply accumulation: for each conv coefficient
-        k, sum the contributing a_i * b_{tab[k, i]} limb convolutions and
-        scatter onto the slot accumulators.  k and i loops are
-        `fori_loop`s so the ~1.3k-instruction conv body is traced ONCE
-        (a fully unrolled version is ~190k Mosaic instructions and
-        stalls/ooms the compiler on full graphs)."""
+        k, sum the limb convolutions of its run of slot products a_i *
+        b_jj (the _flat_mul_tab list) and scatter onto the slot
+        accumulators.  The k and product loops are `fori_loop`s so the
+        ~1.3k-instruction conv body is traced ONCE (a fully unrolled
+        version is ~190k Mosaic instructions and stalls/ooms the
+        compiler on full graphs)."""
 
         def conv_dyn(i, jj):
             aa = read_a(i)
@@ -1160,17 +1192,9 @@ class PallasField:
         self._acc_init(acc_ref, offs)
 
         def k_body(k, _):
-            def i_body(i, acc):
-                jj = tab_ref[k, i]
-
-                def take(acc):
-                    return acc + conv_dyn(i, jnp.maximum(jj, 0))
-
-                return jax.lax.cond(jj >= 0, take, lambda a: a, acc)
-
-            acc = jax.lax.fori_loop(
-                0, 12, i_body,
-                jnp.zeros((2 * N_LIMBS, *_ROW), jnp.int32))
+            acc = self._sum_run(
+                acc_ref, tab_ref[0, k], tab_ref[1, k],
+                lambda t: conv_dyn(tab_ref[2, t], tab_ref[3, t]))
             self._acc_scatter(acc_ref, k, acc)
             return 0
 
@@ -1178,7 +1202,11 @@ class PallasField:
 
     def _sqr_phase(self, acc_ref, tab_ref, read_a, offs):
         """Slot-symmetric squaring accumulation (the _flat_sqr_tab
-        layout: off-diagonal pairs doubled once + triangular diagonal)."""
+        list: a power's run of off-diagonal pairs summed and doubled
+        once, then the triangular diagonal a_{k/2}^2 where the table
+        counts one, scattered by itself: the scatter is linear, so the
+        doubled sum passes through no branch for a diagonal it may not
+        have)."""
 
         def conv_dyn(i, jj):
             aa = read_a(i)
@@ -1197,31 +1225,25 @@ class PallasField:
         self._acc_init(acc_ref, offs)
 
         def k_body(k, _):
-            def t_body(t, acc):
-                i = tab_ref[k, t]
+            def pair(t):
+                i = tab_ref[3, t]
+                return conv_dyn(i, k - i)
 
-                def take(acc):
-                    ii = jnp.maximum(i, 0)
-                    return acc + conv_dyn(ii, k - ii)
+            acc = self._sum_run(acc_ref, tab_ref[0, k], tab_ref[1, k], pair)
+            self._acc_scatter(acc_ref, k, acc + acc)
 
-                return jax.lax.cond(i >= 0, take, lambda a: a, acc)
+            def d_body(_, c):
+                self._acc_scatter(acc_ref, k, sqr_dyn(k // 2))
+                return c
 
-            acc = jax.lax.fori_loop(
-                0, 6, t_body, jnp.zeros((2 * N_LIMBS, *_ROW), jnp.int32))
-            acc = acc + acc                 # off-diagonal pairs doubled
-            d = tab_ref[k, 6]
-            acc = jax.lax.cond(
-                d >= 0, lambda a: a + sqr_dyn(jnp.maximum(d, 0)),
-                lambda a: a, acc)
-            self._acc_scatter(acc_ref, k, acc)
+            jax.lax.fori_loop(0, tab_ref[2, k], d_body, 0)
             return 0
 
         jax.lax.fori_loop(0, 23, k_body, 0)
 
     def _flat_mul_kernel(self, b_idx, offs, tab_ref, a_ref, b_ref,
                          o_ref, acc_ref):
-        """tab_ref (SMEM): [K, 12] int32, tab[k, i] = b row group for
-        power k - i, or -1 (see _flat_mul_tab)."""
+        """tab_ref (SMEM): the compact product table of _flat_mul_tab."""
         K = 11 + max(b_idx) + 1
         self._mul_phase(
             acc_ref, tab_ref, K,
@@ -1419,12 +1441,11 @@ class PallasField:
                                            jnp.int32),
             grid=(nt,),
             in_specs=[
-                pl.BlockSpec((K, 12), lambda i: (0, 0),
+                pl.BlockSpec(tab.shape, lambda i: (0, 0),
                              memory_space=pltpu.SMEM),
                 spec(12 * N_LIMBS), spec(J * N_LIMBS, first)],
             out_specs=spec(12 * N_LIMBS),
-            scratch_shapes=[pltpu.VMEM((13 * 2 * N_LIMBS, *_ROW),
-                                       jnp.int32)],
+            scratch_shapes=[self._acc_scratch()],
         )
         if a_tiled:
             return TileForm(out, shape, n)
@@ -2066,9 +2087,7 @@ class PallasField:
     # Miller loop squares the accumulator every iteration (63x/verify).
 
     def _flat_sqr_kernel(self, offs, tab_ref, a_ref, o_ref, acc_ref):
-        """tab_ref (SMEM): [K, 7] int32 — cols 0..5 the i of pair
-        (i, k-i) with i < k-i (or -1), col 6 the diagonal slot k/2 for
-        even k (or -1) (see _flat_sqr_tab)."""
+        """tab_ref (SMEM): the compact pair table of _flat_sqr_tab."""
         self._sqr_phase(
             acc_ref, tab_ref,
             lambda i: a_ref[0, pl.ds(i * N_LIMBS, N_LIMBS)], offs)
@@ -2099,12 +2118,11 @@ class PallasField:
                                            jnp.int32),
             grid=(nt,),
             in_specs=[
-                pl.BlockSpec((K, 7), lambda i: (0, 0),
+                pl.BlockSpec(tab.shape, lambda i: (0, 0),
                              memory_space=pltpu.SMEM),
                 spec(12 * N_LIMBS)],
             out_specs=spec(12 * N_LIMBS),
-            scratch_shapes=[pltpu.VMEM((13 * 2 * N_LIMBS, *_ROW),
-                                       jnp.int32)],
+            scratch_shapes=[self._acc_scratch()],
         )
         if a_tiled:
             return TileForm(out, shape, n)
@@ -2233,7 +2251,7 @@ class PallasField:
         at = tile_concat([l1, l2])
         out = self._call(
             kernel, 12 * N_LIMBS, at.tiles,
-            scratch=[pltpu.VMEM((13 * 2 * N_LIMBS, *_ROW), jnp.int32)])
+            scratch=[self._acc_scratch()])
         tf = TileForm(out, at.shape, at.b)
         if tiled:
             return tf
@@ -2394,19 +2412,19 @@ class PallasField:
                                       memory_space=pltpu.VMEM)
         out_shape = [jax.ShapeDtypeStruct((nt, 12 * N_LIMBS, *_ROW),
                                           jnp.int32)] * 2
-        scratch = [pltpu.VMEM((13 * 2 * N_LIMBS, *_ROW), jnp.int32),
+        scratch = [self._acc_scratch(),
                    pltpu.VMEM((12 * N_LIMBS, *_ROW), jnp.int32)]
         return spec, out_shape, scratch
 
     # Scoped VMEM of the two merged kernels, per grid step, at 4 KiB a
     # row (one int32 VREG): the blocks f, T, P, masks in and f', T' out
     # are (384+384+128+2) + (384+384) = 1666 rows, double-buffered 3332;
-    # the add step's Q block adds 2*256; the scratch is 13*2*32 + 12*32 =
-    # 1216 rows.  That is 17.77 MiB (dbl) and 19.77 MiB (add), and the
+    # the add step's Q block adds 2*256; the scratch is 14*2*32 + 12*32 =
+    # 1280 rows.  That is 18.02 MiB (dbl) and 20.02 MiB (add), and the
     # v5e compiler, which counts its own internal scratch too, asked for
-    # 19.78 MiB and 23.29 MiB against its 16 MiB default and refused
-    # both.  48 MiB is twice what it asked for and 3/8 of the 128 MiB of
-    # VMEM a v5e TensorCore has.
+    # 19.78 MiB and 23.29 MiB (PR 22, the scratch 64 rows smaller)
+    # against its 16 MiB default and refused both.  48 MiB is twice what
+    # it asked for and 3/8 of the 128 MiB of VMEM a v5e TensorCore has.
     _MILLER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=48 << 20)
 
     def miller_dbl_iter(self, f, T, P, masks, line_merge=True):
@@ -2431,9 +2449,9 @@ class PallasField:
             out_shape=out_shape,
             grid=(nt,),
             in_specs=[
-                pl.BlockSpec((23, 7), lambda i: (0, 0),
+                pl.BlockSpec(sqr_tab.shape, lambda i: (0, 0),
                              memory_space=pltpu.SMEM),
-                pl.BlockSpec((K_mul, 12), lambda i: (0, 0),
+                pl.BlockSpec(mul_tab.shape, lambda i: (0, 0),
                              memory_space=pltpu.SMEM),
                 spec(12 * N_LIMBS), spec(12 * N_LIMBS),
                 spec(4 * N_LIMBS), spec(2)],
@@ -2464,7 +2482,7 @@ class PallasField:
             out_shape=out_shape,
             grid=(nt,),
             in_specs=[
-                pl.BlockSpec((K_mul, 12), lambda i: (0, 0),
+                pl.BlockSpec(mul_tab.shape, lambda i: (0, 0),
                              memory_space=pltpu.SMEM),
                 spec(12 * N_LIMBS), spec(12 * N_LIMBS),
                 spec(8 * N_LIMBS), spec(4 * N_LIMBS), spec(2)],
