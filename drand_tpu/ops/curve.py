@@ -12,6 +12,8 @@ so only mixed/general addition needs explicit masks.
 
 from __future__ import annotations
 
+from typing import Callable, NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -109,14 +111,45 @@ def point_neg(pt, ops):
     return (pt[0], ops.neg(pt[1]), pt[2])
 
 
+class _PointKernels(NamedTuple):
+    """One coordinate field's fused point kernels (ops/pallas_field.py)."""
+    pack: Callable
+    dbl: Callable
+    add: Callable
+    unpack: Callable
+
+
+def _point_kernels(ops):
+    """The fused Pallas point kernels of `ops`' coordinate field on a
+    TPU, None on any other platform (the XLA forms below are then the
+    program, and on the TPU the oracle the kernels are held to)."""
+    pf = FP._pallas()
+    if pf is None:
+        return None
+    if ops is Fp2Ops:
+        return _PointKernels(pf.g2_pack_point, pf.g2_point_dbl,
+                             pf.g2_point_add, pf.g2_unpack_point)
+    if ops is FpOps:
+        return _PointKernels(pf.g1_pack_point, pf.g1_point_dbl,
+                             pf.g1_point_add, pf.g1_unpack_point)
+    return None
+
+
+def g1_ladder_form() -> str:
+    """How a G1 ladder steps in a program traced here: `fused` (one
+    kernel a step on a tile-resident point) or `generic` (the staged XLA
+    formulas); `Verifier.build` records it."""
+    return "generic" if _point_kernels(FpOps) is None else "fused"
+
+
 def point_double(pt, ops):
     """dbl-2009-l in staged stacked products; preserves infinity
-    (Z3 = 2YZ = 0).  On TPU the G2 form runs as one fused Pallas kernel
-    (the cofactor/subgroup ladders scan this body 63+ times)."""
-    if ops is Fp2Ops:
-        pf = FP._pallas()
-        if pf is not None:
-            return pf.g2_point_dbl(pt)
+    (Z3 = 2YZ = 0).  On TPU it runs as one fused Pallas kernel (the
+    cofactor/subgroup ladders scan this body 63+ times), over Fp2 since
+    ISSUE 9 and over Fp since ISSUE 46."""
+    fused = _point_kernels(ops)
+    if fused is not None:
+        return fused.dbl(pt)
     x, y, z = pt
     a, b, yz = ops.products([(x, x), (y, y), (y, z)])
     xb = ops.add(x, b)
@@ -138,12 +171,11 @@ def point_add(p1, p2, ops, with_double: bool = True):
 
     Set with_double=False in loops where p1 == p2 is impossible (e.g.
     double-and-add ladders over canonical scalars) to skip the doubling
-    computation.  On TPU the G2 form runs as one fused Pallas kernel.
+    computation.  On TPU it runs as one fused Pallas kernel.
     """
-    if ops is Fp2Ops:
-        pf = FP._pallas()
-        if pf is not None:
-            return pf.g2_point_add(p1, p2, with_double)
+    fused = _point_kernels(ops)
+    if fused is not None:
+        return fused.add(p1, p2, with_double)
     x1, y1, z1 = p1
     x2, y2, z2 = p2
     z1z1, z2z2, y1z2, y2z1 = ops.products(
@@ -258,19 +290,17 @@ def point_mul_const(pt, k: int, ops):
         return acc
 
     from drand_tpu.ops.field import segmented_ladder
-    if ops is Fp2Ops:
-        pf = FP._pallas()
-        if pf is not None:
-            # Tile-resident ladder: the point packs ONCE (entry crossing),
-            # every scan step is a fused kernel on the packed TileForm,
-            # and the result unpacks once at exit — vs a relayout on both
-            # sides of all 63+ point kernels before (ISSUE 9 tentpole).
-            base = pf.g2_pack_point(pt)
-            out = segmented_ladder(
-                segments, base,
-                lambda acc: pf.g2_point_dbl(acc),
-                lambda acc: pf.g2_point_add(acc, base, False))
-            return pf.g2_unpack_point(out)
+    fused = _point_kernels(ops)
+    if fused is not None:
+        # Tile-resident ladder: the point packs ONCE (entry crossing),
+        # every scan step is a fused kernel on the packed TileForm,
+        # and the result unpacks once at exit — vs a relayout on both
+        # sides of all 63+ point kernels before (ISSUE 9 tentpole; the
+        # G1 ladders since ISSUE 46).
+        base = fused.pack(pt)
+        return fused.unpack(segmented_ladder(
+            segments, base, fused.dbl,
+            lambda acc: fused.add(acc, base, False)))
     return segmented_ladder(
         segments, pt,  # starting from pt consumes the leading 1 bit
         lambda acc: point_double(acc, ops),

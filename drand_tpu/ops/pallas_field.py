@@ -624,6 +624,12 @@ class PallasField:
                               [jnp.zeros_like(a_rows[0])], 2)
         return self._mont_reduce_rows(t)
 
+    def _fp_sqr_rows(self, a_rows):
+        """Canonical Fp rows -> canonical Montgomery square."""
+        t = _carry_cheap_rows(_sqr_conv_rows(a_rows) +
+                              [jnp.zeros_like(a_rows[0])], 2)
+        return self._mont_reduce_rows(t)
+
     def _fp2_mul_rows(self, x, y, off_limbs):
         """Canonical Fp2 rows product (same math/bounds as
         _fp2_products_kernel's body)."""
@@ -2077,6 +2083,119 @@ class PallasField:
                 coords.extend([cpt[0], cpt[1]])
         o = self._coords_call(kernel, coords, 6)
         return ((o[0], o[1]), (o[2], o[3]), (o[4], o[5]))
+
+    # -- fused G1 Jacobian point kernels (ladder bodies) -------------------
+    #
+    # The G1 twins of the kernels above (ISSUE 46): the short-signature
+    # program holds three 64-bit ladders over Fp (`g1_in_subgroup`'s two,
+    # `hash_to_g1`'s cofactor clearing) whose generic step was four
+    # `mont_mul` launches, each with its own relayout both ways, and
+    # eleven XLA carry chains between them.  Same formulas, same
+    # canonical coordinates between products, so the outputs are the XLA
+    # forms' to the limb; 7 Montgomery reductions a doubling, 16 an
+    # addition (23 with the doubling fall-back).
+
+    def _g1_dbl_rows(self, X, Y, Z):
+        """dbl-2009-l body on Fp rows (mirrors curve.point_double)."""
+        st = self._stack3
+        un = self._unstk
+        sq = self._fp_sqr_rows(st(X, Y))
+        A, B = un(sq, 0), un(sq, 1)               # X^2, Y^2
+        YZ = self._fp_mul_rows(Y, Z)
+        sq2 = self._fp_sqr_rows(st(B, self._add_rows(X, B)))
+        C, S2 = un(sq2, 0), un(sq2, 1)            # B^2, (X+B)^2
+        E = self._mul_small_rows(A, 3)
+        D = self._sub_rows(S2, self._add_rows(A, C))
+        D = self._add_rows(D, D)
+        X3 = self._sub_rows(self._fp_sqr_rows(E), self._add_rows(D, D))
+        Et = self._fp_mul_rows(E, self._sub_rows(D, X3))
+        Y3 = self._sub_rows(Et, self._mul_small_rows(C, 8))
+        Z3 = self._add_rows(YZ, YZ)
+        return X3, Y3, Z3
+
+    def _g1_point_dbl_kernel(self, a_ref, o_ref):
+        self._write_coords(
+            o_ref, self._g1_dbl_rows(*self._read_coords(a_ref, 3)))
+
+    def _g1_point_add_kernel(self, with_double, a_ref, o_ref):
+        X1, Y1, Z1, X2, Y2, Z2 = self._read_coords(a_ref, 6)
+        st = self._stack3
+        un = self._unstk
+        sq = self._fp_sqr_rows(st(Z1, Z2))
+        z1z1, z2z2 = un(sq, 0), un(sq, 1)
+        m1 = self._fp_mul_rows(st(Y1, Y2), st(Z2, Z1))
+        m2 = self._fp_mul_rows(st(X1, X2, un(m1, 0), un(m1, 1)),
+                               st(z2z2, z1z1, z2z2, z1z1))
+        u1, u2, s1, s2 = (un(m2, i) for i in range(4))
+        h = self._sub_rows(u2, u1)
+        rr = self._sub_rows(s2, s1)
+        rr = self._add_rows(rr, rr)
+        sq2 = self._fp_sqr_rows(st(self._add_rows(h, h), rr,
+                                   self._add_rows(Z1, Z2)))
+        i, rr2, z12sq = (un(sq2, k) for k in range(3))
+        m3 = self._fp_mul_rows(st(h, u1), st(i, i))
+        j, v = un(m3, 0), un(m3, 1)
+        X3 = self._sub_rows(self._sub_rows(rr2, j), self._add_rows(v, v))
+        zz = self._sub_rows(z12sq, self._add_rows(z1z1, z2z2))
+        m4 = self._fp_mul_rows(st(rr, s1, zz),
+                               st(self._sub_rows(v, X3), j, h))
+        s1j = un(m4, 1)
+        Y3 = self._sub_rows(un(m4, 0), self._add_rows(s1j, s1j))
+        out = [X3, Y3, un(m4, 2)]
+
+        inf1 = self._rows_is_zero(Z1)
+        inf2 = self._rows_is_zero(Z2)
+        eq_u = self._rows_eq(u1, u2) & ~inf1 & ~inf2
+        eq_s = self._rows_eq(s1, s2)
+        if with_double:
+            dbl = self._g1_dbl_rows(X1, Y1, Z1)
+            out = [_select_rows(eq_u & eq_s, d, o)
+                   for d, o in zip(dbl, out)]
+        # P + (-P): infinity (X = Y = 1 in Montgomery form, Z = 0)
+        one = self._const_rows(self.ONE_MONT, X3[0])
+        zero = [jnp.zeros_like(X3[0])] * N_LIMBS
+        cancel = eq_u & ~eq_s
+        out = [_select_rows(cancel, ip, o)
+               for ip, o in zip((one, one, zero), out)]
+        out = [_select_rows(inf1, b, o) for b, o in zip((X2, Y2, Z2), out)]
+        out = [_select_rows(inf2 & ~inf1, a, o)
+               for a, o in zip((X1, Y1, Z1), out)]
+        self._write_coords(o_ref, out)
+
+    def g1_pack_point(self, pt) -> TileForm:
+        """Fp Jacobian point tuple -> packed 3-coord TileForm (one entry
+        crossing; no-op when already packed)."""
+        if isinstance(pt, TileForm):
+            return pt
+        return self.pack_coords(list(pt))
+
+    def g1_unpack_point(self, tf):
+        """Inverse of g1_pack_point (no-op on point tuples)."""
+        if not isinstance(tf, TileForm):
+            return tf
+        return tuple(self.unpack_coords(tf, 3))
+
+    def g1_point_dbl(self, pt):
+        """Fused curve.point_double for Fp Jacobian points; a packed
+        TileForm point stays packed, as in `g2_point_dbl`."""
+        if isinstance(pt, TileForm):
+            out = self._call(self._g1_point_dbl_kernel, 3 * N_LIMBS,
+                             pt.tiles)
+            return TileForm(out, pt.shape, pt.b)
+        return tuple(self._coords_call(self._g1_point_dbl_kernel,
+                                       list(pt), 3))
+
+    def g1_point_add(self, p1, p2, with_double: bool):
+        """Fused curve.point_add for Fp Jacobian points (full branchless
+        case handling); packed operands stay packed, as in
+        `g2_point_add`."""
+        kernel = functools.partial(self._g1_point_add_kernel, with_double)
+        if isinstance(p1, TileForm) or isinstance(p2, TileForm):
+            at = tile_concat([self.g1_pack_point(p1),
+                              self.g1_pack_point(p2)])
+            out = self._call(kernel, 3 * N_LIMBS, at.tiles)
+            return TileForm(out, at.shape, at.b)
+        return tuple(self._coords_call(kernel, [*p1, *p2], 3))
 
     # -- fused flat-Fp12 SQUARE --------------------------------------------
     #

@@ -230,7 +230,11 @@ class Verifier:
         The record, and the `verifier.build` span with it: `miller_lines`,
         which Miller loop the program holds (`table`, with `line_steps`
         rows, where both G2 arguments are the batch's and their lines
-        come with the key; `per_row` else).  The record alone: the
+        come with the key; `per_row` else), and where the program holds
+        G1 ladders (a signature on G1: its subgroup check and the
+        cofactor clearing of its hash) `g1_ladder`, how they step:
+        `fused`, one kernel a step on a tile-resident point, or `generic`,
+        the staged XLA formulas (ops/curve.py).  The record alone: the
         tracing mode; `trace_s` to the exported form in
         hand (of which `load_s` reading the file, with `blob_bytes`, or
         `load_error` where a file was there and could not be used),
@@ -250,8 +254,10 @@ class Verifier:
         # which Miller loop the program holds (ops/pairing.py): the lines
         # of both G2 arguments from the key's table, or a G2 point a row
         if self.shape.sig_on_g1:
+            from drand_tpu.ops.curve import g1_ladder_form
             from drand_tpu.ops.pairing import LINE_STEPS
-            what.update(miller_lines="table", line_steps=LINE_STEPS)
+            what.update(miller_lines="table", line_steps=LINE_STEPS,
+                        g1_ladder=g1_ladder_form())
         else:
             what["miller_lines"] = "per_row"
         with tracing.span("verifier.build", bucket=n, program=name,

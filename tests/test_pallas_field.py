@@ -293,6 +293,36 @@ def test_pallas_point_kernels_match_xla(sim):
     assert_same(DC.point_double(p1d, DC.Fp2Ops), pf.g2_point_dbl(p1d))
 
 
+def test_pallas_g1_point_kernels_match_xla(sim):
+    """The G1 twin (ISSUE 46): fused g1_point_dbl/g1_point_add vs
+    curve.point_double/point_add over Fp, with the same edge cases, under
+    the simulator as the G2 test above (the real interpreter's XLA:CPU
+    compile of these bodies ran past half an hour in the sandbox;
+    tier-1 holds more rows: tests/test_g1_point_kernels.py)."""
+    from drand_tpu.crypto.bls12381 import curve as GC
+    from drand_tpu.ops import curve as DC
+    pf = PFm.PallasField(P)
+
+    def assert_same(a, b):
+        for x, y in zip(a, b):
+            assert (np.asarray(x) == np.asarray(y)).all()
+
+    a1 = GC.g1_mul(GC.G1_GEN, rng.randrange(1, R))
+    a2 = GC.g1_mul(GC.G1_GEN, rng.randrange(1, R))
+    top = (P - 1,) * 3
+    cases1 = [a1, a1, a1, GC.G1_INF, a2, GC.G1_INF, top]
+    cases2 = [a2, a1, GC.g1_neg(a1), a2, GC.G1_INF, GC.G1_INF, a2]
+    p1d, p2d = DC.g1_encode(cases1), DC.g1_encode(cases2)
+    for with_double in (True, False):
+        assert_same(
+            DC.point_add(p1d, p2d, DC.FpOps, with_double=with_double),
+            pf.g1_point_add(p1d, p2d, with_double))
+    assert_same(DC.point_double(p1d, DC.FpOps), pf.g1_point_dbl(p1d))
+    packed = pf.g1_point_dbl(pf.g1_pack_point(p2d))
+    assert isinstance(packed, PFm.TileForm)
+    assert_same(DC.point_double(p2d, DC.FpOps), pf.g1_unpack_point(packed))
+
+
 def test_pallas_sqr4_mul_matches_xla(sim):
     """Fused windowed-exponentiation step (res^16 * t)."""
     pf = PFm.PallasField(P)

@@ -16,7 +16,8 @@ Left out for time (each was compiled once by hand for PR 22, both at one
 tile and at the 16 tiles of the 16,384 bucket; seconds in CHANGES.md):
 `mont_reduce`, `fp2_sqr5_mul`/`sqr4_mul`
 and the `sqr_chain_mul` family, `g2_point_dbl`/
-`g2_point_add`, `line_merge`, `flat_conj`/`flat_frob`, and the wider
+`g2_point_add` (their G1 twins are in since ISSUE 46: small bodies,
+seconds each), `line_merge`, `flat_conj`/`flat_frob`, and the wider
 `fp2_products`/`fp2_sqrs` stackings.  The two Miller kernels stay in
 although each takes minutes: they are the ones the compiler refused.
 `flat_mul`, sparse and dense, is in since ISSUE 45 beside `flat_sqr`:
@@ -111,6 +112,12 @@ KERNELS = {
         lambda pf, f, t, q, p, m: [o.tiles for o in pf.miller_add_iter(
             _tf(f), _tf(t), _tf(q), _tf(p), _tf(m))],
         (384, 384, 256, 128, 2)),
+    # the G1 ladders' step on a packed point (ISSUE 46); the addition
+    # with its doubling fall-back, the larger of its two bodies
+    "g1_point_dbl": (lambda pf, a: pf.g1_point_dbl(_tf(a)).tiles, (96,)),
+    "g1_point_add": (
+        lambda pf, a, b: pf.g1_point_add(_tf(a), _tf(b), True).tiles,
+        (96, 96)),
 }
 
 
